@@ -329,10 +329,11 @@ def bench_kernel_select(s: int = 65536, ticks: int = 12, seed: int = 9,
     One heterogeneous pick-only tick (the fleet hot path) at S streams,
     XLA engine vs ``backend="pallas"`` — same runtime-array contract, so
     the tick loop below also flips goals and churns the mask every tick
-    and asserts NEITHER backend re-traces.  Pick parity is asserted
-    bitwise on every tick (predictions parity once, on the warmup tick).
+    and asserts NEITHER backend re-traces.  Picks are held to the XLA
+    engine under the kernel's margin contract (docs/KERNELS.md) on the
+    warmup tick, predictions included, and on the last churn tick.
 
-    Honesty note (mirrors the sharded row): off-TPU the kernel runs in
+    Honesty note (mirrors the sharded row): on the CPU the kernel runs in
     Pallas **interpret mode** — the grid/BlockSpec semantics execute as
     XLA ops with per-grid-step dispatch overhead, so CPU timings measure
     the kernel *executing correctly*, not its TPU roofline; the record
@@ -343,8 +344,9 @@ def bench_kernel_select(s: int = 65536, ticks: int = 12, seed: int = 9,
     import jax
 
     from benchmarks.common import deadline_range, family_table
-    from repro.kernels.alert_select import (_default_interpret,
-                                            alert_select_cost)
+    from repro.kernels.alert_select import (alert_select_cost,
+                                            clear_lanes, margin_report)
+    from repro.kernels.ops import use_interpret
 
     table = family_table("image")
     dls = deadline_range(table, 5)
@@ -359,13 +361,18 @@ def bench_kernel_select(s: int = 65536, ticks: int = 12, seed: int = 9,
     act = rng.random(s) < 0.95
     kw = dict(accuracy_goal=rng.uniform(0.5, 0.9, s),
               energy_goal=rng.uniform(0.5, 3.0, s) * med_en)
-    # Warmup + full-prediction bitwise parity check.
+
+    def within_margin(bx, bp, predictions):
+        est = xla.estimate(mus, sds, phis, d, active=act)
+        rep = margin_report(bx, bp, clear_lanes(
+            est.accuracy, est.energy, kw["accuracy_goal"],
+            kw["energy_goal"], gk, act), predictions=predictions)
+        return rep["mismatches"] == 0 and rep["pred_ok"]
+
+    # Warmup + full-prediction margin check.
     bx = xla.select(mus, sds, phis, d, goal_kind=gk, active=act, **kw)
     bp = pal.select(mus, sds, phis, d, goal_kind=gk, active=act, **kw)
-    same = all(np.array_equal(getattr(bx, f), getattr(bp, f))
-               for f in ("model_index", "power_index", "feasible",
-                         "relaxed_code", "predicted_latency",
-                         "predicted_accuracy", "predicted_energy"))
+    same = within_margin(bx, bp, True)
     kw["predictions"] = False
     xla.select(mus, sds, phis, d, goal_kind=gk, active=act, **kw)
     pal.select(mus, sds, phis, d, goal_kind=gk, active=act, **kw)
@@ -382,11 +389,7 @@ def bench_kernel_select(s: int = 65536, ticks: int = 12, seed: int = 9,
         t0 = time.perf_counter()
         bp = pal.select(mus, sds, phis, d, goal_kind=gk, active=act, **kw)
         t_p.append(time.perf_counter() - t0)
-        same = same and \
-            np.array_equal(bx.model_index, bp.model_index) and \
-            np.array_equal(bx.power_index, bp.power_index) and \
-            np.array_equal(bx.feasible, bp.feasible) and \
-            np.array_equal(bx.relaxed_code, bp.relaxed_code)
+    same = same and within_margin(bx, bp, False)
     # Both the full-prediction and pick-only executables were warmed, so
     # a flat cache reads [0 estimate, 2 select] on both engines.
     no_retrace = (xla.n_compiles() == n0x and pal.n_compiles() == n0p
@@ -398,10 +401,10 @@ def bench_kernel_select(s: int = 65536, ticks: int = 12, seed: int = 9,
         "k": k, "l": l,
         "block_s": block_s,
         "ticks": ticks,
-        "picks_identical": bool(same),
-        # The kernel's own fallback predicate, so the recorded regime
-        # can never diverge from what actually executed.
-        "interpret": _default_interpret(),
+        "picks_within_margin": bool(same),
+        # The kernels' own mode switch, so the recorded regime can never
+        # diverge from what actually executed.
+        "interpret": use_interpret(),
         "platform": jax.default_backend(),
         "xla_us_per_decision": min(t_x) / s * 1e6,
         "pallas_us_per_decision": min(t_p) / s * 1e6,
@@ -424,7 +427,7 @@ def _sharded_child(s: int, ticks: int, reps: int) -> dict:
     the two paths is recorded as ``picks_identical`` and enforced by the
     parent ``run()``'s claim checks."""
     import jax
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64_scope
 
     from benchmarks.common import family_table, deadline_range
     from repro.launch.mesh import make_lane_mesh
@@ -480,7 +483,7 @@ def _sharded_child(s: int, ticks: int, reps: int) -> dict:
             batch = engine.select(slow.mu, slow.sigma, idle.phi, d_v,
                                   goal_kind=gk_v, active=act_v, **dkw)
             if on_dev:
-                with enable_x64():
+                with x64_scope():
                     obs, prof = feedback(batch.model_index,
                                          batch.power_index, jit_v[t])
             else:
@@ -922,6 +925,7 @@ def _faults_kill_resume() -> None:
 
     from repro.traffic import SessionGateway, generate_requests
 
+    _refuse_on_tpu("--faults-kill-resume")
     table, sessions, n_lanes, dl, _, _ = _faults_workload()
     gw = SessionGateway(table, n_lanes, tick=dl, max_queue=4 * n_lanes)
     ref = gw.run(sessions, generate_requests(sessions))
@@ -1166,6 +1170,18 @@ def bench_obs(s: int = 20_000, n_lanes: int = 1024, rounds: int = 24,
     }
 
 
+def _refuse_on_tpu(leg: str) -> None:
+    """Legs that start a JAX child process cannot run where this process
+    holds a TPU: the child would wait for the chip forever."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"controller_bench: {leg} starts a JAX child process, which "
+            f"cannot reach the TPU this process holds; run this leg on "
+            f"the CPU (JAX_PLATFORMS=cpu)")
+
+
 def bench_sharded(s: int = 65536, ticks: int = 10, reps: int = 3,
                   n_devices: int = 8) -> dict:
     """Lane-sharded vs single-device lockstep tick at fleet scale.
@@ -1180,6 +1196,7 @@ def bench_sharded(s: int = 65536, ticks: int = 10, reps: int = 3,
     carries ``platform``/``n_cores``/``host_fallback`` so the trajectory
     file documents which regime produced the number.
     """
+    _refuse_on_tpu("bench_sharded")
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count="
                          f"{n_devices}",
@@ -1303,7 +1320,7 @@ def run(quick: bool = False) -> dict:
         "megatick_no_retrace": megatick["n_compiles"] == [0, 1],
         # Parity and compile stability are asserted; speed is recorded
         # only (interpret mode on CPU — see bench_kernel_select).
-        "kernel_picks_identical": kernel["picks_identical"],
+        "kernel_picks_within_margin": kernel["picks_within_margin"],
         "kernel_no_retrace": kernel["no_retrace"],
         "faults_adaptation_beats_frozen":
             faults["adaptation_beats_frozen_all_kinds"],
@@ -1456,8 +1473,8 @@ def _print_kernel(kr: dict) -> None:
           f"{kr['pallas_us_per_decision']:.3f} us/dec "
           f"({kr['pallas_decisions_per_sec']:,.0f}/s) vs xla "
           f"{kr['xla_us_per_decision']:.3f} us/dec "
-          f"(ratio {kr['pallas_vs_xla']:.2f}x, picks identical "
-          f"{kr['picks_identical']}, compiles {kr['n_compiles']}, "
+          f"(ratio {kr['pallas_vs_xla']:.2f}x, picks within margin "
+          f"{kr['picks_within_margin']}, compiles {kr['n_compiles']}, "
           f"intensity "
           f"{kr['roofline']['arithmetic_intensity_flops_per_byte']:.0f} "
           f"FLOP/B)")
@@ -1471,12 +1488,12 @@ def main() -> list[tuple]:
         return []
     if "--kernel-smoke" in sys.argv:
         # CI smoke: the fused Pallas decision kernel in interpret mode at
-        # a reduced S — asserts bitwise pick parity with the XLA engine
+        # a reduced S — asserts margin pick parity with the XLA engine
         # and a flat compile count under churn, without touching
         # BENCH_controller.json.
         kr = bench_kernel_select(s=4096, ticks=4, block_s=1024)
         _print_kernel(kr)
-        assert kr["picks_identical"], \
+        assert kr["picks_within_margin"], \
             "kernel smoke: pallas picks diverged from XLA"
         assert kr["no_retrace"], \
             "kernel smoke: pallas backend re-traced under churn"

@@ -93,15 +93,15 @@ def run() -> dict:
         jax.jit(lambda r, k, v, w, u, s0: ref.rwkv_scan_ref(
             r, k, v, w, u, s0)), q, k_, v, w6, u, s0) * 1e6
 
-    # fused Pallas decision kernel (interpret mode off-TPU): one
-    # churning pick-only hetero tick at S=4096, bitwise parity + flat
+    # fused Pallas decision kernel (interpret mode on the CPU): one
+    # churning pick-only hetero tick at S=4096, margin pick parity + flat
     # compile count asserted inside; analytic roofline recorded
     # (docs/KERNELS.md).
     from benchmarks.controller_bench import bench_kernel_select
     out["alert_select"] = bench_kernel_select(s=4096, ticks=4,
                                               block_s=1024)
-    out["checks"]["alert_select_picks_identical"] = \
-        out["alert_select"]["picks_identical"]
+    out["checks"]["alert_select_picks_within_margin"] = \
+        out["alert_select"]["picks_within_margin"]
     out["checks"]["alert_select_no_retrace"] = \
         out["alert_select"]["no_retrace"]
     return out
@@ -120,8 +120,8 @@ def main() -> list[tuple]:
     print(f"  alert_select S={ks['n_streams']}: "
           f"{ks['pallas_us_per_decision']:.3f} us/dec "
           f"({'interpret' if ks['interpret'] else 'compiled'}), "
-          f"{ks['pallas_vs_xla']:.2f}x vs XLA, picks identical "
-          f"{ks['picks_identical']}")
+          f"{ks['pallas_vs_xla']:.2f}x vs XLA, picks within margin "
+          f"{ks['picks_within_margin']}")
     failed = [k for k, v in out["checks"].items() if not v]
     print("claim checks:", "ALL PASS" if not failed else f"FAIL: {failed}")
     rows = [
@@ -132,7 +132,7 @@ def main() -> list[tuple]:
         ("kernel_rwkv_ref", out["rwkv_ref_us"], "b2s256h4d64"),
         ("kernel_alert_select", ks["pallas_us_per_decision"],
          f"s4096;vs_xla={ks['pallas_vs_xla']:.2f}x;"
-         f"parity={ks['picks_identical']}"),
+         f"parity={ks['picks_within_margin']}"),
     ]
     return rows
 

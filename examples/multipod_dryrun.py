@@ -85,6 +85,12 @@ def main():
     ap.add_argument("--ticks", type=int, default=12,
                     help="[--fleet] churning fleet ticks to drive")
     args = ap.parse_args()
+    import jax
+
+    if jax.default_backend() == "tpu":
+        sys.exit("multipod_dryrun: the dry-runs compile on fake host "
+                 "devices in child processes, which cannot share this "
+                 "host's TPU; run with JAX_PLATFORMS=cpu")
     sys.exit(run_fleet(args) if args.fleet else run_model(args))
 
 

@@ -27,6 +27,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro.core.precision import x64_scope
+
 
 def _flatten(tree) -> tuple[list[tuple[str, Any]], Any]:
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -120,7 +122,10 @@ def restore(directory: str, like, shardings=None) -> tuple[Any, int]:
         tree = jax.tree.map(lambda x, s: jax.device_put(x, s), tree,
                             shardings)
     else:
-        tree = jax.tree.map(jax.numpy.asarray, tree)
+        # Keep each leaf's saved dtype: outside a 64-bit scope asarray
+        # would narrow float64/int64 leaves to 32 bits.
+        with x64_scope():
+            tree = jax.tree.map(jax.numpy.asarray, tree)
     return tree, manifest["step"]
 
 

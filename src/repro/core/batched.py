@@ -42,7 +42,7 @@ selection Eq. 4/5 with Section 3.3 relaxation) is evaluated here for
   device (the sharded filter banks) pass jax arrays and set
   ``as_arrays=True`` to keep the whole tick loop free of host gathers.
 
-Numerics: scoring runs in float64 under jax's *scoped* ``enable_x64`` (the
+Numerics: scoring runs in float64 under a *scoped* ``x64_scope()`` (the
 global flag is never touched), which makes the engine's decisions
 bit-identical to the float64 NumPy reference (:mod:`repro.core.reference`)
 across the parity sweep in ``benchmarks/controller_bench.py``.
@@ -63,7 +63,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from repro.core.precision import x64_scope
 
 from repro.core.profiles import ProfileTable
 
@@ -98,20 +98,43 @@ def goal_codes(goals) -> np.ndarray:
         for g in np.atleast_1d(arr)], dtype=np.int64)
 
 
+def _columns(x):
+    return [x[..., c] for c in range(x.shape[-1])]
+
+
 def _row_argmin(x):
     """First-occurrence argmin along the last axis.
 
-    Same semantics as ``jnp.argmin`` (ties -> lowest index), but built from
-    vectorised min + mask arithmetic: XLA CPU lowers variadic argmin/argmax
-    reduces to scalar loops, which at [S, K*L] costs ~10x the whole
-    estimation pass.  This formulation is a plain reduce + elementwise ops.
-    The index arithmetic stays int32 (column counts are tiny) so the
-    second reduce moves half the bytes of the f64 grid even under x64.
+    Same semantics as ``jnp.argmin`` (ties -> lowest index) on rows
+    without NaN, built as a running minimum over the columns: elementwise
+    compares and selects, no reduction.  XLA CPU lowers variadic argmin
+    reduces to scalar loops, and on the TPU, where float64 is emulated
+    as float32 pairs, a row-min reduce returned a value equal to no
+    element of its row (v5e, S=1, K·L=16), so an argmin built on
+    ``x == min(x)`` matched nothing.
     """
-    c = x.shape[-1]
-    mask = x == jnp.min(x, axis=-1, keepdims=True)
-    rev = (c - jnp.arange(c)).astype(jnp.int32)
-    return c - jnp.max(mask * rev, axis=-1)
+    cols = _columns(x)
+    best, idx = cols[0], jnp.zeros(cols[0].shape, jnp.int32)
+    for c, col in enumerate(cols[1:], start=1):
+        better = col < best
+        best = jnp.where(better, col, best)
+        idx = jnp.where(better, c, idx)
+    return idx
+
+
+def _row_max(x):
+    """Row maximum as a running elementwise max (see ``_row_argmin``
+    for why not a reduce), shaped ``[..., 1]``."""
+    return functools.reduce(jnp.maximum, _columns(x))[..., None]
+
+
+def _row_pick(x, idx):
+    """``x[row, idx[row]]`` by elementwise selects over the columns."""
+    cols = _columns(x)
+    out = cols[0]
+    for c, col in enumerate(cols[1:], start=1):
+        out = jnp.where(idx == c, col, out)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,8 +199,9 @@ class BatchedAlertEngine:
     fused jnp passes below) or ``"pallas"`` — the lane-tiled
     :func:`repro.kernels.alert_select.alert_select` kernel, which fuses
     estimation, the merged hetero score, and the argmin into one tiled
-    pass over ``[S, K, L]`` with bitwise-identical picks and predictions
-    (interpret mode off-TPU; docs/KERNELS.md).  Both backends share the
+    pass over ``[S, K, L]``, float32 when compiled, with the float64
+    picks on every lane outside its tie margins (interpret mode on the
+    CPU; docs/KERNELS.md).  Both backends share the
     same seams, runtime-array contracts, and jit-cache behaviour;
     :meth:`estimate` (the grid-returning debug API) always runs XLA.
     ``pallas_block_s`` overrides the kernel's lane-tile size (benchmarks
@@ -254,7 +278,7 @@ class BatchedAlertEngine:
 
         The kernel's contract matches ``_select_hetero_impl`` — one
         tiled pass fusing estimation, the merged hetero score, and the
-        argmin, bitwise-identical picks/predictions — so the hetero
+        argmin, picks under the margin contract — so the hetero
         seams are direct pass-throughs and the homogeneous seams build
         their all-active single-goal code vectors inside the trace.
         Under a lane mesh each implementation is wrapped in ``shard_map``
@@ -429,7 +453,7 @@ class BatchedAlertEngine:
         feas = en_f <= goal_val[:, None]
         any_f = feas.any(axis=1)
         acc_use = jnp.where(feas | ~any_f[:, None], acc_f, -jnp.inf)
-        best = acc_use.max(axis=1, keepdims=True)
+        best = _row_max(acc_use)
         score = jnp.where(best - acc_use <= 1e-12, en_f, jnp.inf)
         relaxed = jnp.where(any_f, RELAXED_NONE, RELAXED_POWER)
         return score, any_f, relaxed
@@ -443,10 +467,8 @@ class BatchedAlertEngine:
             z = jnp.zeros(s)
             return (pick // self._l, pick % self._l, z, z, z, any_f,
                     relaxed)
-        # One-hot gathers (XLA CPU gathers are row-by-row; this is one
-        # elementwise mul + reduce).
-        onehot = jnp.arange(kl) == pick[:, None]
-        gather = lambda a: jnp.sum(a.reshape(s, kl) * onehot, axis=1)
+        # Elementwise gathers (XLA CPU gathers are row-by-row).
+        gather = lambda a: _row_pick(a.reshape(s, kl), pick)
         return (pick // self._l, pick % self._l, gather(lat_mean),
                 gather(acc), gather(energy), any_f, relaxed)
 
@@ -515,7 +537,7 @@ class BatchedAlertEngine:
         # Eq. 5 lexicographic stage (see _score_max_accuracy); for Eq. 4
         # lanes the max is computed but unused.
         acc_use = jnp.where(feas | ~any_, acc_f, -jnp.inf)
-        best = acc_use.max(axis=1, keepdims=True)
+        best = _row_max(acc_use)
         sc_a = jnp.where(best - acc_use <= 1e-12, en_f, jnp.inf)
         # Eq. 4 score (see _score_min_energy), merged per lane.
         sc_e = jnp.where(any_, jnp.where(feas, en_f, jnp.inf), -acc_f)
@@ -588,7 +610,7 @@ class BatchedAlertEngine:
             args.append(active if isinstance(active, jax.Array)
                         else np.broadcast_to(np.asarray(active, bool),
                                              (s,)))
-        with enable_x64():
+        with x64_scope():
             out = self._estimate_jit(*args)
         return EstimateBatch(*(np.asarray(o) for o in out))
 
@@ -658,7 +680,7 @@ class BatchedAlertEngine:
                     "energy_goal"
                 raise ValueError(f"{self.goal} task needs {need}")
             fn = self._select_jit if predictions else self._select_pick_jit
-            with enable_x64():
+            with x64_scope():
                 out = fn(
                     self._vec(mu, s), self._vec(sigma, s, floor=1e-6),
                     self._vec(phi, s), self._vec(deadline, s),
@@ -686,7 +708,7 @@ class BatchedAlertEngine:
             eg = self._vec(0.0 if energy_goal is None else energy_goal, s)
             fn = self._select_hetero_jit if predictions else \
                 self._select_hetero_pick_jit
-            with enable_x64():
+            with x64_scope():
                 out = fn(
                     self._vec(mu, s), self._vec(sigma, s, floor=1e-6),
                     self._vec(phi, s), self._vec(deadline, s),
@@ -869,8 +891,7 @@ class WindowedGoalBank:
 
     def _where_reset(self, changed) -> None:
         """Clear window state on the ``changed`` lanes (device mode)."""
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with x64_scope():
             c = changed[:, None]
             self._buf = jnp.where(c, 0.0, self._buf)
             self._count = jnp.where(changed, 0, self._count)
@@ -881,11 +902,10 @@ class WindowedGoalBank:
         window (the scalar class's recreate-on-change semantics), other
         lanes keep their history."""
         if self.mesh is not None:
-            from jax.experimental import enable_x64
             from repro.core.kalman import _lane_put
             new = _lane_put(self.mesh, np.broadcast_to(
                 np.asarray(goals, dtype=np.float64), self.goal.shape))
-            with enable_x64():
+            with x64_scope():
                 changed = new != self.goal
                 self.goal = jnp.where(changed, new, self.goal)
             self._where_reset(changed)
@@ -905,7 +925,6 @@ class WindowedGoalBank:
         equal to the departed tenant's, which ``set_goals`` would keep."""
         lanes = np.asarray(lanes)
         if self.mesh is not None:
-            from jax.experimental import enable_x64
             from repro.core.kalman import _lane_put
             sel = np.zeros(self.goal.shape[0], bool)
             sel[lanes] = True
@@ -913,7 +932,7 @@ class WindowedGoalBank:
                 new = np.zeros(self.goal.shape[0])
                 new[lanes] = np.asarray(goal, dtype=np.float64)
                 sel_d, new_d = _lane_put(self.mesh, sel, new)
-                with enable_x64():
+                with x64_scope():
                     self.goal = jnp.where(sel_d, new_d, self.goal)
             else:
                 sel_d = _lane_put(self.mesh, sel)
@@ -943,7 +962,6 @@ class WindowedGoalBank:
         rewrite."""
         lanes = np.asarray(lanes)
         if self.mesh is not None:
-            from jax.experimental import enable_x64
             from repro.core.kalman import _lane_put
             s = self.goal.shape[0]
             sel = np.zeros(s, bool)
@@ -958,7 +976,7 @@ class WindowedGoalBank:
             pos[lanes] = state["pos"]
             sel_d, goal_d, buf_d, count_d, pos_d = _lane_put(
                 self.mesh, sel, goal, buf, count, pos)
-            with enable_x64():
+            with x64_scope():
                 self.goal = jnp.where(sel_d, goal_d, self.goal)
                 self._buf = jnp.where(sel_d[:, None], buf_d, self._buf)
                 self._count = jnp.where(sel_d, count_d, self._count)

@@ -183,8 +183,8 @@ def _jit_f64(fn):
 
     def call(*args):
         """Numpy-in/numpy-out dispatch of the jitted step under x64."""
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.core.precision import x64_scope
+        with x64_scope():
             out = jfn(*[np.asarray(a) for a in args])
         return tuple(np.asarray(o) for o in out)
 
@@ -221,8 +221,8 @@ def _jit_f64_sharded(fn, mesh, donate: tuple):
 
     def call(*args):
         """Device-in/device-out dispatch (donating state) under x64."""
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.core.precision import x64_scope
+        with x64_scope():
             return jfn(*[put(a) for a in args])
 
     _BANK_STEPS[key] = call
@@ -231,14 +231,14 @@ def _jit_f64_sharded(fn, mesh, donate: tuple):
 
 def _lane_put(mesh, *arrays):
     """device_put host arrays onto ``mesh`` lane-sharded, preserving f64
-    (dtype canonicalisation is scoped out via ``enable_x64``)."""
+    (dtype canonicalisation is scoped out via ``x64_scope``)."""
     import jax
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64_scope
 
     from repro.launch.mesh import lane_shardings
 
     lane, _ = lane_shardings(mesh)
-    with enable_x64():
+    with x64_scope():
         out = tuple(jax.device_put(np.asarray(a), lane) for a in arrays)
     return out if len(out) > 1 else out[0]
 
@@ -387,8 +387,8 @@ class _LaneBank:
         """Advance per-lane update counters by mask ``m`` (device add when
         either side lives on device — no host sync)."""
         if _is_jax_array(self.n_updates) or _is_jax_array(m):
-            from jax.experimental import enable_x64
-            with enable_x64():  # int64 counters stay int64
+            from repro.core.precision import x64_scope
+            with x64_scope():  # int64 counters stay int64
                 self.n_updates = self.n_updates + m
         else:
             self.n_updates += m
@@ -414,10 +414,10 @@ class _LaneBank:
         names = self._state_names + ("n_updates",)
         if self.mesh is not None:
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
+            from repro.core.precision import x64_scope
             sel = np.zeros(self.n_streams, bool)
             sel[lanes] = True
-            with enable_x64():
+            with x64_scope():
                 for name in names:
                     vals = np.zeros(self.n_streams,
                                     dtype=np.asarray(state[name]).dtype)
@@ -442,11 +442,11 @@ class _LaneBank:
         priors = self._priors()
         if self.mesh is not None:
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
+            from repro.core.precision import x64_scope
             sel = np.zeros(self.n_streams, bool)
             sel[lanes] = True
             sel = _lane_put(self.mesh, sel)
-            with enable_x64():  # keep the f64 state f64 (scoped, like steps)
+            with x64_scope():  # keep the f64 state f64 (scoped, like steps)
                 for name, prior in zip(self._state_names, priors):
                     setattr(self, name, jnp.where(sel, prior,
                                                   getattr(self, name)))
@@ -580,8 +580,8 @@ class SlowdownFilterBank(_LaneBank):
         convention as :attr:`SlowdownFilter.std`."""
         if _is_jax_array(self.sigma):
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
-            with enable_x64():
+            from repro.core.precision import x64_scope
+            with x64_scope():
                 return jnp.maximum(self.sigma, 1e-6)
         return np.maximum(self.sigma, 1e-6)
 

@@ -6,7 +6,7 @@ Public entry points, re-exported here:
   ``BatchedAlertEngine(backend="pallas")`` (plus its analytic roofline,
   :func:`alert_select_cost`);
 * the serving-side kernels via their backend-resolving wrappers in
-  :mod:`repro.kernels.ops` (interpret off-TPU, Mosaic on TPU,
+  :mod:`repro.kernels.ops` (interpret on the CPU, Mosaic elsewhere,
   ``backend="ref"`` for the pure-jnp oracles in :mod:`repro.kernels.ref`):
   :func:`nested_matmul`, :func:`flash_attention`,
   :func:`decode_attention`, :func:`rwkv_scan`.

@@ -4,161 +4,212 @@ One ``pl.pallas_call`` evaluates the whole per-tick decision — the
 Eq. 7/10 staircase accuracy expectation (erf probe grid contracted with
 the precomputed ``[K, K]`` staircase weight matrix), Eq. 9 energy, the
 Eq. 4/5 feasibility masks with the Section 3.3 relaxation fallback, the
-merged heterogeneous score grid, and the ``[K·L]`` argmin — in a single
-tiled pass over the ``[S, K, L]`` grid.  The XLA engine
-(:class:`repro.core.batched.BatchedAlertEngine`) materialises the full
-``[S, K, L]`` probe/accuracy/energy grids in HBM between fused stages;
-here every intermediate lives only for one lane tile.
+merged heterogeneous score, and the ``[K·L]`` argmin — in a single tiled
+pass.  The XLA engine (:class:`repro.core.batched.BatchedAlertEngine`)
+materialises the full ``[S, K, L]`` probe/accuracy/energy grids in HBM
+between fused stages; here every intermediate lives only for one lane
+tile.
 
-**Tiling.**  The grid is 1-D over lane blocks: ``grid = (S / bs,)`` with
-``bs`` lanes per program (``block_s``, default 256).  Per program the
-``[bs]`` state vectors stream in, the ``[K, L]`` latency/power tables and
-the ``[K, K]`` staircase weight matrix stay resident in VMEM (they are
-small replicated constants), and the ``[bs, K, L]`` probe math runs in
-registers/VMEM — nothing ``[S, K, L]``-shaped ever exists.  Lanes are
-independent, so the lane-block dimension is ``parallel``.
+**Layout.**  Lanes sit on the 128-wide minor axis: the ``[S]`` state
+vectors are padded and viewed as ``[S/128, 128]``, and each program
+takes a ``[rows, 128]`` tile (``rows`` a multiple of 8, the float32
+sublane tile).  The ``[K, L]`` latency/power tables and the ``[K, K]``
+staircase weights are read as scalars from SMEM, so every vector op is
+a lane-dense ``[rows, 128]`` elementwise op.  Three passes over the
+``K·L`` cells: (1) a loop over the power buckets computes the K finish
+CDFs, the statically unrolled ``[K, K]`` staircase contraction and Eq. 9
+energy, writes both grids to VMEM scratch and folds the any-feasible
+flag; (2) the Eq. 5 best-accuracy max; (3) the merged score with a
+running first-occurrence argmin in the engine's row-major ``(k, l)``
+order, carrying the pick's predictions.  Passes 2 and 3 loop over the
+K rows with the L buckets unrolled.
 
-**Numerics and parity.**  Probe math is float64, matching
-``core/batched.py`` op for op: the same sanitise → ``t_eff`` → erf →
-einsum → score → ``_row_argmin`` chain, with the block-sized staircase
-contraction ``einsum("ku,bul->bkl")`` bitwise-equal to the engine's
-full-fleet ``einsum("ku,sul->skl")`` (verified: elementwise ops are
-order-free and XLA keeps the contraction order; ``jnp.dot`` would NOT
-match).  Picks, feasibility, relax codes, and the per-pick prediction
-gathers are therefore bitwise identical to the XLA path — asserted by
-``tests/test_kernels.py``, the hypothesis suite, and the golden traces.
-
-**Interpret-mode contract.**  On non-TPU backends the kernel runs under
-the Pallas interpreter (``interpret=True`` — the grid/BlockSpec semantics
-execute as compiled XLA ops, so CPU CI exercises the exact kernel body).
-On TPU the same call compiles via Mosaic; float64 support there is
-hardware/toolchain-gated, so the TPU path is for real deployments to
-validate, while parity and CI run interpret mode.  See docs/KERNELS.md.
+**Precision.**  The kernel computes in the dtype of its float inputs.
+Mosaic has no float64, so the compiled kernel runs in float32; interpret
+mode (CPU) keeps the caller's dtype.  ``erf`` is XLA's float32 rational
+approximation, written out (Mosaic has no ``erf`` lowering).  Results
+therefore match the float64 XLA engine under a margin contract, not
+bitwise: picks, feasibility and relax codes agree on every lane whose
+decision clears the tie margins (:func:`clear_lanes`), and predictions
+agree within :data:`PRED_RTOL`.  docs/KERNELS.md gives the reasons for
+both numbers.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.batched import (GOAL_MIN_ENERGY, RELAXED_ACCURACY,
                                 RELAXED_NONE, RELAXED_POWER)
+from repro.core.precision import x64_scope
+from repro.kernels.ops import use_interpret
 
-_SQRT2 = math.sqrt(2.0)
+_INV_SQRT2 = 1.0 / math.sqrt(2.0)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 
-# Lane-tile defaults: 256 amortises interpret-mode grid-step overhead on
-# CPU while keeping the [bs, K, L] f64 tile ~1 MB for typical tables;
-# benchmarks raise block_s to 8192 where VMEM is not the constraint.
-DEFAULT_BLOCK_S = 256
-_MIN_BLOCK_S = 8
+# XLA's float32 erf: x * P(x^2) / Q(x^2) on x clamped to [-4, 4]
+# (max abs error 4.2e-7 in float32, 6.5e-8 evaluated in float64).
+_ERF_ALPHA = (-2.72614225801306e-10, 2.77068142495902e-08,
+              -2.10102402082508e-06, -5.69250639462346e-05,
+              -7.34990630326855e-04, -2.95459980854025e-03,
+              -1.60960333262415e-02)
+_ERF_BETA = (-1.45660718464996e-05, -2.13374055278905e-04,
+             -1.68282697438203e-03, -7.37332916720468e-03,
+             -1.42647390514189e-02)
 
+# The margin contract against the float64 XLA engine (docs/KERNELS.md).
+ACC_TIE_MARGIN = 1e-4       # absolute, on expected accuracy
+ENERGY_TIE_MARGIN = 1e-4    # relative, on expected energy
+PRED_RTOL = 1e-4            # predictions on lanes that clear the margins
+PRED_ATOL = 1e-6
 
-def _default_interpret() -> bool:
-    """Interpret everywhere but TPU (the CPU-CI fallback contract)."""
-    return jax.default_backend() != "tpu"
+_LANES = 128
+_SUBLANES = 8
+DEFAULT_BLOCK_S = _LANES * _SUBLANES
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _block_argmin(x):
-    """First-occurrence argmin along the last axis — the kernel twin of
-    ``core.batched._row_argmin`` (identical integer arithmetic, TPU-safe
-    2-D iota), so tie-breaks match the XLA engine bit for bit."""
-    c = x.shape[-1]
-    mask = x == jnp.min(x, axis=-1, keepdims=True)
-    rev = c - jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
-    return c - jnp.max(mask * rev, axis=-1)
+def _erf(x):
+    """XLA's float32 ``erf`` rational approximation, in ``x``'s dtype,
+    saturated to exactly +-1 for ``|x| >= 4`` (where erf is within 1.6e-8
+    of +-1), so a finish probability that is 0 or 1 in float64 is 0 or 1
+    here too and cells that tie exactly in the reference tie here."""
+    xc = jnp.clip(x, -4.0, 4.0)
+    x2 = xc * xc
+    p = _ERF_ALPHA[0]
+    for c in _ERF_ALPHA[1:]:
+        p = p * x2 + c
+    q = _ERF_BETA[0]
+    for c in _ERF_BETA[1:]:
+        q = q * x2 + c
+    return jnp.where(jnp.abs(x) >= 4.0, jnp.sign(x), xc * p / q)
 
 
 def _select_kernel(mu_ref, sd_ref, phi_ref, t_ref, ag_ref, eg_ref, gk_ref,
                    act_ref, lat_ref, pw_ref, w_ref,
                    i_ref, j_ref, lat_o_ref, acc_o_ref, en_o_ref, feas_ref,
-                   rel_ref, *, q_fail, overhead, paper_faithful,
-                   predictions):
-    """One lane tile: fused estimate + hetero score + argmin + gathers.
+                   rel_ref, acc_s, en_s, *, k, l, q_fail, overhead,
+                   paper_faithful, predictions):
+    """One ``[rows, 128]`` lane tile: estimate, hetero score, argmin.
 
-    Mirrors ``BatchedAlertEngine._select_hetero_impl`` exactly (same op
-    order — that is the bitwise-parity contract); the homogeneous paths
-    are the all-active single-goal special case.
+    The semantics of ``BatchedAlertEngine._select_hetero_impl``; the
+    homogeneous paths are its all-active single-goal special case.
     """
     # --- dead-lane sanitisation (DESIGN.md §5: garbage-immune) -------- #
     act = act_ref[...] != 0
     mu = jnp.where(act, mu_ref[...], 1.0)
     sd = jnp.where(act, sd_ref[...], 0.1)
     phi = jnp.where(act, phi_ref[...], 0.25)
-    t = jnp.where(act, t_ref[...], 1.0)
+    t = jnp.maximum(jnp.where(act, t_ref[...], 1.0) - overhead, 1e-9)
     ag = jnp.where(act, ag_ref[...], 0.0)
     eg = jnp.where(act, eg_ref[...], 0.0)
-    t_eff = jnp.maximum(t - overhead, 1e-9)
+    gk = gk_ref[...]
 
-    # --- estimation: Eq. 7 + Eq. 10 via the [K, K] contraction -------- #
-    lat = lat_ref[...]                                # [K, L] (VMEM)
-    t_ = t_eff[:, None, None]                         # [bs, 1, 1]
-    lat_mean = mu[:, None, None] * lat[None]          # [bs, K, L]
-    lat_std = jnp.maximum(sd[:, None, None] * lat[None], 1e-12)
-    z = (t_ - lat_mean) / lat_std
-    f = 0.5 * (1.0 + jax.scipy.special.erf(z / _SQRT2))
-    # Block-sized staircase contraction == the engine's full-fleet einsum
-    # bitwise (same contraction order; jnp.dot would differ in the ulp).
-    acc = q_fail + jnp.einsum("ku,bul->bkl", w_ref[...], f)
+    # Masks are built with logical ops: Mosaic cannot select between
+    # boolean vectors.
+    def feasible(acc, en):
+        """The lane's own goal constraint (Eq. 4 accuracy, Eq. 5
+        energy) on one cell."""
+        is_min = gk == GOAL_MIN_ENERGY
+        return (is_min & (acc >= ag)) | (~is_min & (en <= eg))
 
-    # --- Eq. 9 energy on the same tile -------------------------------- #
-    caps = pw_ref[...][None]                          # [1, K, L]
-    if paper_faithful:
-        t_run = jnp.minimum(lat_mean, t_)
-    else:
-        pdf = jnp.exp(-0.5 * z ** 2) * _INV_SQRT_2PI
-        t_run = lat_mean * f + t_ * (1.0 - f) - lat_std * pdf
-        t_run = jnp.clip(t_run, 0.0, t_)
-    phi_ = phi[:, None, None]
-    energy = caps * t_run + phi_ * caps * jnp.maximum(t_ - t_run, 0.0)
+    # --- pass 1, per power bucket: Eq. 7 + Eq. 10 accuracy through the
+    # statically unrolled [K, K] staircase contraction, Eq. 9 energy - #
+    def bucket(jj, any_f):
+        """Pass 1 for power bucket ``jj``: every model's cell."""
+        f, t_run = [], []
+        for u in range(k):
+            lm = mu * lat_ref[u, jj]
+            ls = jnp.maximum(sd * lat_ref[u, jj], 1e-12)
+            z = (t - lm) / ls
+            fu = 0.5 * (1.0 + _erf(z * _INV_SQRT2))
+            if paper_faithful:
+                tr = jnp.minimum(lm, t)
+            else:
+                pdf = jnp.exp(-0.5 * z * z) * _INV_SQRT_2PI
+                tr = jnp.clip(lm * fu + t * (1.0 - fu) - ls * pdf, 0.0, t)
+            f.append(fu)
+            t_run.append(tr)
+        for kk in range(k):
+            acc = q_fail + w_ref[kk, 0] * f[0]
+            for u in range(1, k):
+                acc = acc + w_ref[kk, u] * f[u]
+            p = pw_ref[kk, jj]
+            en = p * t_run[kk] + phi * p * jnp.maximum(t - t_run[kk], 0.0)
+            acc_s[kk, jj] = acc
+            en_s[kk, jj] = en
+            any_f = jnp.where(feasible(acc, en), 1, any_f)
+        return any_f
 
-    # --- merged hetero score + relaxation + ONE argmin ---------------- #
-    bs = mu.shape[0]
-    k, l = lat.shape
-    kl = k * l
-    acc_f = acc.reshape(bs, kl)
-    en_f = energy.reshape(bs, kl)
-    is_min = gk_ref[...] == GOAL_MIN_ENERGY
-    is_min_ = is_min[:, None]
-    feas = jnp.where(is_min_, acc_f >= ag[:, None], en_f <= eg[:, None])
-    any_f = feas.any(axis=1)
-    any_ = any_f[:, None]
-    acc_use = jnp.where(feas | ~any_, acc_f, -jnp.inf)
-    best = acc_use.max(axis=1, keepdims=True)
-    sc_a = jnp.where(best - acc_use <= 1e-12, en_f, jnp.inf)
-    sc_e = jnp.where(any_, jnp.where(feas, en_f, jnp.inf), -acc_f)
-    pick = _block_argmin(jnp.where(is_min_, sc_e, sc_a))
+    any_i = jax.lax.fori_loop(0, l, bucket,
+                              jnp.zeros(act.shape, jnp.int32))
+
+    def usable(kk, jj):
+        """Cell ``(kk, jj)``: accuracy, energy, feasibility, and its
+        accuracy where the Eq. 5 stage may use it (else -inf)."""
+        acc, en = acc_s[kk, jj], en_s[kk, jj]
+        feas = feasible(acc, en)
+        use = jnp.where(feas | (any_i == 0), acc, -jnp.inf)
+        return acc, en, feas, use
+
+    # --- pass 2: Eq. 5 lexicographic stage, best usable accuracy ----- #
+    def row_best(kk, best):
+        """Pass 2 over model row ``kk``."""
+        for jj in range(l):
+            best = jnp.maximum(best, usable(kk, jj)[3])
+        return best
+
+    best = jax.lax.fori_loop(0, k, row_best,
+                             jnp.full(act.shape, -jnp.inf, mu.dtype))
+
+    # --- pass 3: merged score, running first-occurrence argmin over the
+    # cells in row-major (k, l) order, the XLA engine's argmin order -- #
+    def row_pick(kk, carry):
+        """Pass 3 over model row ``kk``."""
+        top, pick, p_lat, p_acc, p_en = carry
+        for jj in range(l):
+            acc, en, feas, acc_use = usable(kk, jj)
+            sc_a = jnp.where(best - acc_use <= 1e-12, en, jnp.inf)
+            sc_e = jnp.where(any_i != 0, jnp.where(feas, en, jnp.inf),
+                             -acc)
+            score = jnp.where(gk == GOAL_MIN_ENERGY, sc_e, sc_a)
+            better = score < top
+            top = jnp.where(better, score, top)
+            pick = jnp.where(better, kk * l + jj, pick)
+            if predictions:
+                p_lat = jnp.where(better, mu * lat_ref[kk, jj], p_lat)
+                p_acc = jnp.where(better, acc, p_acc)
+                p_en = jnp.where(better, en, p_en)
+        return top, pick, p_lat, p_acc, p_en
+
+    zero = jnp.zeros(act.shape, mu.dtype)
+    _, pick, p_lat, p_acc, p_en = jax.lax.fori_loop(
+        0, k, row_pick, (jnp.full(act.shape, jnp.inf, mu.dtype),
+                         jnp.zeros(act.shape, jnp.int32), zero, zero, zero))
+
+    any_f = any_i != 0
     relaxed = jnp.where(any_f, RELAXED_NONE,
-                        jnp.where(is_min, RELAXED_ACCURACY, RELAXED_POWER))
+                        jnp.where(gk == GOAL_MIN_ENERGY, RELAXED_ACCURACY,
+                                  RELAXED_POWER))
     pick = jnp.where(act, pick, 0)
-    any_f = any_f & act
-    relaxed = jnp.where(act, relaxed, RELAXED_NONE)
-
-    i_ref[...] = (pick // l).astype(jnp.int32)
-    j_ref[...] = (pick % l).astype(jnp.int32)
-    feas_ref[...] = any_f.astype(jnp.int32)
-    rel_ref[...] = relaxed.astype(jnp.int32)
-    if predictions:
-        onehot = jax.lax.broadcasted_iota(jnp.int32, (1, kl), 1) \
-            == pick[:, None]
-        gather = lambda a: jnp.sum(a.reshape(bs, kl) * onehot, axis=1)
-        zero = lambda x: jnp.where(act, x, 0.0)
-        lat_o_ref[...] = zero(gather(lat_mean))
-        acc_o_ref[...] = zero(gather(acc))
-        en_o_ref[...] = zero(gather(energy))
-    else:
-        z0 = jnp.zeros_like(mu)
-        lat_o_ref[...] = z0
-        acc_o_ref[...] = z0
-        en_o_ref[...] = z0
+    i_ref[...] = pick // l
+    j_ref[...] = pick % l
+    feas_ref[...] = (any_f & act).astype(jnp.int32)
+    rel_ref[...] = jnp.where(act, relaxed, RELAXED_NONE).astype(jnp.int32)
+    lat_o_ref[...] = jnp.where(act, p_lat, 0.0)
+    acc_o_ref[...] = jnp.where(act, p_acc, 0.0)
+    en_o_ref[...] = jnp.where(act, p_en, 0.0)
 
 
 def alert_select(mu, sigma, phi, deadline, accuracy_goal, energy_goal,
@@ -178,58 +229,163 @@ def alert_select(mu, sigma, phi, deadline, accuracy_goal, energy_goal,
     ``q_fail``/``overhead``/``paper_faithful_energy`` the scalar engine
     constants (baked into the trace).
 
-    S is padded up to a ``block_s`` multiple with dead lanes inside the
-    trace (sanitised in-kernel, sliced off on return), so any fleet size
-    works and per-lane results are unaffected.  Returns the 7-tuple
-    ``(model_index, power_index, predicted_latency, predicted_accuracy,
-    predicted_energy, feasible, relaxed_code)`` with every element
-    bitwise-identical to the XLA engine; with ``predictions=False`` the
-    three prediction gathers are skipped (fields come back zero).
+    S is padded up to a whole number of ``[rows, 128]`` tiles with dead
+    lanes inside the trace (sanitised in-kernel, sliced off on return),
+    so any fleet size works and per-lane results do not depend on the
+    tiling.  ``block_s`` lanes per program are rounded up to a multiple
+    of 1024 (8 sublanes of 128).  Returns the 7-tuple ``(model_index,
+    power_index, predicted_latency, predicted_accuracy,
+    predicted_energy, feasible, relaxed_code)``, predictions in the
+    inputs' float dtype; with ``predictions=False`` the prediction
+    fields come back zero.
 
-    ``interpret=None`` resolves to the CPU-CI fallback (interpret mode
-    everywhere but TPU); pass ``False`` to force Mosaic compilation.
+    ``interpret=None`` runs the Pallas interpreter on the CPU and
+    compiles through Mosaic everywhere else; the compiled kernel
+    computes in float32.
     """
-    from repro.kernels._pallas_compat import CompilerParams
-
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = use_interpret()
     k, l = latency.shape
-    fvecs = [jnp.asarray(a, jnp.float64)
-             for a in (mu, sigma, phi, deadline, accuracy_goal,
-                       energy_goal)]
-    gk = jnp.asarray(goal_kind, jnp.int32)
-    act = jnp.asarray(active, jnp.int32)
+    fvecs = [jnp.asarray(a) for a in (mu, sigma, phi, deadline,
+                                      accuracy_goal, energy_goal)]
+    out_dt = jnp.result_type(*fvecs)
+    dt = out_dt if interpret else jnp.dtype(jnp.float32)
     s = fvecs[0].shape[0]
-    bs = min(int(block_s), _round_up(s, _MIN_BLOCK_S))
-    s_pad = _round_up(s, bs)
-    pad = s_pad - s
-    if pad:
-        fvecs = [jnp.pad(a, (0, pad)) for a in fvecs]
-        gk = jnp.pad(gk, (0, pad))
-        act = jnp.pad(act, (0, pad))           # pads are dead lanes
-    lane = pl.BlockSpec((bs,), lambda i: (i,))
-    const = lambda kk, ll: pl.BlockSpec((kk, ll), lambda i: (0, 0))
+    rows = -(-s // _LANES)
+    br = min(_round_up(-(-int(block_s) // _LANES), _SUBLANES),
+             _round_up(rows, _SUBLANES))
+    rows_pad = _round_up(rows, br)
+    pad = rows_pad * _LANES - s
+
+    def tile(a, dtype):
+        """``[S]`` -> padded ``[rows, 128]``; pads are dead lanes."""
+        return jnp.pad(a.astype(dtype), (0, pad)).reshape(rows_pad, _LANES)
+
+    args = [tile(a, dt) for a in fvecs]
+    args += [tile(jnp.asarray(goal_kind), jnp.int32),
+             tile(jnp.asarray(active), jnp.int32)]
+    args += [jnp.asarray(a, dt) for a in (latency, run_power, weights)]
+    lane = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kern = functools.partial(
-        _select_kernel, q_fail=float(q_fail), overhead=float(overhead),
+        _select_kernel, k=k, l=l, q_fail=float(q_fail),
+        overhead=float(overhead),
         paper_faithful=bool(paper_faithful_energy),
         predictions=bool(predictions))
-    f64 = jnp.dtype(jnp.float64)
     i32 = jnp.dtype(jnp.int32)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kern,
-        grid=(s_pad // bs,),
-        in_specs=[lane] * 8 + [const(k, l), const(k, l), const(k, k)],
+        grid=(rows_pad // br,),
+        in_specs=[lane] * 8 + [smem] * 3,
         out_specs=[lane] * 7,
-        out_shape=[jax.ShapeDtypeStruct((s_pad,), d)
-                   for d in (i32, i32, f64, f64, f64, i32, i32)],
-        compiler_params=CompilerParams(
+        out_shape=[jax.ShapeDtypeStruct((rows_pad, _LANES), d)
+                   for d in (i32, i32, dt, dt, dt, i32, i32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        scratch_shapes=[pltpu.VMEM((k, l, br, _LANES), dt)] * 2,
         interpret=interpret,
-    )(*fvecs, gk, act, jnp.asarray(latency, jnp.float64),
-      jnp.asarray(run_power, jnp.float64),
-      jnp.asarray(weights, jnp.float64))
-    i, j, lat_p, acc_p, en_p, feas, rel = (o[:s] for o in out)
-    return i, j, lat_p, acc_p, en_p, feas.astype(bool), rel
+        name="alert_select",
+    )
+    # Trace the body with 64-bit types only when it computes in float64:
+    # under a caller's x64 scope Python int literals would otherwise
+    # become int64, which Mosaic cannot lower.
+    with x64_scope(dt == jnp.float64):
+        out = call(*args)
+    i, j, lat_p, acc_p, en_p, feas, rel = (o.reshape(-1)[:s] for o in out)
+    return (i, j, lat_p.astype(out_dt), acc_p.astype(out_dt),
+            en_p.astype(out_dt), feas.astype(bool), rel)
+
+
+def clear_lanes(accuracy, energy, accuracy_goal, energy_goal, goal_kind,
+                active) -> np.ndarray:
+    """``[S]`` bool: lanes whose float64 decision clears the tie margins.
+
+    ``accuracy``/``energy`` are a reference's ``[S, K, L]`` float64
+    estimate grids (``BatchedAlertEngine.estimate`` or the scalar
+    reference); the goals, codes and mask are the select call's.  A lane
+    is *clear* when a perturbation of every cell by less than
+    :data:`ACC_TIE_MARGIN` in accuracy and :data:`ENERGY_TIE_MARGIN`
+    (relative) in energy cannot change its pick, feasibility or relax
+    code:
+
+    * no cell sits within the margin of the lane's feasibility threshold;
+    * Eq. 4 lanes: the winning energy beats the runner-up among feasible
+      cells (or, relaxed, the best accuracy beats the runner-up) by more
+      than the margin;
+    * Eq. 5 lanes: no usable cell's accuracy falls within the margin
+      below the best outside the reference's 1e-12 tie set, and the
+      tie set's lowest energy beats its runner-up by more than the
+      margin.
+
+    Dead lanes are always clear (their outputs are fixed nulls).
+    """
+    s = accuracy.shape[0]
+    acc = np.asarray(accuracy, np.float64).reshape(s, -1)
+    en = np.asarray(energy, np.float64).reshape(s, -1)
+    ag = np.asarray(accuracy_goal, np.float64)[:, None]
+    eg = np.asarray(energy_goal, np.float64)[:, None]
+    is_min = np.asarray(goal_kind) == GOAL_MIN_ENERGY
+    ma, me = ACC_TIE_MARGIN, ENERGY_TIE_MARGIN
+    with np.errstate(invalid="ignore"):
+        border = np.where(is_min[:, None], np.abs(acc - ag) <= ma,
+                          np.abs(en - eg) <= me * np.abs(eg)).any(axis=1)
+        feas = np.where(is_min[:, None], acc >= ag, en <= eg)
+        any_f = feas.any(axis=1)
+
+        def gap(x, mask):
+            """Winner of ``x`` over ``mask`` and its gap to the best
+            strictly worse value (inf when there is none).  Cells equal
+            to the winner tie exactly in float64, from identical or
+            saturated terms, and break to the first occurrence in both
+            implementations."""
+            x = np.where(mask, x, np.inf)
+            w = x.min(axis=1, keepdims=True)
+            second = np.where(x > w, x, np.inf).min(axis=1)
+            return second - w[:, 0], w[:, 0]
+
+        g_en, e1 = gap(en, feas)
+        g_acc, _ = gap(-acc, np.ones_like(feas))
+        clear_min = np.where(any_f, g_en > me * np.abs(e1), g_acc > ma)
+
+        usable = feas | ~any_f[:, None]
+        best = np.where(usable, acc, -np.inf).max(axis=1, keepdims=True)
+        tie = usable & (best - acc <= 1e-12)
+        near = usable & ~tie & (best - acc <= ma + 1e-12)
+        g_tie, e1t = gap(en, tie)
+        clear_max = ~near.any(axis=1) & (g_tie > me * np.abs(e1t))
+    clear = ~border & np.where(is_min, clear_min, clear_max)
+    return clear | ~np.asarray(active, bool)
+
+
+def margin_report(ref, got, clear, *, predictions=True) -> dict:
+    """Hold ``got`` to ``ref`` under the margin contract.
+
+    ``ref``/``got`` are select results in the kernel's 7-tuple order (a
+    tuple or a :class:`~repro.core.batched.DecisionBatch`); ``clear`` is
+    :func:`clear_lanes`.  Returns the counts a caller asserts on:
+    ``n_clear`` lanes, ``mismatches`` — clear lanes whose pick,
+    feasibility or relax code differ — ``n_differ`` lanes whose pick
+    differs at all, and ``pred_ok``, whether every clear lane's
+    predictions agree within :data:`PRED_RTOL`/:data:`PRED_ATOL`.
+    """
+    def fields(b):
+        """The 7 result arrays of a tuple or a DecisionBatch."""
+        if dataclasses.is_dataclass(b):
+            return [getattr(b, f.name) for f in dataclasses.fields(b)]
+        return list(b)
+
+    r = [np.asarray(a) for a in fields(ref)]
+    g = [np.asarray(a) for a in fields(got)]
+    clear = np.asarray(clear, bool)
+    pick = (r[0] != g[0]) | (r[1] != g[1])
+    bad = pick | (r[5] != g[5]) | (r[6] != g[6])
+    pred_ok = True
+    if predictions:
+        pred_ok = all(np.allclose(g[n][clear], r[n][clear], rtol=PRED_RTOL,
+                                  atol=PRED_ATOL) for n in (2, 3, 4))
+    return {"n_clear": int(clear.sum()),
+            "mismatches": int((bad & clear).sum()),
+            "n_differ": int(pick.sum()), "pred_ok": bool(pred_ok)}
 
 
 def alert_select_cost(s: int, k: int, l: int, *,
@@ -240,17 +396,18 @@ def alert_select_cost(s: int, k: int, l: int, *,
     ``[S, K, L]`` probe cell (latency/z/energy chains), the ``2·S·K²·L``
     staircase contraction, ~8 ops per cell for the merged score +
     reductions, and one erf per cell (counted as a transcendental, not a
-    FLOP).  Bytes are the streamed ``[S]`` vectors (8 f64 in, 3 f64 + 4
-    i32 out) — the ``[K, L]``/``[K, K]`` constants stay VMEM-resident, so
-    per-lane HBM traffic is O(1) while per-lane compute is O(K·L):
-    arithmetic intensity ~``K·L/4`` FLOP/byte, firmly compute-(VPU-)bound
-    for production tables.
+    FLOP).  Bytes are the streamed ``[S]`` vectors of the compiled
+    float32 kernel (6 float + 2 int32 in, 3 float + 4 int32 out, 4 bytes
+    each) — the ``[K, L]``/``[K, K]`` constants sit in SMEM, so per-lane
+    HBM traffic is O(1) while per-lane compute is O(K·L): arithmetic
+    intensity ~``K·L/2`` FLOP/byte, firmly compute-(VPU-)bound for
+    production tables.
     """
     cells = s * k * l
     flops = cells * (12 + 8) + 2 * s * k * k * l
     if predictions:
-        flops += 3 * s * k * l * 2          # one-hot gather mul+add
-    bytes_io = s * (8 * 8 + 3 * 8 + 4 * 4)
+        flops += 3 * s * k * l      # running selects of the predictions
+    bytes_io = s * (8 + 7) * 4
     return {
         "flops": float(flops),
         "bytes_accessed": float(bytes_io),

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -113,7 +112,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, qg, k, v)

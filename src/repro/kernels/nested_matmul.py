@@ -29,8 +29,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
-
 from repro.core.nesting import StripeSpec
 
 
@@ -110,7 +108,7 @@ def nested_matmul(x: jax.Array, w: jax.Array, in_spec: StripeSpec,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n_cols), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(limits, x, w[:, :n_cols])

@@ -24,10 +24,21 @@ import jax
 LANE_AXIS = "lanes"
 
 
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in ``Auto`` mode.  The sharding
+    rules here annotate inputs and outputs and let the compiler
+    propagate the rest; ``jax.make_mesh``'s default (``Explicit`` axes)
+    would instead demand an output sharding on every gather and
+    contraction that touches a sharded axis."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_lane_mesh(n_devices: int | None = None):
@@ -42,7 +53,7 @@ def make_lane_mesh(n_devices: int | None = None):
     :data:`LANE_AXIS`.
     """
     n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (LANE_AXIS,))
+    return _make_mesh((n,), (LANE_AXIS,))
 
 
 def lane_shardings(mesh):
@@ -82,13 +93,11 @@ def lane_shard_map(fn, mesh, *, n_in: int, n_out: int):
     per-device lane launch — the Pallas select backend and the traffic
     megatick's in-scan select both wrap through here, so the
     no-collectives contract (the decision grid has no cross-lane op —
-    DESIGN.md §6) is enforced in one place (``check_rep=False``: the
+    DESIGN.md §6) is enforced in one place (``check_vma=False``: the
     kernels return unreplicated per-shard outputs)."""
-    from jax.experimental.shard_map import shard_map
-
     p = lane_pspec(mesh)
-    return shard_map(fn, mesh=mesh, in_specs=(p,) * n_in,
-                     out_specs=(p,) * n_out, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(p,) * n_in,
+                         out_specs=(p,) * n_out, check_vma=False)
 
 
 def batch_axes(mesh) -> tuple[str, ...]:
@@ -102,4 +111,4 @@ def make_host_mesh(model_parallel: int = 1):
     mp = model_parallel
     while mp > 1 and n % mp:
         mp //= 2
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _make_mesh((n // mp, mp), ("data", "model"))

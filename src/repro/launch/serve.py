@@ -2,12 +2,14 @@
 and run the ALERT runtime over a synthetic request stream.
 
     PYTHONPATH=src python -m repro.launch.serve --arch alert-anytime-120m \
-        --reduced --requests 40 [--ckpt-dir /tmp/repro_ckpt] \
+        [--reduced] --requests 40 [--ckpt-dir DIR] \
         [--goal max_acc|min_energy] [--deadline-scale 1.2]
 
 This is the production shape of examples/serve_alert.py: checkpoint
 restore, level profiling, deadline-EDF batching, the Kalman/staircase
-controller, and a per-phase report.
+controller, and a per-phase report.  The model runs at its published
+widths, vocabulary and dtype; ``--reduced`` serves the small test-size
+variant in float32 with a 32-token vocabulary instead.
 """
 
 from __future__ import annotations
@@ -22,17 +24,43 @@ from repro import configs
 from repro.checkpoint import io as ckpt_io
 from repro.core.controller import Constraints, Goal
 from repro.data.synthetic import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving.alert_server import AlertServer
 from repro.serving.engine import ServeEngine
 from repro.train.losses import token_accuracy
 
 
+def build_served_model(arch: str, *, reduced: bool = False, seed: int = 0):
+    """``(cfg, model, params)`` for serving ``arch``: the published config,
+    or with ``reduced`` the small variant in float32 with a 32-token
+    vocabulary; weights drawn from ``seed``.  A model without nesting
+    levels gets two, so the controller has a staircase to pick from."""
+    if reduced:
+        cfg = configs.get_reduced(arch).replace(dtype="float32", vocab=32)
+    else:
+        cfg = configs.get_config(arch)
+    if cfg.nest_levels <= 1:
+        cfg = cfg.replace(nest_levels=2)
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(seed))
+
+
+def level_accuracies(model, params, data: SyntheticLM) -> list[float]:
+    """Token accuracy of every nesting level on one held-out batch."""
+    evalb = {k: jax.numpy.asarray(v)
+             for k, v in data.batch_at(10_000).items()}
+    return [float(token_accuracy(
+        model.train_logits(params, evalb, level=k)[0], evalb["labels"]))
+        for k in range(1, model.cfg.nest_levels + 1)]
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="alert-anytime-120m",
                     choices=configs.ALL_IDS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the small test-size variant")
     ap.add_argument("--requests", type=int, default=40)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--goal", default="max_acc",
@@ -45,13 +73,9 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
-    cfg = configs.get_reduced(args.arch).replace(dtype="float32", vocab=32)
-    if cfg.nest_levels <= 1:
-        cfg = cfg.replace(nest_levels=2)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    enable_compile_cache()
+    cfg, model, params = build_served_model(args.arch, reduced=args.reduced)
     if args.ckpt_dir and os.path.exists(args.ckpt_dir):
-        from repro.train.step import TrainState  # noqa: F401
         try:
             restored, step = ckpt_io.restore(args.ckpt_dir, params)
             params = restored
@@ -63,12 +87,7 @@ def main() -> None:
     # measure per-level accuracy on held-out synthetic data
     data = SyntheticLM(vocab=cfg.vocab, seq_len=32,
                        global_batch=args.batch, noise=0.05)
-    evalb = {k: jax.numpy.asarray(v)
-             for k, v in data.batch_at(10_000).items()}
-    accs = []
-    for k in range(1, cfg.nest_levels + 1):
-        logits, _ = model.train_logits(params, evalb, level=k)
-        accs.append(float(token_accuracy(logits, evalb["labels"])))
+    accs = level_accuracies(model, params, data)
     print(f"[serve] level accuracies: "
           + " ".join(f"L{i + 1}={a:.3f}" for i, a in enumerate(accs)))
 

@@ -178,7 +178,7 @@ class FleetAlertServer:
     re-trace-free (DESIGN.md §6).
 
     ``backend="pallas"`` scores ticks through the fused ``alert_select``
-    kernel instead of the XLA passes — bitwise-identical picks, same
+    kernel instead of the XLA passes — margin-contract picks, same
     churn/no-retrace contract (docs/KERNELS.md).
     """
 

@@ -65,6 +65,15 @@ class ServeEngine:
             return tfm.init_caches(lvl_cfg, self.batch_size, self.max_len)
         return self.model.init_caches(self.batch_size, self.max_len)
 
+    def prefill(self, params, prompt, level: int | None = None):
+        """Run ``level``'s compiled prefill program on ``prompt`` [B, S0]
+        (the deepest level when ``level`` is None); returns the model's
+        :class:`~repro.models.transformer.LMOutput`."""
+        cfg = self.model.cfg
+        lvl = level if level is not None else \
+            (cfg.nest_levels if cfg.nest_levels > 1 else None)
+        return self._prefill[lvl](params, {"tokens": jnp.asarray(prompt)})
+
     def n_compiles(self) -> tuple[int, int]:
         """(prefill, decode) trace counts summed across level executables.
 
@@ -99,7 +108,7 @@ class ServeEngine:
         lvl = level if level is not None else \
             (cfg.nest_levels if cfg.nest_levels > 1 else None)
         b, s0 = prompt.shape
-        out = self._prefill[lvl](params, {"tokens": jnp.asarray(prompt)})
+        out = self.prefill(params, prompt, lvl)
         caches = self._merge(self.init_caches(lvl), out.caches)
         logits = out.logits if not isinstance(out.logits, list) \
             else out.logits[-1]

@@ -472,7 +472,7 @@ def deliver_step(i_glob, j_act, scale, dvec, phi_true, *,
 
     # The constants arrive as numpy (indexable by tracers only as jnp
     # arrays); asarray at trace time is free and keeps f64 under the
-    # caller's enable_x64 scope.
+    # caller's x64 scope.
     latency_kl = jnp.asarray(latency_kl)
     run_power_kl = jnp.asarray(run_power_kl)
     is_anytime_k = jnp.asarray(is_anytime_k)
@@ -718,9 +718,8 @@ class FleetSim:
 
         ``backend`` forwards to :class:`BatchedAlertEngine` —
         ``"pallas"`` scores every tick through the fused
-        ``alert_select`` kernel with bitwise-identical picks, so whole
-        trajectories (including the golden traces) reproduce exactly
-        (docs/KERNELS.md).
+        ``alert_select`` kernel, whose picks match the XLA engine's on
+        every lane outside the kernel's tie margins (docs/KERNELS.md).
 
         ``faults`` (a :class:`~repro.traffic.faults.FaultSchedule` over
         ``n_streams`` lanes — this sim is lane-per-stream) injects

@@ -239,7 +239,7 @@ class SessionGateway:
     through the identical clock/queue/delivery path (the hindsight
     ``oracle_static`` baseline of ``repro.traffic.loadsweep``).
     ``backend`` forwards to the engine (``"pallas"`` scores rounds with
-    the fused ``alert_select`` kernel — bitwise-identical picks, same
+    the fused ``alert_select`` kernel — margin-contract picks, same
     no-retrace paging contract; docs/KERNELS.md).
     """
 

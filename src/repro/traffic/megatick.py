@@ -67,6 +67,7 @@ from repro.core.batched import (BatchedAlertEngine, _goal_record_step,
                                 goal_codes, goal_current_step_hostsum)
 from repro.core.kalman import (IdlePowerFilterBank, SlowdownFilterBank,
                                fused_fleet_step)
+from repro.core.precision import x64_scope
 from repro.core.profiles import ProfileTable
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.ring import round_aggregates
@@ -624,7 +625,6 @@ class MegatickGateway:
         first-touch ``reset_lanes`` installs), so first-round behaviour
         matches the host gateway bit for bit."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         s = len(sessions)
         slow = SlowdownFilterBank(s)
@@ -633,7 +633,7 @@ class MegatickGateway:
         goal0 = np.asarray(
             [sess.constraints.accuracy_goal or 0.0 for sess in sessions],
             dtype=np.float64)
-        with enable_x64():
+        with x64_scope():
             carry = tuple(jnp.asarray(a) for a in (
                 slow.mu, slow.sigma, slow.gain, slow.process_noise,
                 idle.phi, idle.variance,
@@ -671,7 +671,6 @@ class MegatickGateway:
             raise ValueError(
                 f"FaultSchedule covers {faults.n_lanes} lanes but the "
                 f"gateway has {self.n_lanes}")
-        from jax.experimental import enable_x64
 
         ob = self._ob
         t0 = time.perf_counter()
@@ -684,7 +683,7 @@ class MegatickGateway:
         out = plan.out
         if plan.n_active:
             fn = self._chunk_fn(policy, static_config, ring=ob is not None)
-            with enable_x64():
+            with x64_scope():
                 if policy == "alert":
                     carry, goal = self._init_carry(sessions)
                 for lo in range(0, plan.act.shape[0], self.chunk):

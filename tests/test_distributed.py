@@ -92,8 +92,9 @@ class TestMiniDryrun:
             from repro.launch import shardings as sh
             from repro.models.registry import build_model
             from repro.optim.adamw import AdamW
+            from repro.launch.mesh import make_host_mesh
             from repro.train.step import init_train_state, make_train_step
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_host_mesh(model_parallel=2)
             cfg = configs.get_reduced("{arch}").replace(
                 dtype="float32", vocab=64)
             model = build_model(cfg)

@@ -585,8 +585,8 @@ class TestCheckpointIO:
         ckpt_io.save(d, tree, step=7, extra={"tag": "x"})
         # restore returns jax arrays; x64 scoped on, the repo
         # discipline, so f64 leaves round-trip without downcast
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.core.precision import x64_scope
+        with x64_scope():
             got, step = ckpt_io.restore(d, tree)
         assert step == 7
         flat_a = jax.tree_util.tree_leaves(tree)
@@ -657,8 +657,8 @@ class TestCheckpointIO:
         tree = {"mu": np.linspace(1, 3, 8), "sigma": np.ones(8)}
         d = str(tmp_path / "ck")
         ckpt_io.save(d, tree, step=4)
-        from jax.experimental import enable_x64
-        with enable_x64():
+        from repro.core.precision import x64_scope
+        with x64_scope():
             got, step = ckpt_io.restore(
                 d, tree, shardings={"mu": sharded, "sigma": sharded})
         assert step == 4

@@ -1,18 +1,20 @@
 """Per-kernel interpret-mode validation: sweep shapes/dtypes, allclose vs
 the pure-jnp oracle in ref.py — plus the fused `alert_select` decision
-kernel, which is held to a stricter bar: BITWISE pick/prediction parity
-against the XLA engine (docs/KERNELS.md)."""
+kernel, held to the float64 XLA engine under the margin contract: equal
+picks on every lane that clears the tie margins, predictions within a
+stated tolerance (docs/KERNELS.md)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.batched import BatchedAlertEngine
 from repro.core.nesting import StripeSpec
+from repro.core.precision import x64_scope
 from repro.kernels import ref
-from repro.kernels.alert_select import alert_select, alert_select_cost
+from repro.kernels.alert_select import (alert_select, alert_select_cost,
+                                        clear_lanes, margin_report)
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.nested_matmul import nested_matmul, nested_matmul_flops
@@ -171,12 +173,16 @@ def _hetero_state(rng, table, s, garbage=np.nan):
     return state
 
 
-def _kernel_out(engine, state, **kw):
-    """Run the raw kernel with an engine's baked constants."""
-    with enable_x64():
+def _kernel_out(engine, state, *, x64=True, **kw):
+    """Run the raw kernel with an engine's baked constants: float64
+    inputs by default, float32 inputs (the chip's precision) with
+    ``x64=False``."""
+    dt = np.float64 if x64 else np.float32
+    with x64_scope(x64):
         out = alert_select(
-            state["mu"], state["sigma"], state["phi"], state["deadline"],
-            state["accuracy_goal"], state["energy_goal"],
+            *(np.asarray(state[n], dt) for n in (
+                "mu", "sigma", "phi", "deadline", "accuracy_goal",
+                "energy_goal")),
             state["goal_kind"], state["active"],
             latency=engine._c_latency, run_power=engine._c_run_power,
             weights=engine._c_weights, q_fail=engine._c_q_fail,
@@ -184,20 +190,34 @@ def _kernel_out(engine, state, **kw):
     return [np.asarray(o) for o in out]
 
 
-def _assert_bitwise(batch, out):
-    i, j, lat, acc, en, feas, rel = out
-    assert np.array_equal(i, batch.model_index)
-    assert np.array_equal(j, batch.power_index)
-    assert np.array_equal(feas, batch.feasible)
-    assert np.array_equal(rel, batch.relaxed_code)
-    assert np.array_equal(lat, batch.predicted_latency)
-    assert np.array_equal(acc, batch.predicted_accuracy)
-    assert np.array_equal(en, batch.predicted_energy)
+def _reference(engine, state):
+    """The float64 XLA engine's decisions on ``state`` and the lanes
+    whose decision clears the tie margins."""
+    st = state
+    batch = engine.select(st["mu"], st["sigma"], st["phi"], st["deadline"],
+                          accuracy_goal=st["accuracy_goal"],
+                          energy_goal=st["energy_goal"],
+                          goal_kind=st["goal_kind"], active=st["active"])
+    est = engine.estimate(st["mu"], np.maximum(st["sigma"], 1e-6),
+                          st["phi"],
+                          np.maximum(st["deadline"] - engine.overhead, 1e-9),
+                          active=st["active"])
+    return batch, clear_lanes(est.accuracy, est.energy,
+                              st["accuracy_goal"], st["energy_goal"],
+                              st["goal_kind"], st["active"])
+
+
+def _assert_margin_parity(batch, clear, out, min_clear=0.5):
+    rep = margin_report(batch, out, clear)
+    assert rep["mismatches"] == 0, rep
+    assert rep["pred_ok"], rep
+    assert rep["n_clear"] >= min_clear * len(clear), rep
 
 
 class TestAlertSelect:
-    """Fused decision kernel vs the XLA engine: BITWISE equality of
-    picks, feasibility, relax codes, and prediction gathers."""
+    """Fused decision kernel vs the float64 XLA engine under the margin
+    contract: picks, feasibility and relax codes equal on every lane
+    that clears the tie margins, predictions within tolerance."""
 
     @pytest.mark.parametrize("s", [1, 5, 64, 257])
     def test_bitwise_parity_hetero(self, s):
@@ -207,13 +227,9 @@ class TestAlertSelect:
         engine = BatchedAlertEngine(
             table, None, overhead=0.1 * float(np.median(table.latency)))
         st = _hetero_state(rng, table, s)
-        batch = engine.select(st["mu"], st["sigma"], st["phi"],
-                              st["deadline"],
-                              accuracy_goal=st["accuracy_goal"],
-                              energy_goal=st["energy_goal"],
-                              goal_kind=st["goal_kind"],
-                              active=st["active"])
-        _assert_bitwise(batch, _kernel_out(engine, st, block_s=64))
+        batch, clear = _reference(engine, st)
+        _assert_margin_parity(batch, clear, _kernel_out(engine, st),
+                              min_clear=0.5 if s > 5 else 0.0)
 
     @pytest.mark.parametrize("garbage", [np.nan, np.inf, -np.inf, 1e300])
     def test_dead_lane_garbage_is_inert(self, garbage):
@@ -222,20 +238,27 @@ class TestAlertSelect:
         table = random_table(rng)
         engine = BatchedAlertEngine(table, None)
         st = _hetero_state(rng, table, 33, garbage=garbage)
-        i, j, lat, acc, en, feas, rel = _kernel_out(engine, st)
+        out = _kernel_out(engine, st)
+        i, j, lat, acc, en, feas, rel = out
         dead = ~st["active"]
         assert np.all(i[dead] == 0) and np.all(j[dead] == 0)
         assert not feas[dead].any() and np.all(rel[dead] == 0)
         assert np.all(lat[dead] == 0.0) and np.all(en[dead] == 0.0)
-        live = st["active"]
-        batch = engine.select(st["mu"], st["sigma"], st["phi"],
-                              st["deadline"],
-                              accuracy_goal=st["accuracy_goal"],
-                              energy_goal=st["energy_goal"],
-                              goal_kind=st["goal_kind"],
-                              active=st["active"])
-        assert np.array_equal(i[live], batch.model_index[live])
-        assert np.array_equal(j[live], batch.power_index[live])
+        batch, clear = _reference(engine, st)
+        _assert_margin_parity(batch, clear, out)
+
+    def test_float32_inputs_hold_the_margin_contract(self):
+        """Float32 inputs run the kernel in float32, the precision of the
+        compiled kernel on the chip: same contract against float64."""
+        from benchmarks.controller_bench import random_table
+        rng = np.random.default_rng(17)
+        table = random_table(rng)
+        engine = BatchedAlertEngine(table, None)
+        st = _hetero_state(rng, table, 300)
+        out = _kernel_out(engine, st, x64=False)
+        assert out[2].dtype == np.float32
+        batch, clear = _reference(engine, st)
+        _assert_margin_parity(batch, clear, out)
 
     def test_block_size_invariance(self):
         """Lane tiling must not change a single bit of any output."""
@@ -243,9 +266,9 @@ class TestAlertSelect:
         rng = np.random.default_rng(11)
         table = random_table(rng)
         engine = BatchedAlertEngine(table, None)
-        st = _hetero_state(rng, table, 200)
+        st = _hetero_state(rng, table, 3000)
         outs = [_kernel_out(engine, st, block_s=bs)
-                for bs in (8, 64, 256, 1024)]
+                for bs in (1024, 2048, 4096)]
         for o in outs[1:]:
             for a, b in zip(o, outs[0]):
                 assert np.array_equal(a, b)

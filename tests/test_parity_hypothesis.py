@@ -50,9 +50,12 @@ def check_select_parity(table_seed: int, lanes: list[dict],
     """One heterogeneous masked select vs per-lane scalar references.
 
     ``backend="pallas"`` runs the same property through the fused
-    `alert_select` kernel: the reference is the shared oracle, so kernel
-    == reference here plus engine == reference above proves the
-    kernel/XLA bitwise pick parity on every drawn fleet."""
+    `alert_select` kernel under its margin contract: the pick checks
+    apply to every lane whose float64 decision clears the tie margins
+    (docs/KERNELS.md); dead lanes and the float64 estimates are checked
+    as for XLA."""
+    from repro.kernels.alert_select import clear_lanes
+
     rng = np.random.default_rng(table_seed)
     table = random_table(rng)
     med_lat = float(np.median(table.latency))
@@ -78,6 +81,8 @@ def check_select_parity(table_seed: int, lanes: list[dict],
                           energy_goal=egs, goal_kind=gk, active=active)
     est = engine.estimate(mus, sds, phis,
                           np.maximum(dls - overhead, 1e-9), active=active)
+    clear = clear_lanes(est.accuracy, est.energy, qgs, egs, gk, active) \
+        if backend == "pallas" else np.ones(s, bool)
     for i in range(s):
         if not active[i]:
             assert int(batch.model_index[i]) == 0
@@ -98,15 +103,17 @@ def check_select_parity(table_seed: int, lanes: list[dict],
             if goal is Goal.MINIMIZE_ENERGY \
             else {"energy_goal": float(egs[i])}
         d = ref.select(Constraints(deadline=float(dls[i]), **kw))
-        assert d.model_index == int(batch.model_index[i]), f"lane {i}"
-        assert d.power_index == int(batch.power_index[i]), f"lane {i}"
-        assert d.feasible == bool(batch.feasible[i]), f"lane {i}"
-        assert d.relaxed == RELAXED_NAMES[int(batch.relaxed_code[i])]
         e = ref.estimate(max(float(dls[i]) - overhead, 1e-9))
         np.testing.assert_allclose(est.accuracy[i], e.accuracy,
                                    rtol=0, atol=1e-12)
         np.testing.assert_allclose(est.energy[i], e.energy,
                                    rtol=1e-12, atol=1e-12)
+        if not clear[i]:
+            continue
+        assert d.model_index == int(batch.model_index[i]), f"lane {i}"
+        assert d.power_index == int(batch.power_index[i]), f"lane {i}"
+        assert d.feasible == bool(batch.feasible[i]), f"lane {i}"
+        assert d.relaxed == RELAXED_NAMES[int(batch.relaxed_code[i])]
 
 
 def check_masked_bank_parity(seed: int, n_streams: int,
@@ -167,8 +174,8 @@ def test_select_parity_random_fleets(data):
 def test_select_parity_random_fleets_pallas(data):
     """The fused Pallas kernel under the same property: random hetero
     fleets, garbage-laden dead lanes, both relaxation branches — picks
-    bitwise-equal to the scalar reference (and hence to the XLA
-    engine)."""
+    equal to the scalar reference on every lane that clears the tie
+    margins."""
     table_seed = data.draw(st.integers(0, 2**31 - 1))
     n = data.draw(st.integers(1, 8))
     lanes = [_draw_lane(data) for _ in range(n)]

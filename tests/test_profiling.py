@@ -355,11 +355,14 @@ class TestLiveProfile:
         from repro.core.controller import Constraints, Goal
         from repro.serving.sim import DEFAULT_ENV
         from repro.traffic import PoissonProcess, TenantSpec, sweep_loads
+        from tests.make_golden_traces import live_accuracy_goal
         table = live_cfg[0]
         dl = 2.0 * float(table.latency[-1, -1])
         n_lanes, n_sessions = 16, 48
         mix = [TenantSpec("t", Goal.MINIMIZE_ENERGY,
-                          Constraints(deadline=dl, accuracy_goal=0.40),
+                          Constraints(deadline=dl,
+                                      accuracy_goal=live_accuracy_goal(
+                                          table)),
                           PoissonProcess(0.5 * (n_lanes / dl)
                                          / n_sessions),
                           n_sessions=n_sessions, phases=DEFAULT_ENV)]

@@ -576,13 +576,13 @@ class TestMegatickKernels:
         """The traced window sum reproduces numpy's pairwise-summation
         order exactly, at every depth the recursion changes shape."""
         import jax
-        from jax.experimental import enable_x64
+        from repro.core.precision import x64_scope
         from repro.core.batched import pairwise_sum_cols
 
         rng = np.random.default_rng(depth)
         buf = rng.uniform(-1.0, 1.0, (7, depth))
         want = buf.sum(axis=1)
-        with enable_x64():
+        with x64_scope():
             got = np.asarray(jax.jit(
                 lambda b: pairwise_sum_cols(
                     [b[:, c] for c in range(b.shape[1])]))(buf))
@@ -592,7 +592,7 @@ class TestMegatickKernels:
         """Traced effective-goal compensation == the host bank's numpy
         path, including the runtime-zero FMA-contraction guard."""
         import jax
-        from jax.experimental import enable_x64
+        from repro.core.precision import x64_scope
         from repro.core.batched import goal_current_step_hostsum
 
         rng = np.random.default_rng(7)
@@ -602,7 +602,7 @@ class TestMegatickKernels:
             bank.record(rng.uniform(0.0, 1.0, s),
                         mask=rng.random(s) < 0.7)
         want = bank.current_goal()
-        with enable_x64():
+        with x64_scope():
             got = np.asarray(jax.jit(goal_current_step_hostsum,
                                      static_argnums=3)(
                 bank.goal, bank._buf, bank._count, window, 0.0))
@@ -613,7 +613,7 @@ class TestMegatickKernels:
         under jit (where XLA's FMA contraction would bite without the
         runtime-zero guard)."""
         import jax
-        from jax.experimental import enable_x64
+        from repro.core.precision import x64_scope
         from repro.serving.sim import deliver_step, deliver_tick
 
         st = table.staircase_tensors()
@@ -634,7 +634,7 @@ class TestMegatickKernels:
                       q_fail=float(table.q_fail), is_anytime_k=is_any,
                       lvl_lat_kml=st.lvl_lat, lvl_valid_km=st.lvl_valid,
                       lvl_acc_km=st.lvl_acc)
-        with enable_x64():
+        with x64_scope():
             got = jax.jit(lambda ii, jj, sc, dv, fz: deliver_step(
                 ii, jj, sc, dv, 0.25, f_zero=fz, **consts))(
                     i, j, scale, dvec, 0.0)
