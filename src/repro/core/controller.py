@@ -34,6 +34,7 @@ from scipy.special import erf as _erf
 
 from repro.core.kalman import IdlePowerFilter, SlowdownFilter
 from repro.core.profiles import ProfileTable
+from repro.obs.trace import span as obs_span
 
 _SQRT2 = math.sqrt(2.0)
 
@@ -153,14 +154,20 @@ class AlertController:
         If True (default) use Eq. 9 verbatim (mean-latency energy).  If
         False, use E[min(t, T)] under the Normal model — a strictly better
         estimator we evaluate as a beyond-paper variant in benchmarks.
+    obs:
+        Optional :class:`~repro.obs.FlightRecorder` (a pure observer):
+        :meth:`select` records the batched engine's pick as a span
+        ``engine_select``, which leaves the goal adjustment and the
+        decision's host bookkeeping outside.
     """
 
     def __init__(self, table: ProfileTable, goal: Goal,
                  kappa: float = 3.0, overhead: float = 0.0,
                  accuracy_window: int = 10,
-                 paper_faithful_energy: bool = True):
+                 paper_faithful_energy: bool = True, obs=None):
         from repro.core.batched import BatchedAlertEngine
 
+        self.obs = obs
         self.table = table
         self.goal = goal
         self.kappa = kappa
@@ -240,10 +247,12 @@ class AlertController:
 
         # Eq. 4 / Eq. 5 + Section 3.3 relaxation, fused with estimation in
         # one engine pass (the engine subtracts ``overhead`` from T_goal).
-        batch = self.engine.select(
-            self.slowdown.mu, self.slowdown.sigma, self.idle_power.phi,
-            np.asarray([constraints.deadline]),
-            accuracy_goal=q_goal_eff, energy_goal=constraints.energy_goal)
+        with obs_span(self.obs, "engine_select", "controller"):
+            batch = self.engine.select(
+                self.slowdown.mu, self.slowdown.sigma,
+                self.idle_power.phi, np.asarray([constraints.deadline]),
+                accuracy_goal=q_goal_eff,
+                energy_goal=constraints.energy_goal)
         i = int(batch.model_index[0])
         j = int(batch.power_index[0])
         decision = Decision(

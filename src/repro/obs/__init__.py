@@ -7,7 +7,10 @@ of counters/gauges/histograms/phase timers, a
 Chrome-trace export, and a :class:`~repro.obs.ring.TelemetryRing` of
 per-round aggregates fed straight from the megatick scan.  The three
 are bundled by :class:`FlightRecorder`, the single object a gateway or
-server accepts via its ``obs=`` keyword.
+server accepts via its ``obs=`` keyword.  The serving path records
+through :func:`~repro.obs.trace.span` and :func:`~repro.obs.trace.count`,
+which also put every span into a running JAX profiler's trace and into
+the process-wide :func:`~repro.obs.trace.process_recorder`.
 
 Hard contract — **pure observer**: attaching a recorder leaves every
 pick, bank state, and golden trace bitwise identical, and a disabled
@@ -23,11 +26,14 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                PhaseTimer)
 from repro.obs.ring import RING_FIELDS, TelemetryRing
 from repro.obs.spans import SpanTracer, validate_jsonl
+from repro.obs.trace import (ProcessRecorder, count, process_recorder,
+                             span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "PhaseTimer",
     "TelemetryRing", "RING_FIELDS", "SpanTracer", "validate_jsonl",
-    "FlightRecorder",
+    "FlightRecorder", "ProcessRecorder", "count", "process_recorder",
+    "span",
 ]
 
 

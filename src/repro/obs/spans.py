@@ -11,6 +11,11 @@ export formats:
 * ``write_chrome_trace(path)`` — the Chrome ``traceEvents`` JSON that
   ``chrome://tracing`` and Perfetto open directly.
 
+Every span carries a process-unique ``id`` and the ``id`` of the span
+open around it on the same thread (``parent``, 0 at the root), and
+inherits its parent's request id (``args["rid"]``) unless it names its
+own, so the spans of one request can be found and nested after the fact.
+
 The tracer is a pure observer: it reads the clock around phases the
 serving path already executes, keeps a bounded in-memory buffer
 (overflow is *counted*, never silent), and touches no controller state.
@@ -18,30 +23,102 @@ serving path already executes, keeps a bounded in-memory buffer
 
 from __future__ import annotations
 
+import itertools
 import json
+import threading
 import time
-from contextlib import contextmanager
 
 # Default bound on buffered events; past it new events are dropped and
 # counted in `dropped` (exported in both writers' metadata).
 SPAN_BUFFER_CAP = 262144
 
 # Required keys of one JSONL record, in write order.
-JSONL_SCHEMA = ("name", "cat", "ph", "ts_us", "dur_us", "args")
+JSONL_SCHEMA = ("name", "cat", "ph", "ts_us", "dur_us", "args", "id",
+                "parent")
+JSONL_VERSION = 2
+
+_ids = itertools.count(1)
+_open = threading.local()
+
+
+def _stack() -> list:
+    """This thread's open spans, innermost last."""
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+def current_span():
+    """The innermost open span on this thread, or ``None``."""
+    stack = _stack()
+    return stack[-1] if stack else None
+
+
+class Span:
+    """One open span, recorded into every tracer of ``tracers`` when it
+    closes; ``note`` (a ``jax.profiler.TraceAnnotation`` or ``None``) is
+    entered and left with it, so the span is also a host event in a
+    running profiler's trace.  Times come from ``time.perf_counter``,
+    every :class:`SpanTracer`'s default clock."""
+
+    __slots__ = ("tracers", "name", "cat", "args", "note", "id",
+                 "parent", "t0")
+
+    def __init__(self, tracers, name: str, cat: str, args: dict,
+                 note=None):
+        self.tracers = tracers
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.note = note
+
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        up = stack[-1] if stack else None
+        self.parent = up.id if up is not None else 0
+        if up is not None and "rid" in up.args and "rid" not in self.args:
+            self.args["rid"] = up.args["rid"]
+        self.id = next(_ids)
+        stack.append(self)
+        if self.note is not None:
+            self.note.__enter__()
+            if self.args:
+                self.note.set_metadata(**self.args)
+        self.t0 = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        """Add ``args`` to the span while it is open (e.g. a value known
+        only halfway through the block)."""
+        self.args.update(args)
+        if self.note is not None:
+            self.note.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        for tr in self.tracers:
+            tr.add(self.name, self.cat, self.t0, t1 - self.t0, self.args,
+                   self.id, self.parent)
+        return False
 
 
 class SpanTracer:
     """Bounded in-memory recorder of phase spans and instant events."""
 
-    def __init__(self, clock=time.perf_counter, capacity: int = SPAN_BUFFER_CAP):
-        self._clock = clock
-        self._t0 = clock()
+    def __init__(self, capacity: int = SPAN_BUFFER_CAP):
+        self._t0 = time.perf_counter()
         self.capacity = int(capacity)
         self.events: list[dict] = []
         self.dropped = 0
 
     def _now_us(self) -> float:
-        return (self._clock() - self._t0) * 1e6
+        return (time.perf_counter() - self._t0) * 1e6
 
     def _record(self, rec: dict) -> None:
         if len(self.events) < self.capacity:
@@ -49,22 +126,26 @@ class SpanTracer:
         else:
             self.dropped += 1
 
-    @contextmanager
-    def span(self, name: str, cat: str = "host", **args):
+    def span(self, name: str, cat: str = "host", **args) -> Span:
         """Time the enclosed block as a complete span (``ph == "X"``)."""
-        ts = self._now_us()
-        try:
-            yield
-        finally:
-            self._record({"name": name, "cat": cat, "ph": "X",
-                          "ts_us": ts, "dur_us": self._now_us() - ts,
-                          "args": args})
+        return Span((self,), name, cat, args)
+
+    def add(self, name: str, cat: str, t0: float, dur_s: float,
+            args: dict, span_id: int, parent: int) -> None:
+        """Record a complete span that started at ``t0`` (perf-counter
+        seconds) and lasted ``dur_s``."""
+        self._record({"name": name, "cat": cat, "ph": "X",
+                      "ts_us": (t0 - self._t0) * 1e6, "dur_us": dur_s * 1e6,
+                      "args": dict(args), "id": span_id, "parent": parent})
 
     def event(self, name: str, cat: str = "host", **args) -> None:
-        """Record an instant event (``ph == "i"``, zero duration)."""
+        """Record an instant event (``ph == "i"``, zero duration), a
+        child of the span open around it."""
+        up = current_span()
         self._record({"name": name, "cat": cat, "ph": "i",
                       "ts_us": self._now_us(), "dur_us": 0.0,
-                      "args": args})
+                      "args": args, "id": next(_ids),
+                      "parent": up.id if up is not None else 0})
 
     def __len__(self) -> int:
         return len(self.events)
@@ -88,7 +169,7 @@ class SpanTracer:
         carrying the schema version and the dropped-event count."""
         with open(path, "w") as f:
             f.write(json.dumps({"_meta": {"schema": list(JSONL_SCHEMA),
-                                          "version": 1,
+                                          "version": JSONL_VERSION,
                                           "dropped": self.dropped}}))
             f.write("\n")
             for e in self.events:
@@ -101,7 +182,8 @@ class SpanTracer:
         for e in self.events:
             rec = {"name": e["name"], "cat": e["cat"], "ph": e["ph"],
                    "ts": e["ts_us"], "pid": 0, "tid": 0,
-                   "args": e["args"]}
+                   "args": {**e["args"], "id": e["id"],
+                            "parent": e["parent"]}}
             if e["ph"] == "X":
                 rec["dur"] = e["dur_us"]
             else:

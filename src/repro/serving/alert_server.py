@@ -39,6 +39,7 @@ from repro.core.kalman import (IdlePowerFilterBank, SlowdownFilterBank,
                                observe_fleet)
 from repro.core.power import PowerModel
 from repro.core.profiles import Candidate, ProfileTable
+from repro.obs.trace import count as obs_count, span as obs_span
 from repro.serving.engine import ServeEngine
 
 
@@ -99,14 +100,23 @@ class AlertServer:
     """One request stream over a real model: profile the levels at
     startup (t^train), then serve inputs one at a time through the
     :class:`~repro.core.controller.AlertController` loop (S=1 wrapper of
-    the batched engine)."""
+    the batched engine).
+
+    ``obs`` (an optional :class:`~repro.obs.FlightRecorder`, a pure
+    observer) records each request as a root span ``request`` (args: the
+    request id ``rid`` and the level) over ``controller_select``, the
+    engine's ``first_token`` / ``decode_step`` / ``token_fetch`` and
+    ``controller_observe``, all sharing the request id, and counts
+    ``requests``; the same spans reach a running profiler's trace
+    (:mod:`repro.obs.trace`)."""
 
     def __init__(self, engine: ServeEngine, params,
                  level_accuracies: list[float], goal: Goal,
                  power_model: PowerModel | None = None,
                  n_power_buckets: int = 4,
                  profile_iters: int = 3, q_fail: float = 0.0,
-                 prompt_len: int = 8, gen_tokens: int = 4):
+                 prompt_len: int = 8, gen_tokens: int = 4, obs=None):
+        self.obs = obs
         self.engine = engine
         self.params = params
         self.goal = goal
@@ -118,7 +128,7 @@ class AlertServer:
             engine, params, level_accuracies, pm,
             n_power_buckets=n_power_buckets, profile_iters=profile_iters,
             q_fail=q_fail, prompt_len=prompt_len, gen_tokens=gen_tokens)
-        self.controller = AlertController(self.table, goal)
+        self.controller = AlertController(self.table, goal, obs=obs)
         self.history: list[ServedInput] = []
 
     def serve_one(self, prompt: np.ndarray, constraints: Constraints
@@ -126,26 +136,35 @@ class AlertServer:
         """Select a (level, power) for this input, run the level's
         compiled program under the deadline, book energy through the
         power model, and feed the outcome back to the controller."""
-        d = self.controller.select(constraints)
-        lvl = self.engine.levels[d.model_index]
-        r = self.engine.generate(self.params, prompt, self.gen_tokens,
-                                 level=lvl, deadline_s=constraints.deadline)
-        lat = r["latency"]
-        missed = (lat > constraints.deadline) or not r["complete"]
-        acc = self.table.candidates[d.model_index].accuracy \
-            if not missed else self.table.q_fail
-        f = self.power_model.speed_fraction(d.power_cap)
-        p = self.power_model.power_at_fraction(f)
-        run_t = min(lat, constraints.deadline)
-        energy = p * run_t + self.controller.idle_power.phi * p * \
-            max(constraints.deadline - run_t, 0.0)
-        self.controller.observe(
-            run_t, deadline_missed=missed,
-            idle_power=0.25 * p, delivered_accuracy=acc)
-        out = ServedInput(level=lvl or 0, power_cap=d.power_cap,
-                          latency=lat, missed=missed, accuracy=acc,
-                          energy=energy, feasible=d.feasible)
-        self.history.append(out)
+        ob = self.obs
+        with obs_span(ob, "request", "serve",
+                      rid=len(self.history)) as req:
+            with obs_span(ob, "controller_select", "serve"):
+                d = self.controller.select(constraints)
+            lvl = self.engine.levels[d.model_index]
+            req.set(level=lvl or 0)
+            r = self.engine.generate(self.params, prompt, self.gen_tokens,
+                                     level=lvl,
+                                     deadline_s=constraints.deadline,
+                                     obs=ob)
+            lat = r["latency"]
+            missed = (lat > constraints.deadline) or not r["complete"]
+            acc = self.table.candidates[d.model_index].accuracy \
+                if not missed else self.table.q_fail
+            f = self.power_model.speed_fraction(d.power_cap)
+            p = self.power_model.power_at_fraction(f)
+            run_t = min(lat, constraints.deadline)
+            energy = p * run_t + self.controller.idle_power.phi * p * \
+                max(constraints.deadline - run_t, 0.0)
+            with obs_span(ob, "controller_observe", "serve"):
+                self.controller.observe(
+                    run_t, deadline_missed=missed,
+                    idle_power=0.25 * p, delivered_accuracy=acc)
+            out = ServedInput(level=lvl or 0, power_cap=d.power_cap,
+                              latency=lat, missed=missed, accuracy=acc,
+                              energy=energy, feasible=d.feasible)
+            self.history.append(out)
+        obs_count(ob, "requests", server="alert")
         return out
 
 

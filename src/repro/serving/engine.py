@@ -3,9 +3,11 @@ compiled programs for anytime models.
 
 One compiled ``decode_step`` per (nesting level) — static shapes, so the
 controller can switch levels between requests at zero recompile cost after
-warmup.  The engine is mesh-agnostic: pass ``shardings`` built from
-launch/shardings.py to serve under pjit on a pod; on CPU (tests, examples)
-it runs single-device.
+warmup.  Each level's programs carry its name (``prefill_level<k>``,
+``decode_level<k>``), which a profiler trace shows as the module
+``jit_prefill_level<k>``.  The engine is mesh-agnostic: pass
+``shardings`` built from launch/shardings.py to serve under pjit on a
+pod; on CPU (tests, examples) it runs single-device.
 """
 
 from __future__ import annotations
@@ -20,6 +22,13 @@ import numpy as np
 
 from repro.models.registry import Model
 from repro.models import transformer as tfm
+from repro.obs.trace import count as obs_count, span as obs_span
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, the name its jitted program carries."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @dataclasses.dataclass
@@ -40,15 +49,16 @@ class ServeEngine:
         self._prefill = {}
         self._decode = {}
         for lvl in self.levels:
-            self._prefill[lvl] = jax.jit(
+            tag = "" if lvl is None else f"_level{lvl}"
+            self._prefill[lvl] = jax.jit(_named(
                 lambda p, b, lvl=lvl: tfm.lm_apply(
                     p, cfg, b["tokens"], mode="prefill", level=lvl,
-                    pos3d=b.get("pos3d")))
-            self._decode[lvl] = jax.jit(
+                    pos3d=b.get("pos3d")), f"prefill{tag}"))
+            self._decode[lvl] = jax.jit(_named(
                 lambda p, b, c, lvl=lvl: tfm.lm_apply(
                     p, cfg, b["tokens"], mode="decode", caches=c,
                     cache_len=b["cache_len"], level=lvl,
-                    pos3d=b.get("pos3d")))
+                    pos3d=b.get("pos3d")), f"decode{tag}"))
 
     def init_caches(self, level: int | None = None):
         """Fresh decode caches sized to ``level`` (level-k programs write
@@ -87,7 +97,7 @@ class ServeEngine:
     def generate(self, params, prompt: np.ndarray, n_new: int,
                  level: int | None = None,
                  deadline_s: float | None = None,
-                 clock=None) -> dict:
+                 clock=None, obs=None) -> dict:
         """Greedy-decode ``n_new`` tokens after ``prompt`` [B, S0].
 
         Anytime semantics: when ``level`` is None and the model is nested,
@@ -100,6 +110,13 @@ class ServeEngine:
         drive deadlines and reported latency without real wall clocks; the
         reported latency is compute-inclusive because every step's tokens
         are materialised on host before the final clock read.
+
+        Spans (``obs`` is an optional :class:`~repro.obs.FlightRecorder`;
+        see :mod:`repro.obs.trace`): ``first_token`` from entry to the
+        first token on the host, then one ``decode_step`` per step from
+        dispatch to its token on the host, each with a child
+        ``token_fetch`` around the copy; counters ``decode_steps`` and
+        ``deadline_cutoffs`` (a deadline stopped the loop early).
         """
         if clock is None:
             clock = time.perf_counter
@@ -108,23 +125,31 @@ class ServeEngine:
         lvl = level if level is not None else \
             (cfg.nest_levels if cfg.nest_levels > 1 else None)
         b, s0 = prompt.shape
-        out = self.prefill(params, prompt, lvl)
-        caches = self._merge(self.init_caches(lvl), out.caches)
-        logits = out.logits if not isinstance(out.logits, list) \
-            else out.logits[-1]
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        toks = [np.asarray(next_tok)]
+        with obs_span(obs, "first_token", "engine", level=lvl):
+            out = self.prefill(params, prompt, lvl)
+            caches = self._merge(self.init_caches(lvl), out.caches)
+            logits = out.logits if not isinstance(out.logits, list) \
+                else out.logits[-1]
+            next_tok = jnp.argmax(logits[:, -1:],
+                                  axis=-1).astype(jnp.int32)
+            with obs_span(obs, "token_fetch", "engine"):
+                toks = [np.asarray(next_tok)]
         for i in range(n_new - 1):
             if deadline_s is not None and clock() - t0 > deadline_s:
+                obs_count(obs, "deadline_cutoffs")
                 break
-            step = {"tokens": next_tok,
-                    "cache_len": jnp.asarray(s0 + i, jnp.int32)}
-            o = self._decode[lvl](params, step, caches)
-            caches = o.caches
-            lg = o.logits if not isinstance(o.logits, list) else \
-                o.logits[-1]
-            next_tok = jnp.argmax(lg[:, -1:], axis=-1).astype(jnp.int32)
-            toks.append(np.asarray(next_tok))
+            with obs_span(obs, "decode_step", "engine", step=i):
+                step = {"tokens": next_tok,
+                        "cache_len": jnp.asarray(s0 + i, jnp.int32)}
+                o = self._decode[lvl](params, step, caches)
+                caches = o.caches
+                lg = o.logits if not isinstance(o.logits, list) else \
+                    o.logits[-1]
+                next_tok = jnp.argmax(lg[:, -1:],
+                                      axis=-1).astype(jnp.int32)
+                with obs_span(obs, "token_fetch", "engine"):
+                    toks.append(np.asarray(next_tok))
+        obs_count(obs, "decode_steps", len(toks) - 1)
         return {
             "tokens": np.concatenate(toks, axis=1),
             "latency": clock() - t0,

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from contextlib import nullcontext
 from typing import Sequence
 
 import numpy as np
@@ -47,6 +46,7 @@ from repro.core.batched import (BatchedAlertEngine, WindowedGoalBank,
 from repro.core.kalman import (IdlePowerFilterBank, SlowdownFilterBank,
                                observe_fleet)
 from repro.core.profiles import ProfileTable
+from repro.obs.trace import count as obs_count, span as obs_span
 from repro.runtime.ft import InjectedFailure
 from repro.serving.batcher import DeadlineBatcher
 from repro.serving.sim import TraceResult, deliver_tick
@@ -550,8 +550,7 @@ class SessionGateway:
         """
         rs = self._init_run(sessions, requests, policy=policy,
                             static_config=static_config, faults=faults)
-        with self._ob.spans.span("checkpoint_restore", cat="checkpoint") \
-                if self._ob else nullcontext():
+        with obs_span(self._ob, "checkpoint_restore", "checkpoint"):
             self._load_checkpoint(rs, checkpoint_dir)
         return self._drive(rs, policy, static_config, faults, detector,
                            checkpoint_dir, checkpoint_every,
@@ -605,53 +604,15 @@ class SessionGateway:
                                        lanes=lanes, now_s=float(now))
                 self._dead = dead_now
                 fmul = faults.slow_at(now)
-            # --- arrivals due by this round (backpressure at submit) ---
-            while rs.ri < n and requests[rs.ri].arrival <= now:
-                req = requests[rs.ri]
-                if not queue.submit(req):
-                    out.status[req._row] = REJECTED_BACKPRESSURE
-                rs.ri += 1
-            if q_depth is not None:
-                q_depth.observe(len(queue))
-            # --- EDF pop onto the lanes that are free this round, at
-            # most one request per session (a session is sequential:
-            # whether queued behind itself or mid-service on a busy
-            # lane, its later requests wait).  The scan is bounded: a
-            # run of blocked same-session requests longer than the
-            # deferral budget waits for the next round instead of
-            # churning the whole backlog through the heap every round.
-            n_rej = len(queue.rejected)
-            avail = int(((self._busy_until <= now)
-                         & ~self._dead).sum())
-            batch: list[TrafficRequest] = []
-            seen: set[int] = set()
-            deferred: list[TrafficRequest] = []
-            defer_budget = 4 * self.n_lanes
-            while len(batch) < avail and len(deferred) <= defer_budget:
-                req = queue.pop_one(now)
-                if req is None:
-                    break
-                lane = self._lane_of.get(req.sid, -1)
-                if req.sid in seen or \
-                        (lane >= 0 and self._busy_until[lane] > now):
-                    deferred.append(req)
-                    continue
-                seen.add(req.sid)
-                batch.append(req)
-            for req in deferred:
-                # Deferral is not a new arrival: requeue() bypasses
-                # max_queue backpressure (the request was already
-                # admitted) and restores the original heap seq so the
-                # EDF submission-order tie-break survives deferral.
-                queue.requeue(req)
-            for req in queue.rejected[n_rej:]:   # failed fast this round
-                out.status[req._row] = REJECTED_INFEASIBLE
-                out.start[req._row] = now
+            with obs_span(ob, "admit", "gateway", round_k=rs.round_k):
+                batch, deferred = self._admit(rs, now, q_depth)
+            obs_count(ob, "pops", len(batch) + len(deferred),
+                      gateway="host")
+            obs_count(ob, "deferred", len(deferred), gateway="host")
             if batch:
-                with ob.spans.span("serve_round", cat="gateway",
-                                   round_k=rs.round_k,
-                                   batch=len(batch)) \
-                        if ob else nullcontext():
+                obs_count(ob, "rounds", gateway="host")
+                with obs_span(ob, "serve_round", "gateway",
+                              round_k=rs.round_k, batch=len(batch)):
                     rs.last_completion = max(
                         rs.last_completion, self._serve_round(
                             batch, sess, now, rs.round_k, policy,
@@ -662,9 +623,8 @@ class SessionGateway:
             rs.iters += 1
             if checkpoint_dir is not None and \
                     rs.iters % max(checkpoint_every, 1) == 0:
-                with ob.spans.span("checkpoint_write", cat="checkpoint",
-                                   iters=rs.iters) \
-                        if ob else nullcontext():
+                with obs_span(ob, "checkpoint_write", "checkpoint",
+                              iters=rs.iters):
                     self._save_checkpoint(rs, checkpoint_dir)
         out.horizon = max(rs.last_completion,
                           float(out.arrival[-1]) if n else 0.0)
@@ -675,6 +635,54 @@ class SessionGateway:
             _obs_record_result(ob.metrics, out, gateway="host",
                                policy=policy)
         return out
+
+    def _admit(self, rs: "_RunState", now: float, q_depth):
+        """One round's admission: submit the arrivals due by ``now``
+        (backpressure at submit), then pop the EDF queue onto the lanes
+        free this round, at most one request per session, failing fast
+        what can no longer meet its deadline.  Returns the round's batch
+        and the requests popped and put back (deferred)."""
+        requests, queue, out = rs.requests, rs.queue, rs.out
+        n = len(requests)
+        while rs.ri < n and requests[rs.ri].arrival <= now:
+            req = requests[rs.ri]
+            if not queue.submit(req):
+                out.status[req._row] = REJECTED_BACKPRESSURE
+            rs.ri += 1
+        if q_depth is not None:
+            q_depth.observe(len(queue))
+        # A session is sequential: whether queued behind itself or
+        # mid-service on a busy lane, its later requests wait.  The scan
+        # is bounded: a run of blocked same-session requests longer than
+        # the deferral budget waits for the next round instead of
+        # churning the whole backlog through the heap every round.
+        n_rej = len(queue.rejected)
+        avail = int(((self._busy_until <= now) & ~self._dead).sum())
+        batch: list[TrafficRequest] = []
+        seen: set[int] = set()
+        deferred: list[TrafficRequest] = []
+        defer_budget = 4 * self.n_lanes
+        while len(batch) < avail and len(deferred) <= defer_budget:
+            req = queue.pop_one(now)
+            if req is None:
+                break
+            lane = self._lane_of.get(req.sid, -1)
+            if req.sid in seen or \
+                    (lane >= 0 and self._busy_until[lane] > now):
+                deferred.append(req)
+                continue
+            seen.add(req.sid)
+            batch.append(req)
+        for req in deferred:
+            # Deferral is not a new arrival: requeue() bypasses
+            # max_queue backpressure (the request was already admitted)
+            # and restores the original heap seq so the EDF
+            # submission-order tie-break survives deferral.
+            queue.requeue(req)
+        for req in queue.rejected[n_rej:]:   # failed fast this round
+            out.status[req._row] = REJECTED_INFEASIBLE
+            out.start[req._row] = now
+        return batch, deferred
 
     # -------------------------------------------------------------- #
     # checkpoint / resume                                             #
@@ -817,8 +825,7 @@ class SessionGateway:
         deliver through the shared tick kernel, absorb feedback.  Returns
         the round's last completion time."""
         ob = self._ob
-        with ob.spans.span("page_in", cat="paging", round_k=round_k) \
-                if ob else nullcontext():
+        with obs_span(ob, "page_in", "paging", round_k=round_k):
             lanes = self._page_in([r.sid for r in batch], sess, round_k,
                                   now)
         act = np.zeros(self.n_lanes, bool)
@@ -842,11 +849,12 @@ class SessionGateway:
             # paths see bit-identical effective scales.
             scale = scale * fmul
         if policy == "alert":
-            b = self.engine.select(
-                self.slow.mu, self.slow.sigma, self.idle.phi, dvec,
-                accuracy_goal=self.goal_bank.current_goal(),
-                energy_goal=e_goal, goal_kind=self._goal_kinds,
-                active=act, predictions=False)
+            with obs_span(ob, "select", "gateway", round_k=round_k):
+                b = self.engine.select(
+                    self.slow.mu, self.slow.sigma, self.idle.phi, dvec,
+                    accuracy_goal=self.goal_bank.current_goal(),
+                    energy_goal=e_goal, goal_kind=self._goal_kinds,
+                    active=act, predictions=False)
             i_pick, j_pick = b.model_index, b.power_index
         else:
             b = None
@@ -854,21 +862,23 @@ class SessionGateway:
                              dtype=np.int64)
             j_pick = np.full(self.n_lanes, static_config[1],
                              dtype=np.int64)
-        d = deliver_tick(self.table, self._st, i_pick, j_pick, scale,
-                         dvec, self.phi_true, self._is_anytime,
-                         self.table.latency[i_pick, j_pick])
+        with obs_span(ob, "deliver", "gateway", round_k=round_k):
+            d = deliver_tick(self.table, self._st, i_pick, j_pick, scale,
+                             dvec, self.phi_true, self._is_anytime,
+                             self.table.latency[i_pick, j_pick])
         # Pre-update Eq. 6 prior, snapshotted only for the innovation
         # histogram below (reads never perturb the bank).
         mu_prev = np.asarray(self.slow.mu) \
             if (ob is not None and policy == "alert") else None
         if policy == "alert":
-            observe_fleet(self.slow, self.idle, d.observed, d.profiled,
-                          deadline_missed=d.miss_flag,
-                          idle_power=self.phi_true * d.run_power,
-                          active_power=self.table.run_power[i_pick,
-                                                            j_pick],
-                          mask=act)
-            self.goal_bank.record(d.accuracy, mask=act)
+            with obs_span(ob, "feedback", "gateway", round_k=round_k):
+                observe_fleet(self.slow, self.idle, d.observed,
+                              d.profiled, deadline_missed=d.miss_flag,
+                              idle_power=self.phi_true * d.run_power,
+                              active_power=self.table.run_power[i_pick,
+                                                                j_pick],
+                              mask=act)
+                self.goal_bank.record(d.accuracy, mask=act)
             if detector is not None:
                 # Detection reads the Eq.7 posterior AFTER the round's
                 # update — ALERT's own estimate, not an oracle flag.

@@ -58,9 +58,9 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from contextlib import nullcontext
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.core.batched import (BatchedAlertEngine, _goal_record_step,
@@ -71,6 +71,7 @@ from repro.core.precision import x64_scope
 from repro.core.profiles import ProfileTable
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.ring import round_aggregates
+from repro.obs.trace import count as obs_count, span as obs_span
 from repro.serving.batcher import DeadlineBatcher
 from repro.serving.sim import deliver_step
 from repro.traffic.gateway import (REJECTED_BACKPRESSURE,
@@ -377,43 +378,47 @@ class MegatickGateway:
                         ob.spans.event("quarantine", cat="fault",
                                        lanes=lanes, now_s=float(now))
                 self._dead = dead_now
-            while ri < n and requests[ri].arrival <= now:
-                req = requests[ri]
-                if not queue.submit(req):
-                    out.status[req._row] = REJECTED_BACKPRESSURE
-                ri += 1
-            if q_depth is not None:
-                q_depth.observe(len(queue))
-            n_rej = len(queue.rejected)
-            # avail == surviving lanes and no busy-lane deferral: the
-            # regime contract makes every lane idle at every round
-            # boundary (run_t <= dvec <= rel_deadline <= tick), so the
-            # host's `(busy_until <= now) & ~dead` count reduces to the
-            # live-lane count.
-            avail = self.n_lanes - int(self._dead.sum())
-            batch: list[TrafficRequest] = []
-            seen: set[int] = set()
-            deferred: list[TrafficRequest] = []
-            defer_budget = 4 * self.n_lanes
-            while len(batch) < avail and \
-                    len(deferred) <= defer_budget:
-                req = queue.pop_one(now)
-                if req is None:
-                    break
-                if req.sid in seen:
-                    deferred.append(req)
-                    continue
-                seen.add(req.sid)
-                batch.append(req)
-            for req in deferred:
-                queue.requeue(req)
-            for req in queue.rejected[n_rej:]:
-                out.status[req._row] = REJECTED_INFEASIBLE
-                out.start[req._row] = now
+            with obs_span(ob, "plan_admit", "megatick", round_k=round_k):
+                while ri < n and requests[ri].arrival <= now:
+                    req = requests[ri]
+                    if not queue.submit(req):
+                        out.status[req._row] = REJECTED_BACKPRESSURE
+                    ri += 1
+                if q_depth is not None:
+                    q_depth.observe(len(queue))
+                n_rej = len(queue.rejected)
+                # avail == surviving lanes and no busy-lane deferral: the
+                # regime contract makes every lane idle at every round
+                # boundary (run_t <= dvec <= rel_deadline <= tick), so
+                # the host's `(busy_until <= now) & ~dead` count reduces
+                # to the live-lane count.
+                avail = self.n_lanes - int(self._dead.sum())
+                batch: list[TrafficRequest] = []
+                seen: set[int] = set()
+                deferred: list[TrafficRequest] = []
+                defer_budget = 4 * self.n_lanes
+                while len(batch) < avail and \
+                        len(deferred) <= defer_budget:
+                    req = queue.pop_one(now)
+                    if req is None:
+                        break
+                    if req.sid in seen:
+                        deferred.append(req)
+                        continue
+                    seen.add(req.sid)
+                    batch.append(req)
+                for req in deferred:
+                    queue.requeue(req)
+                for req in queue.rejected[n_rej:]:
+                    out.status[req._row] = REJECTED_INFEASIBLE
+                    out.start[req._row] = now
             if batch:
-                dense = [sid_index[r.sid] for r in batch]
-                lanes = self._page_in_meta(
-                    np.asarray(dense, dtype=np.int64), round_k)
+                obs_count(ob, "rounds", gateway="megatick")
+                with obs_span(ob, "plan_page", "megatick",
+                              round_k=round_k):
+                    dense = [sid_index[r.sid] for r in batch]
+                    lanes = self._page_in_meta(
+                        np.asarray(dense, dtype=np.int64), round_k)
                 k = len(now_l)
                 now_l.append(now)
                 if faults is not None:
@@ -493,7 +498,6 @@ class MegatickGateway:
         key = (policy, static_config, ring)
         if key in self._chunk_jits:
             return self._chunk_jits[key]
-        import jax
         import jax.numpy as jnp
 
         ln = self.n_lanes
@@ -525,8 +529,9 @@ class MegatickGateway:
                 dvec = jnp.where(act, relv - (now - arrv), 1.0)
                 i = jnp.full((ln,), i_fix, jnp.int64)
                 j = jnp.full((ln,), j_fix, jnp.int64)
-                run_t, acc, energy, missed, *_ = deliver_step(
-                    i, j, scl, dvec, phi_true, f_zero=fz, **consts)
+                with jax.named_scope("deliver"):
+                    run_t, acc, energy, missed, *_ = deliver_step(
+                        i, j, scl, dvec, phi_true, f_zero=fz, **consts)
                 sojourn = (now - arrv) + run_t
                 ys = (run_t, acc, energy, missed, i, j, sojourn)
                 if ring:
@@ -568,34 +573,38 @@ class MegatickGateway:
             # planner never schedules a dead lane, so this only hardens
             # the scan against a planner/schedule mismatch.
             act = act & ~deadv
-            mu_l, sd_l, ph_l = mu[sidv], sigma[sidv], phv[sidv]
-            g_l, q_l, v_l = gain[sidv], qn[sidv], var[sidv]
-            dvec = jnp.where(act, relv - (now - arrv), 1.0)
-            if depth:
-                acc_goal = goal_current_step_hostsum(
-                    goal[sidv], buf[sidv], count[sidv], window, fz)
-            else:
-                acc_goal = goal[sidv]
-            i, j, _lat, _acc, _en, feas, relaxed = select(
-                mu_l, sd_l, ph_l, dvec, acc_goal, egl, gkv, act)
-            (run_t, acc, energy, missed, p, observed, profiled,
-             miss_flag) = deliver_step(i, j, scl, dvec, phi_true,
-                                       f_zero=fz, **consts)
-            prof_m = jnp.where(act, profiled, 1.0)
-            act_p = jnp.where(act, p, 1.0)
-            mu_n, sd_n, g_n, q_n, ph_n, v_n = fused_fleet_step(
-                mu_l, sd_l, g_l, q_l, observed, prof_m, miss_flag, act,
-                *slow_params, ph_l, v_l, phi_true * p, act_p,
-                *idle_params)
-            put = lambda s, v: s.at[sidv].set(v, mode="drop")
-            mu, sigma = put(mu, mu_n), put(sigma, sd_n)
-            gain, qn = put(gain, g_n), put(qn, q_n)
-            phv, var = put(phv, ph_n), put(var, v_n)
-            if depth:
-                buf_n, pos_n, cnt_n = _goal_record_step(
-                    buf[sidv], pos[sidv], count[sidv], acc, act, depth)
-                buf = buf.at[sidv].set(buf_n, mode="drop")
-                pos, count = put(pos, pos_n), put(count, cnt_n)
+            with jax.named_scope("select"):
+                mu_l, sd_l, ph_l = mu[sidv], sigma[sidv], phv[sidv]
+                g_l, q_l, v_l = gain[sidv], qn[sidv], var[sidv]
+                dvec = jnp.where(act, relv - (now - arrv), 1.0)
+                if depth:
+                    acc_goal = goal_current_step_hostsum(
+                        goal[sidv], buf[sidv], count[sidv], window, fz)
+                else:
+                    acc_goal = goal[sidv]
+                i, j, _lat, _acc, _en, feas, relaxed = select(
+                    mu_l, sd_l, ph_l, dvec, acc_goal, egl, gkv, act)
+            with jax.named_scope("deliver"):
+                (run_t, acc, energy, missed, p, observed, profiled,
+                 miss_flag) = deliver_step(i, j, scl, dvec, phi_true,
+                                           f_zero=fz, **consts)
+            with jax.named_scope("feedback"):
+                prof_m = jnp.where(act, profiled, 1.0)
+                act_p = jnp.where(act, p, 1.0)
+                mu_n, sd_n, g_n, q_n, ph_n, v_n = fused_fleet_step(
+                    mu_l, sd_l, g_l, q_l, observed, prof_m, miss_flag,
+                    act, *slow_params, ph_l, v_l, phi_true * p, act_p,
+                    *idle_params)
+                put = lambda s, v: s.at[sidv].set(v, mode="drop")
+                mu, sigma = put(mu, mu_n), put(sigma, sd_n)
+                gain, qn = put(gain, g_n), put(qn, q_n)
+                phv, var = put(phv, ph_n), put(var, v_n)
+                if depth:
+                    buf_n, pos_n, cnt_n = _goal_record_step(
+                        buf[sidv], pos[sidv], count[sidv], acc, act,
+                        depth)
+                    buf = buf.at[sidv].set(buf_n, mode="drop")
+                    pos, count = put(pos, pos_n), put(count, cnt_n)
             sojourn = (now - arrv) + run_t
             ys = (run_t, acc, energy, missed, i, j, sojourn)
             if ring:
@@ -674,8 +683,7 @@ class MegatickGateway:
 
         ob = self._ob
         t0 = time.perf_counter()
-        with ob.spans.span("plan", cat="megatick") if ob \
-                else nullcontext():
+        with obs_span(ob, "plan", "megatick"):
             sid_index = {s.sid: k for k, s in enumerate(sessions)}
             plan = self._plan(sessions, requests, sid_index, faults)
         self._plan_timer.observe(time.perf_counter() - t0)
@@ -693,38 +701,24 @@ class MegatickGateway:
                           plan.arr[lo:hi], plan.e_goal[lo:hi],
                           plan.scale[lo:hi], plan.dead[lo:hi],
                           plan.now[lo:hi])
-                    with ob.spans.span("scan_dispatch", cat="megatick",
-                                       chunk_lo=lo) if ob \
-                            else nullcontext():
+                    with obs_span(ob, "scan_dispatch", "megatick",
+                                  chunk_lo=lo):
                         if policy == "alert":
                             carry, ys = fn(carry, goal, 0.0, xs)
                         else:
                             ys = fn(0.0, xs)
-                    a = plan.act[lo:hi]
-                    rows = plan.row[lo:hi][a]
-                    out.latency[rows] = np.asarray(ys[0])[a]
-                    out.accuracy[rows] = np.asarray(ys[1])[a]
-                    out.missed[rows] = np.asarray(ys[3])[a]
-                    out.model_index[rows] = np.asarray(ys[4])[a]
-                    out.power_index[rows] = np.asarray(ys[5])[a]
-                    out.sojourn[rows] = np.asarray(ys[6])[a]
-                    # Energy is recomputed HERE, in numpy, from
-                    # bitwise-stable scan outputs: its mul+add chain is
-                    # the one expression XLA CPU may still contract into
-                    # an FMA inside the fused scan body, and the host
-                    # loop's numpy kernel never does.
-                    rt = out.latency[rows]
-                    ii, jj = out.model_index[rows], out.power_index[rows]
-                    pw = self.table.run_power[ii, jj]
-                    dv = (plan.rel[lo:hi]
-                          - (plan.now[lo:hi, None] - plan.arr[lo:hi]))[a]
-                    out.energy[rows] = pw * rt + self.phi_true * pw * \
-                        np.maximum(dv - rt, 0.0)
+                    with obs_span(ob, "scan_wait", "megatick",
+                                  chunk_lo=lo):
+                        ys = jax.block_until_ready(ys)
+                    with obs_span(ob, "scan_scatter", "megatick",
+                                  chunk_lo=lo):
+                        self._scatter(plan, lo, hi, ys, out)
                     if ob is not None:
                         # Drop the all-inactive pad rounds of the final
                         # chunk; ring energy is the scan's own sum (may
                         # differ in the last ulp from the host FMA
-                        # recompute above — docs/OBSERVABILITY.md).
+                        # recompute in `_scatter` —
+                        # docs/OBSERVABILITY.md).
                         n_real = min(self.chunk, plan.n_active - lo)
                         if n_real > 0:
                             ob.ring.push_rounds(
@@ -752,6 +746,30 @@ class MegatickGateway:
             _obs_record_result(ob.metrics, out, gateway="megatick",
                                policy=policy)
         return out
+
+    def _scatter(self, plan: _Plan, lo: int, hi: int, ys,
+                 out: GatewayResult) -> None:
+        """Copy rounds ``[lo, hi)`` of a chunk's outputs ``ys`` into the
+        result rows their lanes served."""
+        a = plan.act[lo:hi]
+        rows = plan.row[lo:hi][a]
+        out.latency[rows] = np.asarray(ys[0])[a]
+        out.accuracy[rows] = np.asarray(ys[1])[a]
+        out.missed[rows] = np.asarray(ys[3])[a]
+        out.model_index[rows] = np.asarray(ys[4])[a]
+        out.power_index[rows] = np.asarray(ys[5])[a]
+        out.sojourn[rows] = np.asarray(ys[6])[a]
+        # Energy is recomputed HERE, in numpy, from bitwise-stable scan
+        # outputs: its mul+add chain is the one expression XLA CPU may
+        # still contract into an FMA inside the fused scan body, and the
+        # host loop's numpy kernel never does.
+        rt = out.latency[rows]
+        ii, jj = out.model_index[rows], out.power_index[rows]
+        pw = self.table.run_power[ii, jj]
+        dv = (plan.rel[lo:hi]
+              - (plan.now[lo:hi, None] - plan.arr[lo:hi]))[a]
+        out.energy[rows] = pw * rt + self.phi_true * pw * \
+            np.maximum(dv - rt, 0.0)
 
     def n_compiles(self) -> tuple[int, int]:
         """(estimate, scan) jit-cache sizes, the
