@@ -31,6 +31,19 @@ def _named(fn, name: str):
     return fn
 
 
+def _greedy_step(cfg, lvl):
+    """The greedy decode step at level ``lvl``, for one device program."""
+    def step(params, token, cache_len, caches):
+        """``(params, token [B, 1] int32, cache_len, caches)`` to the next
+        token [B, 1] int32, ``cache_len + 1`` and the updated caches."""
+        o = tfm.lm_apply(params, cfg, token, mode="decode", caches=caches,
+                         cache_len=cache_len, level=lvl)
+        lg = o.logits[-1] if isinstance(o.logits, list) else o.logits
+        nxt = jnp.argmax(lg[:, -1:], axis=-1).astype(jnp.int32)
+        return nxt, cache_len + 1, o.caches
+    return step
+
+
 @dataclasses.dataclass
 class ServeEngine:
     """Per-level compiled serving programs for one (possibly nested)
@@ -54,11 +67,9 @@ class ServeEngine:
                 lambda p, b, lvl=lvl: tfm.lm_apply(
                     p, cfg, b["tokens"], mode="prefill", level=lvl,
                     pos3d=b.get("pos3d")), f"prefill{tag}"))
-            self._decode[lvl] = jax.jit(_named(
-                lambda p, b, c, lvl=lvl: tfm.lm_apply(
-                    p, cfg, b["tokens"], mode="decode", caches=c,
-                    cache_len=b["cache_len"], level=lvl,
-                    pos3d=b.get("pos3d")), f"decode{tag}"))
+            self._decode[lvl] = jax.jit(
+                _named(_greedy_step(cfg, lvl), f"decode{tag}"),
+                donate_argnums=(3,))
 
     def init_caches(self, level: int | None = None):
         """Fresh decode caches sized to ``level`` (level-k programs write
@@ -103,19 +114,29 @@ class ServeEngine:
         Anytime semantics: when ``level`` is None and the model is nested,
         runs at the deepest level; a deadline (wall-clock seconds) makes
         generate return whatever tokens are complete at expiry (paper
-        Eq. 10 staircase measured for real).  Prefill and every decode step
-        run through the per-level compiled executables (zero recompiles
-        after warmup — assert with :meth:`n_compiles`).  ``clock`` injects
-        the timebase (default ``time.perf_counter``) so deterministic tests
-        drive deadlines and reported latency without real wall clocks; the
-        reported latency is compute-inclusive because every step's tokens
-        are materialised on host before the final clock read.
+        Eq. 10 staircase measured for real): no step is dispatched after
+        the deadline, and the step dispatched before it is kept.  Prefill
+        and every decode step run through the per-level compiled
+        executables (zero recompiles after warmup — assert with
+        :meth:`n_compiles`).  A decode step is one dispatch of its level's
+        program, which takes the previous token, position and caches as
+        device arrays and returns the next ones (the caches donated), and
+        the host reads each token one step behind: step *i* is dispatched
+        before token *i-1* is fetched, so the device runs while the host
+        dispatches.  ``clock`` injects the timebase (default
+        ``time.perf_counter``; read once at entry, once before each step
+        and once at the end) so deterministic tests drive deadlines and
+        reported latency without real wall clocks; the reported latency
+        is compute-inclusive because the last token is fetched before the
+        final clock read.
 
         Spans (``obs`` is an optional :class:`~repro.obs.FlightRecorder`;
         see :mod:`repro.obs.trace`): ``first_token`` from entry to the
-        first token on the host, then one ``decode_step`` per step from
-        dispatch to its token on the host, each with a child
-        ``token_fetch`` around the copy; counters ``decode_steps`` and
+        first token on the host, then one ``decode_step`` per step over
+        its dispatch and the fetch of the previous step's token, in a
+        child ``token_fetch``; the last token's ``token_fetch`` follows the
+        loop.  Counters: ``decode_steps``, ``decode_overlapped`` (steps
+        dispatched while the previous one was still on the device) and
         ``deadline_cutoffs`` (a deadline stopped the loop early).
         """
         if clock is None:
@@ -130,26 +151,28 @@ class ServeEngine:
             caches = self._merge(self.init_caches(lvl), out.caches)
             logits = out.logits if not isinstance(out.logits, list) \
                 else out.logits[-1]
-            next_tok = jnp.argmax(logits[:, -1:],
-                                  axis=-1).astype(jnp.int32)
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             with obs_span(obs, "token_fetch", "engine"):
-                toks = [np.asarray(next_tok)]
+                toks = [np.asarray(tok)]
+        decode, pending, overlapped = self._decode[lvl], None, 0
+        pos = jnp.asarray(s0, jnp.int32) if n_new > 1 else None
         for i in range(n_new - 1):
             if deadline_s is not None and clock() - t0 > deadline_s:
                 obs_count(obs, "deadline_cutoffs")
                 break
             with obs_span(obs, "decode_step", "engine", step=i):
-                step = {"tokens": next_tok,
-                        "cache_len": jnp.asarray(s0 + i, jnp.int32)}
-                o = self._decode[lvl](params, step, caches)
-                caches = o.caches
-                lg = o.logits if not isinstance(o.logits, list) else \
-                    o.logits[-1]
-                next_tok = jnp.argmax(lg[:, -1:],
-                                      axis=-1).astype(jnp.int32)
-                with obs_span(obs, "token_fetch", "engine"):
-                    toks.append(np.asarray(next_tok))
+                if pending is not None and not pending.is_ready():
+                    overlapped += 1
+                tok, pos, caches = decode(params, tok, pos, caches)
+                if pending is not None:
+                    with obs_span(obs, "token_fetch", "engine"):
+                        toks.append(np.asarray(pending))
+                pending = tok
+        if pending is not None:
+            with obs_span(obs, "token_fetch", "engine"):
+                toks.append(np.asarray(pending))
         obs_count(obs, "decode_steps", len(toks) - 1)
+        obs_count(obs, "decode_overlapped", overlapped)
         return {
             "tokens": np.concatenate(toks, axis=1),
             "latency": clock() - t0,

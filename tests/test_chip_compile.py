@@ -115,9 +115,8 @@ def test_full_width_model_prefill_and_decode_compile(one_chip, level):
     pre = engine._prefill[level].lower(
         params, {"tokens": _spec(one_chip, (4, 8), jnp.int32)}).compile()
     dec = engine._decode[level].lower(
-        params, {"tokens": _spec(one_chip, (4, 1), jnp.int32),
-                 "cache_len": _spec(one_chip, (), jnp.int32)},
-        caches).compile()
+        params, _spec(one_chip, (4, 1), jnp.int32),
+        _spec(one_chip, (), jnp.int32), caches).compile()
     for c in (pre, dec):
         mem = c.memory_analysis()
         assert mem.argument_size_in_bytes < 16 * 2 ** 30

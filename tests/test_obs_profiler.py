@@ -205,6 +205,24 @@ class TestServeSpans:
             len(history)
         assert obs.metrics.counter("decode_steps").value == \
             sum(t.shape[1] - 1 for t in tokens)
+        assert 0 <= obs.metrics.counter("decode_overlapped").value <= \
+            obs.metrics.counter("decode_steps").value
+
+    def test_decode_steps_fetch_one_step_behind(self, model_setup,
+                                                monkeypatch):
+        """Each request's first decode step fetches nothing; every later
+        one holds one ``token_fetch`` (the previous step's token), and
+        the last token's fetch follows the loop: one fetch per token."""
+        obs = FlightRecorder()
+        _, tokens = _served(model_setup, monkeypatch, obs=obs)
+        ev = obs.spans.events
+        steps = [e for e in ev if e["name"] == "decode_step"]
+        fetches = [e for e in ev if e["name"] == "token_fetch"]
+        fetch_parents = [f["parent"] for f in fetches]
+        for e in steps:
+            assert fetch_parents.count(e["id"]) == \
+                (0 if e["args"]["step"] == 0 else 1)
+        assert len(fetches) == sum(t.shape[1] for t in tokens)
 
     def test_serve_spans_reach_the_trace(self, model_setup, monkeypatch,
                                          tmp_path):
@@ -260,8 +278,7 @@ def test_level_programs_carry_distinct_names(model_setup):
     for lvl in engine.levels:
         pre = engine._prefill[lvl].lower(params, {"tokens": toks})
         dec = engine._decode[lvl].lower(
-            params, {"tokens": toks[:, :1],
-                     "cache_len": jnp.asarray(4, jnp.int32)},
+            params, toks[:, :1], jnp.asarray(4, jnp.int32),
             engine.init_caches(lvl))
         assert f"@jit_prefill_level{lvl}" in pre.as_text()
         assert f"@jit_decode_level{lvl}" in dec.as_text()

@@ -65,6 +65,76 @@ class TestServeEngine:
                                        np.asarray(full[:, 7]),
                                        rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("lvl", [1, 2])
+    def test_generate_matches_stepwise_greedy_reference(self, nested_setup,
+                                                        lvl):
+        """``generate``'s tokens are the host-side greedy argmax of
+        ``lm_apply`` run one position at a time (prefill, then decode),
+        and each level keeps one decode trace after warm-up."""
+        from repro.models import transformer as tfm
+
+        cfg, model, params = nested_setup
+        engine = ServeEngine(model, max_len=16, batch_size=2)
+        rng = np.random.default_rng(lvl)
+        prompt = rng.integers(0, cfg.vocab, (2, 5), dtype=np.int32)
+        n_new = 7
+        out = tfm.lm_apply(params, cfg, jnp.asarray(prompt),
+                           mode="prefill", level=lvl)
+        caches = engine._merge(engine.init_caches(lvl), out.caches)
+        tok = np.argmax(np.asarray(out.logits[:, -1:]), axis=-1)
+        want = [tok]
+        for i in range(n_new - 1):
+            o = tfm.lm_apply(params, cfg, jnp.asarray(tok, jnp.int32),
+                             mode="decode", caches=caches,
+                             cache_len=jnp.asarray(5 + i, jnp.int32),
+                             level=lvl)
+            caches = o.caches
+            tok = np.argmax(np.asarray(o.logits[:, -1:]), axis=-1)
+            want.append(tok)
+        want = np.concatenate(want, axis=1).astype(np.int32)
+        engine.generate(params, prompt, n_new, level=lvl)
+        warm = engine.n_compiles()
+        got = engine.generate(params, prompt, n_new, level=lvl)
+        np.testing.assert_array_equal(got["tokens"], want)
+        assert got["complete"]
+        assert engine.n_compiles() == warm == (1, 1)
+
+    @pytest.mark.parametrize("k", [1, 2, 3, 5, 9])
+    def test_deadline_after_kth_clock_read(self, nested_setup, k):
+        """A clock that expires after its ``k``-th read: read 1 is entry
+        and read ``i + 2`` precedes step ``i``, so exactly the steps whose
+        read came before expiry are dispatched, and the last dispatched
+        step's token is returned."""
+        from repro.obs import FlightRecorder
+
+        class ExpiringClock:
+            def __init__(self):
+                self.reads = 0
+
+            def __call__(self):
+                self.reads += 1
+                return 0.0 if self.reads <= k else 1e9
+
+        cfg, model, params = nested_setup
+        engine = ServeEngine(model, max_len=16, batch_size=2)
+        prompt = np.zeros((2, 4), np.int32)
+        n_new = 6
+        full = engine.generate(params, prompt, n_new, level=2)["tokens"]
+        obs = FlightRecorder()
+        out = engine.generate(params, prompt, n_new, level=2,
+                              deadline_s=1.0, clock=ExpiringClock(),
+                              obs=obs)
+        n_tok = min(n_new, k)
+        assert out["tokens"].shape == (2, n_tok)
+        np.testing.assert_array_equal(out["tokens"], full[:, :n_tok])
+        assert out["complete"] == (n_tok == n_new)
+        assert out["latency"] == (0.0 if k > n_tok else 1e9)
+        steps = obs.metrics.counter("decode_steps").value
+        assert steps == n_tok - 1
+        assert 0 <= obs.metrics.counter("decode_overlapped").value <= steps
+        assert obs.metrics.counter("deadline_cutoffs").value == \
+            (0 if n_tok == n_new else 1)
+
     def test_deadline_cuts_generation_short(self, nested_setup):
         cfg, model, params = nested_setup
         engine = ServeEngine(model, max_len=64, batch_size=2)
