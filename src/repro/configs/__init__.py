@@ -1,6 +1,7 @@
 """Architecture registry: ``get_config(arch_id)`` / ``get_reduced(arch_id)``.
 
-The 10 assigned architectures plus the paper's own anytime family.
+The 10 assigned architectures, AI21-Jamba2-Mini and the paper's own
+anytime family.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ _MODULES = {
     "olmoe-1b-7b": "olmoe_1b_7b",
     "whisper-tiny": "whisper_tiny",
     "rwkv6-3b": "rwkv6_3b",
+    "AI21-Jamba2-Mini": "jamba2_mini",
     "alert-anytime-120m": "alert_anytime",
 }
 

@@ -31,6 +31,8 @@ class ModelConfig:
     global_every: int = 0                # gemma3: layer i is global iff
     #                                      (i+1) % global_every == 0; 0 = all global
     attn_logit_softcap: float | None = None
+    pos_encoding: str = "rope"           # rope | none (NoPE: Jamba's
+    #                                      attention has no positions)
 
     # --- MoE ---
     n_experts: int = 0
@@ -39,6 +41,12 @@ class ModelConfig:
     moe_offset: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    moe_renormalize: bool = True         # top-k gates rescaled to sum to 1
+    #                                      (Jamba keeps the softmax values)
+    experts_held: int = 0                # experts whose weights this chip
+    #                                      holds (0 = all n_experts); the
+    #                                      router stays n_experts wide
+    experts_offset: int = 0              # id of the first held expert
 
     # --- hybrid (Jamba): attention at layers i % attn_every == attn_offset,
     #     Mamba elsewhere.  attn_every == 0 means every layer is attention.
@@ -49,6 +57,8 @@ class ModelConfig:
     mamba_d_conv: int = 4
     mamba_dt_rank: int = 0               # 0 -> ceil(d_model/16)
     mamba_chunk: int = 128
+    mamba_inner_norms: bool = False      # Jamba: RMSNorm over dt, B and C
+    #                                      after x_proj
 
     # --- RWKV-6 ---
     rwkv: bool = False
@@ -84,7 +94,9 @@ class ModelConfig:
     attn_unroll_chunks: bool = False     # python-loop the attn chunk map
     #                                      (flop-calibration: no while op)
     moe_dispatch: str = "onehot"         # onehot (GShard) | gather (sorted
-    #                                      index dispatch — §Perf cell D)
+    #                                      index dispatch — §Perf cell D) |
+    #                                      dropless (grouped matmuls over
+    #                                      the held experts, no capacity)
 
     def __post_init__(self):
         if self.family not in ("dense", "moe", "hybrid", "ssm", "encdec",
@@ -94,6 +106,16 @@ class ModelConfig:
             raise ValueError("MoE config needs top_k")
         if self.rwkv and self.d_model % self.rwkv_head_dim:
             raise ValueError("d_model must divide into rwkv heads")
+        if self.pos_encoding not in ("rope", "none"):
+            raise ValueError(f"unknown pos_encoding {self.pos_encoding!r}")
+        if self.n_experts:
+            held = self.n_experts_here
+            if not (0 < held and 0 <= self.experts_offset
+                    and self.experts_offset + held <= self.n_experts):
+                raise ValueError("held experts must lie within n_experts")
+            if held < self.n_experts and self.moe_dispatch != "dropless":
+                raise ValueError("a share of the experts needs "
+                                 "moe_dispatch='dropless'")
 
     # ------------------------------------------------------------------ #
     @property
@@ -103,6 +125,11 @@ class ModelConfig:
     @property
     def mamba_dt_rank_actual(self) -> int:
         return self.mamba_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def n_experts_here(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def rwkv_n_heads(self) -> int:
@@ -159,6 +186,8 @@ class ModelConfig:
                 total += d * 2 * di + self.mamba_d_conv * di \
                     + di * (dt + 2 * ds) + dt * di + di * ds + 2 * di \
                     + di * d
+                if self.mamba_inner_norms:
+                    total += dt + 2 * ds                # dt, B, C norms
             elif mixer == "rwkv":
                 total += 5 * d                          # token-shift mus
                 total += 4 * d * d + d * d              # r,k,v,g + out
@@ -168,8 +197,8 @@ class ModelConfig:
             if ffn == "dense":
                 total += 3 * d * self.d_ff
             else:
-                total += d * self.n_experts
-                total += self.n_experts * 3 * d * self.d_ff
+                total += d * self.n_experts             # router
+                total += self.n_experts_here * 3 * d * self.d_ff
         if self.encoder_layers:
             # encoder self-attn + ffn, and decoder cross-attn add-ons
             enc = self.encoder_layers * (
@@ -189,7 +218,8 @@ class ModelConfig:
         total = self.param_count()
         for _, ffn in self.layer_plan():
             if ffn == "moe":
-                total -= (self.n_experts - self.top_k) * 3 * d * self.d_ff
+                total -= (self.n_experts_here - self.top_k) * 3 * d \
+                    * self.d_ff
         return total
 
     def replace(self, **kw) -> "ModelConfig":

@@ -5,6 +5,10 @@ layer [arXiv:2403.19887; hf].
 Layer period = 8: position 4 is attention, the other 7 are Mamba; odd
 positions carry the MoE FFN (16 experts, top-2), even carry dense FFN.
 SSM-dominant => runs the ``long_500k`` cell.
+
+Jamba's equations (``transformers``' ``modeling_jamba``): no positional
+encoding in attention, RMSNorms over the Mamba mixer's dt, B and C, and
+top-2 gates not renormalised.
 """
 
 from repro.configs.base import ModelConfig
@@ -28,6 +32,9 @@ CONFIG = ModelConfig(
     mamba_d_state=16,
     mamba_expand=2,
     mamba_d_conv=4,
+    mamba_inner_norms=True,
+    pos_encoding="none",
+    moe_renormalize=False,
 )
 
 

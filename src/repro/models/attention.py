@@ -1,4 +1,4 @@
-"""Attention: GQA + RoPE/M-RoPE + sliding window + cross-attention.
+"""Attention: GQA + RoPE/M-RoPE/NoPE + sliding window + cross-attention.
 
 Two execution paths:
 
@@ -212,7 +212,9 @@ def attention(params: dict, x: jax.Array, positions: jax.Array,
     k = k.reshape(b, s, n_kv, hd)
     v = v.reshape(b, s, n_kv, hd)
 
-    if cfg.m_rope and positions_3d is not None:
+    if cfg.pos_encoding == "none":
+        pass                      # NoPE: order comes from the causal mask
+    elif cfg.m_rope and positions_3d is not None:
         q = apply_mrope(q, positions_3d, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions_3d, cfg.rope_theta, cfg.mrope_sections)
     else:
@@ -300,8 +302,9 @@ def nested_attention(params: dict, x: jax.Array, positions: jax.Array,
     q = q.reshape(b, s, n_q, hd)
     k = k.reshape(b, s, n_kv, hd)
     v = v.reshape(b, s, n_kv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.pos_encoding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     if cache is not None and cache_len is not None:
         new_k = _scatter_at(cache.k, k, cache_len)
         new_v = _scatter_at(cache.v, v, cache_len)

@@ -7,6 +7,10 @@ across chunks (rematerialised per chunk), an inner ``lax.scan`` runs the
 recurrence within the chunk.  Decode is a single recurrence step carrying
 (ssm state, conv tail) — O(1) in sequence length, which is why Jamba runs
 the ``long_500k`` cell.
+
+With ``cfg.mamba_inner_norms`` (Jamba) dt, B and C are each RMS-normalised
+with a gain of their own right after ``x_proj``, before ``dt_proj`` and
+the scan, in prefill and decode alike.
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ def mamba_param_shapes(cfg: ModelConfig) -> dict:
     d, di = cfg.d_model, cfg.mamba_d_inner
     ds, dc = cfg.mamba_d_state, cfg.mamba_d_conv
     dt = cfg.mamba_dt_rank_actual
-    return {
+    shapes = {
         "in_proj": (d, 2 * di),
         "conv_w": (dc, di),
         "conv_b": (di,),
@@ -41,6 +45,9 @@ def mamba_param_shapes(cfg: ModelConfig) -> dict:
         "out_proj": (di, d),
         "norm": (d,),
     }
+    if cfg.mamba_inner_norms:
+        shapes.update(dt_norm=(dt,), b_norm=(ds,), c_norm=(ds,))
+    return shapes
 
 
 def mamba_init(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -49,7 +56,7 @@ def mamba_init(key: jax.Array, cfg: ModelConfig) -> dict:
     keys = split_keys(key, len(shapes))
     out = {}
     for (name, shape), k in zip(sorted(shapes.items()), keys):
-        if name == "norm":
+        if name.endswith("norm"):
             out[name] = jnp.ones(shape, dtype)
         elif name == "a_log":
             # S4D-real init: A = -(1..d_state), stored as log.
@@ -111,6 +118,10 @@ def mamba(params: dict, x: jax.Array, cfg: ModelConfig,
     proj = xc @ params["x_proj"]
     dt_raw, b_ssm, c_ssm = jnp.split(
         proj, [dt_rank, dt_rank + ds], axis=-1)
+    if cfg.mamba_inner_norms:
+        dt_raw = rms_norm(dt_raw, params["dt_norm"], cfg.norm_eps)
+        b_ssm = rms_norm(b_ssm, params["b_norm"], cfg.norm_eps)
+        c_ssm = rms_norm(c_ssm, params["c_norm"], cfg.norm_eps)
     delta = jax.nn.softplus(dt_raw @ params["dt_proj"]
                             + params["dt_bias"]).astype(jnp.float32)
     a = -jnp.exp(params["a_log"])                            # [di, ds]

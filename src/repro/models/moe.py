@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN: top-k router + GShard-style capacity dispatch.
+"""Mixture-of-Experts FFN: top-k router + GShard-style capacity dispatch,
+or a dropless grouped-matmul dispatch over a chip's held experts.
 
 Expert-parallel by construction: expert tensors lead with the E dim (sharded
 over the ``model`` mesh axis), tokens are grouped (group dim sharded over
@@ -24,9 +25,11 @@ MOE_GROUP_SIZE = 512  # tokens per dispatch group (see module docstring)
 
 
 def moe_param_shapes(cfg: ModelConfig) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router is ``n_experts`` wide; the expert weights are the held
+    experts' (``n_experts_here``)."""
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts_here
     return {
-        "router": (d, e),
+        "router": (d, cfg.n_experts),
         "w_gate": (e, d, f),
         "w_up": (e, d, f),
         "w_down": (e, f, d),
@@ -54,12 +57,15 @@ def capacity(group_size: int, top_k: int, n_experts: int,
     return max(int(group_size * top_k / n_experts * factor), top_k)
 
 
-def route_topk(logits: jax.Array, top_k: int
+def route_topk(logits: jax.Array, top_k: int, renormalize: bool = True
                ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (gate values [T,k] normalised, expert ids [T,k], probs [T,E])."""
+    """Returns (gate values [T,k], expert ids [T,k], probs [T,E]); the
+    gates are the top-k softmax values, rescaled to sum to 1 when
+    ``renormalize``."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     vals, idx = jax.lax.top_k(probs, top_k)
-    vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    if renormalize:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     return vals, idx, probs
 
 
@@ -80,7 +86,7 @@ def moe_gather(params: dict, x: jax.Array, cfg: ModelConfig
     xn = rms_norm(x, params["norm"], cfg.norm_eps)
     flat = xn.reshape(t, d)
     logits = flat.astype(jnp.float32) @ params["router"]
-    vals, idx, probs = route_topk(logits, k)          # [T,k]
+    vals, idx, probs = route_topk(logits, k, cfg.moe_renormalize)  # [T,k]
 
     # Flatten (token, slot) pairs; slot-major order preserves the one-hot
     # version's drop priority (all slot-0 assignments outrank slot-1).
@@ -113,10 +119,81 @@ def moe_gather(params: dict, x: jax.Array, cfg: ModelConfig
     return out.reshape(b, s, d), aux.astype(jnp.float32)
 
 
+def held_mask(cfg: ModelConfig, idx: jax.Array) -> jax.Array:
+    """Which expert ids in ``idx`` this chip holds."""
+    return (idx >= cfg.experts_offset) & \
+        (idx < cfg.experts_offset + cfg.n_experts_here)
+
+
+def route_stats(cfg: ModelConfig, prefill: jax.Array, steps: tuple
+                ) -> jax.Array:
+    """``[assigned, hit]`` int32 of one request's expert choices:
+    ``prefill`` [n_moe, T, k] and ``steps``, each decode step's [n_moe,
+    B, k] (-1 where no step ran).  ``assigned`` counts the (token, slot)
+    pairs routed to experts held here, over prefill and decode; ``hit``
+    the held experts a decode step gave at least one token, summed over
+    the steps and expert layers."""
+    assigned = jnp.sum(held_mask(cfg, prefill))
+    hit = jnp.zeros((), jnp.int32)
+    if steps:
+        dec = jnp.stack(steps)                          # [n, n_moe, B, k]
+        held = held_mask(cfg, dec)
+        local = jnp.where(held, dec - cfg.experts_offset, -1)
+        ids = jnp.arange(cfg.n_experts_here)
+        hit = jnp.sum(jnp.any(local[..., None] == ids, axis=(2, 3)))
+        assigned = assigned + jnp.sum(held)
+    return jnp.stack([assigned, hit]).astype(jnp.int32)
+
+
+def moe_dropless(params: dict, x: jax.Array, cfg: ModelConfig
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless dispatch over the experts this chip holds (serving).
+
+    The router scores all ``n_experts`` in float32 and picks the top-k;
+    the (token, slot) pairs whose expert is held here are sorted by
+    expert and run through the three expert products as grouped matmuls
+    (``jax.lax.ragged_dot``: each expert's rows, no capacity, no
+    padding), then scatter-added back with their gates.  Pairs routed to
+    experts held elsewhere add nothing here: with every expert held this
+    is the whole layer, with a share it is that share's part of the sum
+    (expert parallelism without its exchange).  Returns (output [B,S,d],
+    aux loss, expert ids [B*S, k] int32).
+    """
+    b, s, d = x.shape
+    k, n_here = cfg.top_k, cfg.n_experts_here
+    t = b * s
+    xn = rms_norm(x, params["norm"], cfg.norm_eps)
+    flat = xn.reshape(t, d)
+    logits = flat.astype(jnp.float32) @ params["router"]
+    vals, idx, probs = route_topk(logits, k, cfg.moe_renormalize)
+    held = held_mask(cfg, idx).reshape(-1)              # [T*k], token-major
+    group = jnp.where(held, idx.reshape(-1) - cfg.experts_offset, n_here)
+    order = jnp.argsort(group, stable=True)             # held pairs first
+    sizes = jnp.bincount(group, length=n_here + 1)[:n_here].astype(
+        jnp.int32)
+    tok = order // k
+    rows = flat[tok]
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, params["w_gate"], sizes)) \
+        * jax.lax.ragged_dot(rows, params["w_up"], sizes)
+    y = jax.lax.ragged_dot(h, params["w_down"], sizes)
+    # Rows past the held pairs are left undefined by the TPU's grouped
+    # product (any bits, NaN among them): select, never scale, them away.
+    y = jnp.where(held[order][:, None],
+                  y.astype(jnp.float32) * vals.reshape(-1)[order][:, None],
+                  0.0)
+    out = jnp.zeros((t, d), jnp.float32).at[tok].add(y)
+    frac = jnp.zeros((cfg.n_experts,), jnp.float32).at[
+        idx.reshape(-1)].add(1.0) / (t * k)
+    aux = cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
+    return out.astype(x.dtype).reshape(b, s, d), aux, idx.astype(jnp.int32)
+
+
 def moe(params: dict, x: jax.Array, cfg: ModelConfig,
         group_size: int = MOE_GROUP_SIZE
         ) -> tuple[jax.Array, jax.Array]:
     """Returns (output [B,S,d], aux load-balancing loss scalar)."""
+    if cfg.moe_dispatch == "dropless":
+        return moe_dropless(params, x, cfg)[:2]
     if getattr(cfg, "moe_dispatch", "onehot") == "gather":
         return moe_gather(params, x, cfg)
     b, s, d = x.shape
@@ -132,7 +209,8 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig,
     flat = xn.reshape(g, sg, d)
     logits = jnp.einsum("gsd,de->gse", flat.astype(jnp.float32),
                         params["router"])
-    vals, idx, probs = route_topk(logits.reshape(t, e), k)
+    vals, idx, probs = route_topk(logits.reshape(t, e), k,
+                                  cfg.moe_renormalize)
     vals = vals.reshape(g, sg, k)
     idx = idx.reshape(g, sg, k)
 

@@ -60,6 +60,9 @@ class LMOutput(NamedTuple):
     logits: jax.Array | list[jax.Array]
     aux_loss: jax.Array
     caches: Any
+    # Dropless MoE only: every expert layer's choices, [n_moe_layers,
+    # B*S, top_k] int32 in layer order (None for other configs).
+    routing: Any = None
 
 
 # --------------------------------------------------------------------- #
@@ -102,7 +105,8 @@ def apply_layer(lp: dict, x: jax.Array, positions: jax.Array,
                 cfg: ModelConfig, mixer: str, ffn: str, *,
                 pos3d: jax.Array | None = None, cache=None,
                 cache_len=None, level: int | None = None):
-    """Returns (x, aux, new_cache)."""
+    """Returns (x, aux, new_cache, route): ``route`` is a dropless MoE
+    layer's expert ids [B*S, top_k], else None."""
     aux = jnp.zeros((), jnp.float32)
     nested = cfg.nest_levels > 1
     if mixer in ("attn", "attn_local"):
@@ -127,19 +131,23 @@ def apply_layer(lp: dict, x: jax.Array, positions: jax.Array,
                                               state=cache)
         x = x + c
         new_cache = rwkv_mod.RwkvState(wkv, tail_t, tail_c)
-        return x, aux, new_cache
+        return x, aux, new_cache, None
     else:
         raise ValueError(mixer)
 
+    route = None
     if ffn == "dense":
         if nested:
             x = x + mlp_mod.nested_mlp(lp["ffn"], x, cfg, level=level)
         else:
             x = x + mlp_mod.mlp(lp["ffn"], x, cfg)
+    elif cfg.moe_dispatch == "dropless":
+        o, aux, route = moe_mod.moe_dropless(lp["ffn"], x, cfg)
+        x = x + o
     else:
         o, aux = moe_mod.moe(lp["ffn"], x, cfg)
         x = x + o
-    return x, aux, new_cache
+    return x, aux, new_cache, route
 
 
 # --------------------------------------------------------------------- #
@@ -244,22 +252,25 @@ def lm_apply(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
         x = x[..., :d_spec_trunc.width(level)]
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: dict = {}
+    routes: list = []
 
     def block(x, block_params, block_caches):
         """One period of layers (positions 0..p-1)."""
         aux_sum = jnp.zeros((), jnp.float32)
-        outs = {}
+        outs, routes = {}, []
         if mode != "decode":
             x = _constrain(x)
         for pos in range(p):
             mixer, ffn = plan[pos]
             cache = block_caches.get(f"pos{pos}") if block_caches else None
-            x, aux, nc = apply_layer(
+            x, aux, nc, route = apply_layer(
                 block_params[f"pos{pos}"], x, positions, cfg, mixer, ffn,
                 pos3d=pos3d, cache=cache, cache_len=cache_len, level=level)
             aux_sum = aux_sum + aux
             outs[f"pos{pos}"] = nc if want_cache else None
-        return x, aux_sum, outs
+            if route is not None:
+                routes.append(route)
+        return x, aux_sum, (outs, routes)
 
     if r > 0:
         def scan_body(carry, xs):
@@ -281,8 +292,13 @@ def lm_apply(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
                                               for q in range(p)}))
             (x, aux_total), outs = jax.lax.scan(
                 scan_body_nc, (x, aux_total), params["group"])
+        outs, group_routes = outs
         if want_cache:
             new_caches["group"] = outs
+        if group_routes:
+            # [r, n_pos, T, k] -> layer order (repeat-major).
+            stacked = jnp.stack(group_routes, axis=1)
+            routes.extend(stacked.reshape((-1,) + stacked.shape[2:]))
 
     for i in range(rem):
         li = r * p + i
@@ -294,10 +310,12 @@ def lm_apply(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
                                cache_len=cache_len, level=level)
         if cfg.remat and mode == "train":
             layer_fn = jax.checkpoint(layer_fn, policy=_resolve_policy(cfg))
-        x, aux, nc = layer_fn(params[f"rem{i}"], x)
+        x, aux, nc, route = layer_fn(params[f"rem{i}"], x)
         aux_total = aux_total + aux
         if want_cache:
             new_caches[f"rem{i}"] = nc
+        if route is not None:
+            routes.append(route)
 
     if mode == "prefill" and cfg.prefill_last_only:
         # Serving semantics (hillclimb lever): prefill's product is the KV
@@ -309,7 +327,8 @@ def lm_apply(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     if return_hidden:
         # Chunked-loss path: caller projects to vocab chunk-by-chunk.
         h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return LMOutput(h, aux_total, new_caches if want_cache else None)
+        return LMOutput(h, aux_total, new_caches if want_cache else None,
+                        _stack_routes(routes))
 
     unembed = params.get("unembed")
     if unembed is None:
@@ -328,4 +347,10 @@ def lm_apply(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     else:
         h = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = h @ unembed
-    return LMOutput(logits, aux_total, new_caches if want_cache else None)
+    return LMOutput(logits, aux_total, new_caches if want_cache else None,
+                    _stack_routes(routes))
+
+
+def _stack_routes(routes: list):
+    """The expert layers' choices as one array, or None without any."""
+    return jnp.stack(routes) if routes else None

@@ -13,6 +13,7 @@ pod; on CPU (tests, examples) it runs single-device.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any
 
@@ -20,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.moe import route_stats
 from repro.models.registry import Model
 from repro.models import transformer as tfm
 from repro.obs.trace import count as obs_count, span as obs_span
@@ -35,13 +37,21 @@ def _greedy_step(cfg, lvl):
     """The greedy decode step at level ``lvl``, for one device program."""
     def step(params, token, cache_len, caches):
         """``(params, token [B, 1] int32, cache_len, caches)`` to the next
-        token [B, 1] int32, ``cache_len + 1`` and the updated caches."""
+        token [B, 1] int32, ``cache_len + 1``, the updated caches and a
+        dropless MoE model's expert choices [n_moe, B, k] (else None)."""
         o = tfm.lm_apply(params, cfg, token, mode="decode", caches=caches,
                          cache_len=cache_len, level=lvl)
         lg = o.logits[-1] if isinstance(o.logits, list) else o.logits
         nxt = jnp.argmax(lg[:, -1:], axis=-1).astype(jnp.int32)
-        return nxt, cache_len + 1, o.caches
+        return nxt, cache_len + 1, o.caches, o.routing
     return step
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _grow(pre, shape):
+    """A prefill KV leaf zero-padded along its sequence axis to the
+    decode buffer's ``shape`` (one program per shape)."""
+    return jnp.pad(pre, [(0, n - m) for n, m in zip(shape, pre.shape)])
 
 
 @dataclasses.dataclass
@@ -70,6 +80,14 @@ class ServeEngine:
             self._decode[lvl] = jax.jit(
                 _named(_greedy_step(cfg, lvl), f"decode{tag}"),
                 donate_argnums=(3,))
+        self._route_stats = jax.jit(functools.partial(route_stats, cfg))
+        self._cache_shapes, self._no_step = {}, {}
+
+    @property
+    def routed(self) -> bool:
+        """Whether the programs also return their expert choices (a
+        dropless MoE model)."""
+        return self.model.cfg.moe_dispatch == "dropless"
 
     def init_caches(self, level: int | None = None):
         """Fresh decode caches sized to ``level`` (level-k programs write
@@ -123,21 +141,37 @@ class ServeEngine:
         device arrays and returns the next ones (the caches donated), and
         the host reads each token one step behind: step *i* is dispatched
         before token *i-1* is fetched, so the device runs while the host
-        dispatches.  ``clock`` injects the timebase (default
+        dispatches.  The decode buffers are the prefill's caches: a KV
+        leaf is padded to ``max_len`` on the device, a state without a
+        sequence axis (Mamba's SSM state and conv tail) is taken as it
+        is.  ``clock`` injects the timebase (default
         ``time.perf_counter``; read once at entry, once before each step
         and once at the end) so deterministic tests drive deadlines and
         reported latency without real wall clocks; the reported latency
         is compute-inclusive because the last token is fetched before the
         final clock read.
 
+        For a dropless MoE model the result also holds ``routing``: the
+        prefill's expert choices [n_moe, B*S0, k] and each decode step's
+        [n_moe, B, k], as device arrays (the loop never waits for them),
+        and the request's ``moe_assigned_here`` and ``moe_experts_hit``
+        (the counters below), reduced from them on the device by one
+        program after the loop and fetched once, with the last token.
+
         Spans (``obs`` is an optional :class:`~repro.obs.FlightRecorder`;
         see :mod:`repro.obs.trace`): ``first_token`` from entry to the
-        first token on the host, then one ``decode_step`` per step over
+        first token on the host, with a child ``cache_setup`` over making
+        the decode buffers from the prefill's caches, then one
+        ``decode_step`` per step over
         its dispatch and the fetch of the previous step's token, in a
         child ``token_fetch``; the last token's ``token_fetch`` follows the
         loop.  Counters: ``decode_steps``, ``decode_overlapped`` (steps
         dispatched while the previous one was still on the device) and
-        ``deadline_cutoffs`` (a deadline stopped the loop early).
+        ``deadline_cutoffs`` (a deadline stopped the loop early), and for
+        a dropless MoE model ``moe_assigned_here`` ((token, slot) pairs
+        routed to the held experts, prefill and decode) and
+        ``moe_experts_hit`` (held experts given a token, summed over
+        decode steps and expert layers).
         """
         if clock is None:
             clock = time.perf_counter
@@ -146,9 +180,11 @@ class ServeEngine:
         lvl = level if level is not None else \
             (cfg.nest_levels if cfg.nest_levels > 1 else None)
         b, s0 = prompt.shape
+        routed, stats, steps = self.routed, None, []
         with obs_span(obs, "first_token", "engine", level=lvl):
             out = self.prefill(params, prompt, lvl)
-            caches = self._merge(self.init_caches(lvl), out.caches)
+            with obs_span(obs, "cache_setup", "engine"):
+                caches = self._merge(self.cache_shapes(lvl), out.caches)
             logits = out.logits if not isinstance(out.logits, list) \
                 else out.logits[-1]
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
@@ -163,30 +199,65 @@ class ServeEngine:
             with obs_span(obs, "decode_step", "engine", step=i):
                 if pending is not None and not pending.is_ready():
                     overlapped += 1
-                tok, pos, caches = decode(params, tok, pos, caches)
+                tok, pos, caches, r = decode(params, tok, pos, caches)
+                steps.append(r)
                 if pending is not None:
                     with obs_span(obs, "token_fetch", "engine"):
                         toks.append(np.asarray(pending))
                 pending = tok
+        if routed:
+            # Steps a deadline left out read as routed nowhere (one
+            # program for any number of steps run).
+            stats = self._route_stats(out.routing, tuple(steps) + (
+                self._unrouted(out.routing.shape[0], b),)
+                * (n_new - 1 - len(steps)))
         if pending is not None:
             with obs_span(obs, "token_fetch", "engine"):
-                toks.append(np.asarray(pending))
+                last, stats = jax.device_get((pending, stats))
+                toks.append(last)
+        else:
+            stats = jax.device_get(stats)
         obs_count(obs, "decode_steps", len(toks) - 1)
         obs_count(obs, "decode_overlapped", overlapped)
-        return {
+        res = {
             "tokens": np.concatenate(toks, axis=1),
             "latency": clock() - t0,
             "level": lvl,
             "complete": len(toks) == n_new,
         }
+        if routed:
+            res["moe_assigned_here"], res["moe_experts_hit"] = \
+                int(stats[0]), int(stats[1])
+            obs_count(obs, "moe_assigned_here", res["moe_assigned_here"])
+            obs_count(obs, "moe_experts_hit", res["moe_experts_hit"])
+            res["routing"] = {"prefill": out.routing, "decode": steps}
+        return res
+
+    def _unrouted(self, n_moe: int, batch: int):
+        """A decode step's expert choices where no step ran: -1, held
+        nowhere."""
+        key = (n_moe, batch)
+        if key not in self._no_step:
+            self._no_step[key] = jnp.full(
+                (n_moe, batch, self.model.cfg.top_k), -1, jnp.int32)
+        return self._no_step[key]
+
+    def cache_shapes(self, level: int | None = None):
+        """The decode buffers' shapes at ``level`` (no allocation)."""
+        if level not in self._cache_shapes:
+            self._cache_shapes[level] = jax.eval_shape(
+                lambda: self.init_caches(level))
+        return self._cache_shapes[level]
 
     @staticmethod
     def _merge(buffers, prefill):
+        """The decode caches from the prefill's: each leaf whose shape
+        differs from its buffer in ``buffers`` (arrays or shapes) — a KV
+        leaf, shorter along its sequence axis — is zero-padded to it on
+        the device; any other leaf is the prefill's own array, uncopied."""
         def merge(buf, pre):
-            """Copy a prefill cache leaf into the decode buffer leaf."""
-            buf, pre = jnp.asarray(buf), jnp.asarray(pre)
+            """One decode cache leaf from its prefill leaf."""
             if buf.shape == pre.shape:
                 return pre
-            return jax.lax.dynamic_update_slice_in_dim(
-                buf, pre.astype(buf.dtype), 0, axis=buf.ndim - 3)
+            return _grow(pre.astype(buf.dtype), tuple(buf.shape))
         return jax.tree.map(merge, buffers, prefill)

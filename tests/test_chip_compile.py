@@ -137,3 +137,60 @@ def test_alert_select_compiles_to_mosaic(one_chip):
             *_lane_args(one_chip, S_SELECT)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert np.isfinite(compiled.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def jamba_cut(one_chip):
+    """The ``jamba2-mini-ep2`` cell's cut of AI21-Jamba2-Mini (8 layers,
+    8 of 16 experts held, 32,768 vocabulary rows, published widths) and
+    its serving engine at the cell's batch and lengths."""
+    import json
+
+    from bench import hybrid_ref
+    from repro.models.registry import build_model
+    from repro.serving.engine import ServeEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "jamba2-mini-ep2.json")) as f:
+        cfg = hybrid_ref.model_config(json.load(f))
+    assert (cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_experts_here,
+            cfg.vocab) == (4096, 14336, 16, 8, 32768)
+    model = build_model(cfg)
+    engine = ServeEngine(model, max_len=1024 + 32, batch_size=4)
+    put = lambda t: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), t)
+    params = put(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    return cfg, engine, params, put
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "weights"])
+def test_jamba_cut_fits_one_chip(one_chip, jamba_cut, program):
+    """(e) The Jamba cut on one chip: prefill of 4 x 1,024 tokens, a
+    decode step over 1,056-position buffers, and the weight maker each
+    compile with their arguments and temporaries inside 16 GiB; the
+    dropless expert layer's grouped products lower as ragged dots."""
+    from bench import hybrid_ref
+
+    cfg, engine, params, put = jamba_cut
+    if program == "prefill":
+        c = engine._prefill[None].lower(
+            params, {"tokens": _spec(one_chip, (4, 1024), jnp.int32)}
+        ).compile()
+        assert "ragged-dot" in c.as_text()
+    elif program == "decode":
+        c = engine._decode[None].lower(
+            params, _spec(one_chip, (4, 1), jnp.int32),
+            _spec(one_chip, (), jnp.int32),
+            put(engine.cache_shapes())).compile()
+        assert "ragged-dot" in c.as_text()
+    else:
+        k = jax.eval_shape(lambda: hybrid_ref.key_for(0))
+        c = hybrid_ref._param_fn(cfg).lower(
+            _spec(one_chip, k.shape, k.dtype)).compile()
+        # Every weight is drawn in place: no leaf-sized temporary.
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** 26
+    mem = c.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + \
+        mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert used < 16 * 2 ** 30

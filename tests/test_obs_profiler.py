@@ -41,8 +41,8 @@ GATEWAY_SPANS = {"gateway.admit", "gateway.serve_round", "paging.page_in",
                  "gateway.select", "gateway.deliver", "gateway.feedback"}
 SERVE_SPANS = {"serve.request", "serve.controller_select",
                "controller.engine_select", "engine.first_token",
-               "engine.decode_step", "engine.token_fetch",
-               "serve.controller_observe"}
+               "engine.cache_setup", "engine.decode_step",
+               "engine.token_fetch", "serve.controller_observe"}
 
 
 @pytest.fixture(scope="module")
