@@ -21,9 +21,10 @@ shifts.  :class:`SessionGateway` serves that regime with ONE
   fastest profiled config, and bounded-queue backpressure at submit.
 * **Session paging** — each served session needs its own Kalman/goal
   state, but only ``n_lanes`` lanes exist.  The gateway keeps a resident
-  set; a round that needs a non-resident session evicts the
-  least-recently-used resident (``export_lanes`` snapshots its state to
-  a host store) and restores the incomer (``import_lanes``) — same-shape
+  set; a round that needs non-resident sessions evicts the
+  least-recently-used residents (one ``export_lanes`` per bank,
+  scattered into a host store of columns indexed by session) and
+  restores the incomers (one ``import_lanes`` per bank) — same-shape
   ``[S]`` writes only, so paging reuses the churn-no-retrace protocol of
   DESIGN.md §5 and the engine never re-traces.
 * **Delivery** — the shared :func:`~repro.serving.sim.deliver_tick`
@@ -36,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+from collections.abc import Iterator, Mapping
 from typing import Sequence
 
 import numpy as np
@@ -103,6 +105,26 @@ def _obs_record_result(metrics, out: "GatewayResult", *, gateway: str,
     metrics.gauge("n_compiles_select", gateway=gateway).set(
         out.n_compiles[1])
 
+def _lru_pick(resident: np.ndarray, last_used: np.ndarray,
+              avail: np.ndarray, needed: np.ndarray,
+              n_missing: int) -> tuple[np.ndarray, np.ndarray]:
+    """Lanes for ``n_missing`` non-resident sessions: the free ``avail``
+    lanes in ascending order, and, where they are too few, the
+    least-recently-used ``avail`` residents not in ``needed`` to evict,
+    ties broken by lane (a stable argsort of ``last_used`` over
+    ascending lanes: the ``(last_used, lane)`` order).  Returns
+    ``(free, evict)``; the caller takes them in that order.  The LRU
+    policy both gateways page by."""
+    free = np.nonzero((resident < 0) & avail)[0]
+    n_evict = n_missing - free.size
+    if n_evict <= 0:
+        return free, free[:0]
+    cand = avail & (resident >= 0)
+    cand[cand] = ~np.isin(resident[cand], needed)
+    cand = np.nonzero(cand)[0]
+    return free, cand[np.argsort(last_used[cand], kind="stable")][:n_evict]
+
+
 # GatewayResult arrays a checkpoint must carry (the loop mutates these;
 # sid/index/arrival are rebuilt from the workload at resume).
 _CKPT_OUT_FIELDS = ("status", "start", "latency", "sojourn", "missed",
@@ -120,6 +142,7 @@ class _RunState:
     tick: float
     queue: DeadlineBatcher
     out: "GatewayResult"
+    sidx: np.ndarray            # dense session index per request row
     ri: int = 0                 # next unsubmitted request index
     round_k: int = 0            # round clock
     n_rounds: int = 0           # rounds that served a batch
@@ -277,6 +300,15 @@ class SessionGateway:
         self._is_anytime = np.zeros(len(table.candidates), bool)
         self._is_anytime[sorted({i for g in groups.values()
                                  for i in g})] = True
+        # The session store: columns over a dense session index, built
+        # per run by _index_sessions (empty until the first run).
+        self._banks = {"slow": self.slow, "idle": self.idle,
+                       "goal": self.goal_bank}
+        self._sess = None
+        self._sids = np.zeros(0, dtype=np.int64)
+        self._stored = np.zeros(0, dtype=bool)
+        self._lane_of = np.zeros(0, dtype=np.int64)
+        self._cols: dict[str, dict[str, np.ndarray]] = {}
         self._reset_lane_pool()
 
     # -------------------------------------------------------------- #
@@ -287,8 +319,8 @@ class SessionGateway:
         ``[S]`` shapes are untouched, so the engine's jit cache
         survives."""
         self._resident = np.full(self.n_lanes, -1, dtype=np.int64)
-        self._lane_of: dict[int, int] = {}
-        self._store: dict[int, dict] = {}
+        self._stored[:] = False
+        self._lane_of[:] = -1
         self._goal_kinds = np.zeros(self.n_lanes, dtype=np.int64)
         self._last_used = np.zeros(self.n_lanes, dtype=np.int64)
         self._busy_until = np.zeros(self.n_lanes)
@@ -299,27 +331,61 @@ class SessionGateway:
         self.idle.reset_lanes(all_lanes)
         self.goal_bank.reset_lanes(all_lanes, goal=np.zeros(self.n_lanes))
 
-    def _evict_lanes(self, ev_lanes: Sequence[int]) -> None:
-        """Page the residents of ``ev_lanes`` out to the host store (one
-        batched ``export_lanes`` per bank) and free the lanes.  Shared
-        by LRU eviction and device-loss quarantine — a dead lane's
-        session state survives the device and can be re-admitted on a
-        surviving lane (DESIGN.md §10)."""
-        if not len(ev_lanes):
+    def _index_sessions(self, sess: dict[int, Session]) -> None:
+        """Index one run's sessions densely, in ascending sid order, over
+        an empty lane pool: the session store is a set of columns over
+        that index (one per bank state field, ``[n, W-1]`` for the goal
+        ring), with its ``stored`` mask and the ``lane_of`` map beside
+        them.  Each session's goal code and accuracy goal are read here
+        once, so a round never touches a :class:`Session`.  The columns
+        are kept for the next run of the same session count."""
+        if (self._resident >= 0).any() or self._stored.any():
+            raise RuntimeError("sessions are indexed only over an empty "
+                               "lane pool and session store")
+        sids = np.fromiter(sess, dtype=np.int64, count=len(sess))
+        order = np.argsort(sids, kind="stable")
+        self._sids = sids[order]
+        goals = [s.goal for s in sess.values()]
+        code_of = {g: int(goal_codes([g])[0]) for g in set(goals)}
+        self._sess_goal_kind = np.asarray(
+            [code_of[g] for g in goals], dtype=np.int64)[order]
+        self._sess_accuracy_goal = np.asarray(
+            [s.constraints.accuracy_goal or 0.0 for s in sess.values()],
+            dtype=np.float64)[order]
+        n = len(sids)
+        if self._stored.shape[0] != n:
+            none = np.zeros(0, dtype=np.int64)
+            self._cols = {
+                part: {name: np.zeros((n,) + v.shape[1:], v.dtype)
+                       for name, v in bank.export_lanes(none).items()}
+                for part, bank in self._banks.items()}
+            self._stored = np.zeros(n, dtype=bool)
+            self._lane_of = np.full(n, -1, dtype=np.int64)
+        self._sess = sess
+
+    def _dense(self, sids) -> np.ndarray:
+        """Dense session indices of raw ``sids`` (all indexed)."""
+        return np.searchsorted(self._sids, sids)
+
+    def _evict_lanes(self, ev_lanes) -> None:
+        """Page the residents of ``ev_lanes`` out to the session store
+        (one batched ``export_lanes`` per bank, scattered into the
+        columns) and free the lanes.  Shared by LRU eviction and
+        device-loss quarantine — a dead lane's session state survives
+        the device and can be re-admitted on a surviving lane
+        (DESIGN.md §10)."""
+        ev = np.asarray(ev_lanes, dtype=np.int64)
+        if not ev.size:
             return
-        slow_s = self.slow.export_lanes(ev_lanes)
-        idle_s = self.idle.export_lanes(ev_lanes)
-        goal_s = self.goal_bank.export_lanes(ev_lanes)
-        for k, ln in enumerate(ev_lanes):
-            old = int(self._resident[ln])
-            self._store[old] = {
-                "slow": {n: v[k:k + 1] for n, v in slow_s.items()},
-                "idle": {n: v[k:k + 1] for n, v in idle_s.items()},
-                "goal": {n: v[k:k + 1] for n, v in goal_s.items()},
-            }
-            del self._lane_of[old]
-            self._resident[ln] = -1
-            self.pages_out += 1
+        olds = self._dense(self._resident[ev])
+        for part, bank in self._banks.items():
+            cols = self._cols[part]
+            for name, v in bank.export_lanes(ev).items():
+                cols[name][olds] = v
+        self._stored[olds] = True
+        self._lane_of[olds] = -1
+        self._resident[ev] = -1
+        self.pages_out += int(ev.size)
 
     def _page_in(self, sids: Sequence[int],
                  sessions: dict[int, Session], round_k: int,
@@ -329,87 +395,81 @@ class SessionGateway:
 
         Non-residents land in free idle lanes first, then evict the
         least-recently-used *idle* residents not needed this round (a
-        busy lane's session is mid-service and cannot move): the
-        evictees' filter + goal-window state is snapshotted to the host
-        store (one batched ``export_lanes``) and the incomers' state
-        restored (one batched ``import_lanes`` for paged sessions, one
-        ``reset_lanes`` for first-time sessions) — same-shape writes
-        only, so paging can never re-trace the engine (DESIGN.md §7).
+        busy lane's session is mid-service and cannot move;
+        :func:`_lru_pick`).  The evictees' filter + goal-window state goes
+        to the session store (one ``export_lanes`` per bank) and the
+        incomers' state is restored (one ``import_lanes`` per bank for
+        paged sessions, one ``reset_lanes`` for first-time sessions) —
+        same-shape writes only, so paging can never re-trace the engine
+        (DESIGN.md §7).  ``sessions`` is the run's mapping; a direct
+        call with another one indexes it first.
         """
-        needed = set(sids)
-        lanes = np.empty(len(sids), dtype=np.int64)
-        missing: list[int] = []           # position in sids
-        for pos, sid in enumerate(sids):
-            lane = self._lane_of.get(sid, -1)
-            lanes[pos] = lane
-            if lane < 0:
-                missing.append(pos)
-        if missing:
-            idle = (self._busy_until <= now) & ~self._dead
-            free = [int(x) for x in
-                    np.nonzero((self._resident < 0) & idle)[0]]
-            n_evict = len(missing) - len(free)
-            if n_evict > 0:
-                evictable = [(int(self._last_used[ln]), ln)
-                             for ln in range(self.n_lanes)
-                             if idle[ln] and self._resident[ln] >= 0
-                             and int(self._resident[ln]) not in needed]
-                evictable.sort()
-                ev_lanes = [ln for _, ln in evictable[:n_evict]]
-            else:
-                ev_lanes = []
-            if ev_lanes:
-                self._evict_lanes(ev_lanes)
-                free += ev_lanes
-            if len(free) < len(missing):
-                # Eviction could not produce enough idle lanes (every
-                # other resident is busy or needed this round).  A
-                # silent zip truncation here would leave lanes[pos] ==
-                # -1 and corrupt the last lane downstream, so fail
-                # loudly instead.
+        if sessions is not self._sess:
+            self._index_sessions(sessions)
+        with obs_span(self._ob, "page_in", "paging",
+                      round_k=round_k) as sp:
+            sids = np.asarray(sids, dtype=np.int64)
+            idx = self._dense(sids)
+            lanes = self._lane_of[idx]
+            miss = np.nonzero(lanes < 0)[0]
+            n_in = n_out = 0
+            if miss.size:
+                free, ev = _lru_pick(
+                    self._resident, self._last_used,
+                    (self._busy_until <= now) & ~self._dead, sids,
+                    miss.size)
+                if ev.size:
+                    self._evict_lanes(ev)
+                    n_out = int(ev.size)
+                    free = np.concatenate([free, ev])
+                if free.size < miss.size:
+                    # Eviction could not produce enough idle lanes (every
+                    # other resident is busy or needed this round).  A
+                    # silent truncation here would leave a lane of -1
+                    # and corrupt the last lane downstream, so fail
+                    # loudly instead.
+                    raise RuntimeError(
+                        f"page-in underflow: {miss.size} non-resident "
+                        f"session(s) need lanes but only {free.size} "
+                        "lane(s) are free or evictable (the rest are busy"
+                        " or needed this round)")
+                take = free[:miss.size]
+                inc = idx[miss]
+                lanes[miss] = take
+                self._resident[take] = sids[miss]
+                self._lane_of[inc] = take
+                self._goal_kinds[take] = self._sess_goal_kind[inc]
+                paged = self._stored[inc]
+                if paged.any():
+                    p_lanes, p_idx = take[paged], inc[paged]
+                    for part, bank in self._banks.items():
+                        bank.import_lanes(p_lanes, {
+                            name: col[p_idx]
+                            for name, col in self._cols[part].items()})
+                    self._stored[p_idx] = False
+                    n_in = int(p_lanes.size)
+                    self.pages_in += n_in
+                if n_in < miss.size:
+                    f_lanes, f_idx = take[~paged], inc[~paged]
+                    self.slow.reset_lanes(f_lanes)
+                    self.idle.reset_lanes(f_lanes)
+                    self.goal_bank.reset_lanes(
+                        f_lanes, goal=self._sess_accuracy_goal[f_idx])
+            sp.set(n_in=n_in, n_fresh=int(miss.size) - n_in, n_out=n_out)
+            if np.any(lanes < 0):
                 raise RuntimeError(
-                    f"page-in underflow: {len(missing)} non-resident "
-                    f"session(s) need lanes but only {len(free)} lane(s)"
-                    " are free or evictable (the rest are busy or needed"
-                    " this round)")
-            paged_lanes, paged_sids, fresh_lanes, fresh_sids = \
-                [], [], [], []
-            for pos, ln in zip(missing, free):
-                sid = sids[pos]
-                lanes[pos] = ln
-                self._resident[ln] = sid
-                self._lane_of[sid] = ln
-                if sid in self._store:
-                    paged_lanes.append(ln)
-                    paged_sids.append(sid)
-                else:
-                    fresh_lanes.append(ln)
-                    fresh_sids.append(sid)
-                self._goal_kinds[ln] = goal_codes([sessions[sid].goal])[0]
-            if paged_lanes:
-                cat = lambda part: {
-                    n: np.concatenate([self._store[s][part][n]
-                                       for s in paged_sids])
-                    for n in self._store[paged_sids[0]][part]}
-                self.slow.import_lanes(paged_lanes, cat("slow"))
-                self.idle.import_lanes(paged_lanes, cat("idle"))
-                self.goal_bank.import_lanes(paged_lanes, cat("goal"))
-                for s in paged_sids:
-                    del self._store[s]
-                self.pages_in += len(paged_lanes)
-            if fresh_lanes:
-                self.slow.reset_lanes(fresh_lanes)
-                self.idle.reset_lanes(fresh_lanes)
-                self.goal_bank.reset_lanes(
-                    fresh_lanes,
-                    goal=[sessions[s].constraints.accuracy_goal or 0.0
-                          for s in fresh_sids])
-        if np.any(lanes < 0):
-            raise RuntimeError(
-                "page-in invariant violated: a requested session has no "
-                "lane after paging (lanes={})".format(lanes.tolist()))
-        self._last_used[lanes] = round_k
+                    "page-in invariant violated: a requested session has "
+                    "no lane after paging (lanes={})".format(
+                        lanes.tolist()))
+            self._last_used[lanes] = round_k
         return lanes
+
+    @property
+    def _store(self) -> "_SessionStoreView":
+        """The paged-out sessions as a read-only mapping: sid ->
+        ``{part: {name: [1]-array}}`` (``[1, W-1]`` for the goal ring),
+        read from the columns."""
+        return _SessionStoreView(self)
 
     # -------------------------------------------------------------- #
     # clock                                                           #
@@ -476,6 +536,13 @@ class SessionGateway:
         tick = self.tick if self.tick is not None else \
             (max(r.rel_deadline for r in requests) if n else 1.0)
         self._reset_lane_pool()
+        self._index_sessions(sess)
+        sidx = self._dense(out.sid)
+        known = sidx < len(self._sids)
+        known[known] = self._sids[sidx[known]] == out.sid[known]
+        if not known.all():
+            raise ValueError("a request names a session that is not "
+                             "among the offered sessions")
         queue = DeadlineBatcher(batch_size=self.n_lanes,
                                 min_feasible_latency=
                                 self.min_feasible_latency,
@@ -483,7 +550,7 @@ class SessionGateway:
                                 metrics=self._ob.metrics
                                 if self._ob else None)
         return _RunState(requests=requests, sess=sess, tick=float(tick),
-                         queue=queue, out=out)
+                         queue=queue, out=out, sidx=sidx)
 
     def run(self, sessions: Sequence[Session],
             requests: list[TrafficRequest] | None = None, *,
@@ -591,9 +658,8 @@ class SessionGateway:
                     # out to the host store (their Kalman/goal state
                     # survives the device), capacity shrinks to the
                     # survivors — the §5 churn protocol, no re-traces.
-                    ev = [int(ln) for ln in np.nonzero(newly_dead)[0]
-                          if self._resident[ln] >= 0]
-                    self._evict_lanes(ev)
+                    self._evict_lanes(
+                        np.nonzero(newly_dead & (self._resident >= 0))[0])
                     if ob:
                         lanes = [int(x) for x in np.nonzero(newly_dead)[0]]
                         ob.metrics.counter("quarantine_events",
@@ -666,7 +732,7 @@ class SessionGateway:
             req = queue.pop_one(now)
             if req is None:
                 break
-            lane = self._lane_of.get(req.sid, -1)
+            lane = self._lane_of[rs.sidx[req._row]]
             if req.sid in seen or \
                     (lane >= 0 and self._busy_until[lane] > now):
                 deferred.append(req)
@@ -699,15 +765,12 @@ class SessionGateway:
         n0 = next(q._counter)
         q._counter = itertools.count(n0)
         all_lanes = np.arange(self.n_lanes)
-        store_sids = np.asarray(sorted(self._store), dtype=np.int64)
-        store: dict = {"sids": store_sids}
-        if store_sids.size:
-            s0 = self._store[int(store_sids[0])]
-            for part in ("slow", "idle", "goal"):
-                for name in s0[part]:
-                    store[f"{part}.{name}"] = np.concatenate(
-                        [self._store[int(s)][part][name]
-                         for s in store_sids])
+        held = np.nonzero(self._stored)[0]
+        store: dict = {"sids": self._sids[held]}
+        if held.size:
+            for part, cols in self._cols.items():
+                for name, col in cols.items():
+                    store[f"{part}.{name}"] = col[held]
         tree = {
             "meta": {
                 "ri": np.int64(rs.ri),
@@ -787,8 +850,8 @@ class SessionGateway:
         self._last_used = ln["last_used"].astype(np.int64)
         self._busy_until = ln["busy_until"].astype(np.float64)
         self._dead = ln["dead"].astype(bool)
-        self._lane_of = {int(s): int(l)
-                         for l, s in enumerate(self._resident) if s >= 0}
+        on = np.nonzero(self._resident >= 0)[0]
+        self._lane_of[self._dense(self._resident[on])] = on
         all_lanes = np.arange(self.n_lanes)
         slow_state, idle_state = tree["slow"], tree["idle"]
         if self.mesh is not None:
@@ -803,16 +866,12 @@ class SessionGateway:
         self.slow.import_lanes(all_lanes, slow_state)
         self.idle.import_lanes(all_lanes, idle_state)
         self.goal_bank.import_lanes(all_lanes, tree["goal"])
-        self._store = {}
-        sids = tree["store"]["sids"].tolist()
-        for k, sid in enumerate(sids):
-            entry: dict = {"slow": {}, "idle": {}, "goal": {}}
-            for key, arr in tree["store"].items():
-                if key == "sids":
-                    continue
+        held = self._dense(tree["store"]["sids"])
+        self._stored[held] = True
+        for key, arr in tree["store"].items():
+            if key != "sids":
                 part, name = key.split(".", 1)
-                entry[part][name] = arr[k:k + 1]
-            self._store[int(sid)] = entry
+                self._cols[part][name][held] = arr
         for f in _CKPT_OUT_FIELDS:
             getattr(rs.out, f)[:] = tree["out"][f]
 
@@ -825,9 +884,7 @@ class SessionGateway:
         deliver through the shared tick kernel, absorb feedback.  Returns
         the round's last completion time."""
         ob = self._ob
-        with obs_span(ob, "page_in", "paging", round_k=round_k):
-            lanes = self._page_in([r.sid for r in batch], sess, round_k,
-                                  now)
+        lanes = self._page_in([r.sid for r in batch], sess, round_k, now)
         act = np.zeros(self.n_lanes, bool)
         dvec = np.ones(self.n_lanes)
         e_goal = np.zeros(self.n_lanes)
@@ -926,3 +983,29 @@ class SessionGateway:
             self._busy_until[lane] = now + float(d.latency[lane])
             last = max(last, now + float(d.latency[lane]))
         return last
+
+
+class _SessionStoreView(Mapping):
+    """Read-only view of a :class:`SessionGateway`'s paged-out sessions
+    in ascending sid order: each entry is ``{part: {name: array}}``, one
+    ``[1]`` slice of each state column (``[1, W-1]`` for the goal ring),
+    copied out of the columns."""
+
+    def __init__(self, gw: SessionGateway):
+        self._gw = gw
+
+    def __getitem__(self, sid) -> dict:
+        gw = self._gw
+        k = int(gw._dense(sid))
+        if k >= len(gw._sids) or gw._sids[k] != sid or not gw._stored[k]:
+            raise KeyError(sid)
+        return {part: {name: col[k:k + 1].copy()
+                       for name, col in cols.items()}
+                for part, cols in gw._cols.items()}
+
+    def __iter__(self) -> Iterator[int]:
+        gw = self._gw
+        return iter(gw._sids[gw._stored].tolist())
+
+    def __len__(self) -> int:
+        return int(self._gw._stored.sum())

@@ -77,7 +77,8 @@ from repro.serving.sim import deliver_step
 from repro.traffic.gateway import (REJECTED_BACKPRESSURE,
                                    REJECTED_INFEASIBLE, SERVED,
                                    GatewayResult, SessionGateway,
-                                   _obs_record_result, _resolve_obs)
+                                   _lru_pick, _obs_record_result,
+                                   _resolve_obs)
 from repro.traffic.workloads import Session, TrafficRequest, \
     generate_requests
 
@@ -216,10 +217,8 @@ class MegatickGateway:
         semantic no-op (export/import round-trips are bitwise lossless
         and every per-lane op is lane-independent), but WHICH sessions
         page — and therefore ``pages_in``/``pages_out`` — is still the
-        host loop's observable, so the LRU bookkeeping is reproduced
-        exactly, vectorized: free lanes in ascending order, then
-        evictions by (last_used, lane) via a stable argsort over
-        ascending lane indices (identical to the host's tuple sort),
+        host loop's observable, so the LRU bookkeeping is the host
+        loop's own pick (:func:`~repro.traffic.gateway._lru_pick`),
         assigned to missing batch positions in order.  Under the regime
         contract every lane is idle at every round boundary, so the
         host loop's idle mask is all-true here by construction.
@@ -229,14 +228,9 @@ class MegatickGateway:
         lanes = self._lane_arr[sids]
         miss = np.nonzero(lanes < 0)[0]
         if miss.size:
-            free = np.nonzero((self._resident < 0) & ~self._dead)[0]
-            n_evict = miss.size - free.size
-            if n_evict > 0:
-                mask = (self._resident >= 0) & ~self._dead
-                mask[mask] = ~np.isin(self._resident[mask], sids)
-                cand = np.nonzero(mask)[0]
-                order = np.argsort(self._last_used[cand], kind="stable")
-                ev = cand[order][:n_evict]
+            free, ev = _lru_pick(self._resident, self._last_used,
+                                 ~self._dead, sids, miss.size)
+            if ev.size:
                 olds = self._resident[ev]
                 self._stored_arr[olds] = True
                 self._lane_arr[olds] = -1

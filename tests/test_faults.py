@@ -264,6 +264,79 @@ class TestGatewayFaults:
                          checkpoint_dir=ck, faults=fs)
         assert_bitwise(ref, res)
 
+    def test_kill_resume_bitwise_under_heavy_paging(self, table,
+                                                    tmp_path):
+        """40 sessions over 4 lanes at a tick of a quarter deadline, so
+        lanes stay busy across rounds and nearly every round pages: a
+        run killed mid-horizon resumes from the checkpoint of its
+        session store's columns and ends bitwise equal to the
+        uninterrupted run, result and final state (banks, residents,
+        the store's every entry).  The checkpoint keeps the store's
+        on-disk keys."""
+        from repro.traffic import PoissonProcess, TenantSpec, build_sessions
+        from repro.serving.sim import MEMORY_ENV
+
+        deadline = float(deadline_range(table, 5)[3])
+        n_lanes, per_tenant = 4, 20
+        rate = 0.9 * (n_lanes / deadline) / (2 * per_tenant)
+        sessions = build_sessions([
+            TenantSpec("minE", Goal.MINIMIZE_ENERGY,
+                       Constraints(deadline=deadline, accuracy_goal=0.78),
+                       PoissonProcess(rate), n_sessions=per_tenant,
+                       phases=CPU_ENV),
+            TenantSpec("maxA", Goal.MAXIMIZE_ACCURACY,
+                       Constraints.from_power_budget(deadline, 170.0),
+                       PoissonProcess(rate), n_sessions=per_tenant,
+                       phases=MEMORY_ENV)], 40 * deadline, seed=5)
+
+        def gateway():
+            return SessionGateway(table, n_lanes, tick=deadline / 4,
+                                  max_queue=4 * n_lanes)
+
+        def final_state(gw):
+            lanes = np.arange(n_lanes)
+            return (gw._resident.copy(),
+                    {part: bank.export_lanes(lanes) for part, bank in
+                     (("slow", gw.slow), ("idle", gw.idle),
+                      ("goal", gw.goal_bank))},
+                    dict(gw._store.items()))
+
+        gw = gateway()
+        ref = gw.run(sessions, generate_requests(sessions))
+        want = final_state(gw)
+        assert ref.pages_in > 50 and ref.pages_out > 80
+        ck = str(tmp_path / "ck")
+        with pytest.raises(InjectedFailure):
+            gateway().run(sessions, generate_requests(sessions),
+                          checkpoint_dir=ck, checkpoint_every=5,
+                          kill_at_round=ref.n_rounds // 2)
+        tree, _ = ckpt_io.restore_tree(ck)
+        assert set(tree["store"]) == {
+            "sids", "slow.mu", "slow.sigma", "slow.gain",
+            "slow.process_noise", "slow.n_updates", "idle.phi",
+            "idle.variance", "idle.n_updates", "goal.goal", "goal.buf",
+            "goal.count", "goal.pos"}
+        sids = tree["store"]["sids"]
+        assert sids.size and np.all(np.diff(sids) > 0)
+        assert tree["store"]["goal.buf"].shape == (sids.size, 9)
+        gw2 = gateway()
+        res = gw2.resume(sessions, generate_requests(sessions),
+                         checkpoint_dir=ck)
+        assert_bitwise(ref, res)
+        got = final_state(gw2)
+        np.testing.assert_array_equal(got[0], want[0])
+        for part, fields in want[1].items():
+            for name, v in fields.items():
+                np.testing.assert_array_equal(got[1][part][name], v,
+                                              err_msg=f"{part}.{name}")
+        assert got[2].keys() == want[2].keys()
+        for sid, entry in want[2].items():
+            for part, fields in entry.items():
+                for name, v in fields.items():
+                    np.testing.assert_array_equal(
+                        got[2][sid][part][name], v,
+                        err_msg=f"sid {sid} {part}.{name}")
+
     def test_resume_rejects_different_workload(self, table, workload,
                                                tmp_path):
         sessions, n_lanes, deadline, _ = workload

@@ -366,6 +366,25 @@ class TestPureObserver:
         assert m.histogram("kalman_innovation",
                            gateway="host").count == int(res.served.sum())
 
+    def test_page_in_spans_count_the_run_pages(self, table):
+        """Each ``page_in`` span carries its round's paging: summed over
+        the run, ``n_in`` / ``n_out`` are the result's ``pages_in`` /
+        ``pages_out``, and ``n_fresh`` counts each served session once
+        (its first page-in)."""
+        sessions, n_lanes, deadline = gateway_config(table)
+        obs = FlightRecorder()
+        gw = SessionGateway(table, n_lanes, tick=deadline / 4,
+                            max_queue=4 * n_lanes, obs=obs)
+        res = gw.run(sessions, generate_requests(sessions))
+        spans = [e for e in obs.spans.events
+                 if e["name"] == "page_in" and e["ph"] == "X"]
+        assert len(spans) == res.n_rounds
+        assert res.pages_in > 0 and res.pages_out > 0
+        assert sum(e["args"]["n_in"] for e in spans) == res.pages_in
+        assert sum(e["args"]["n_out"] for e in spans) == res.pages_out
+        assert sum(e["args"]["n_fresh"] for e in spans) == \
+            np.unique(res.sid[res.served]).size
+
 
 # ------------------------------------------------------------------ #
 # sweep-level observation (satellite: uniform n_compiles + obs)       #

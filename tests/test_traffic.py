@@ -1,5 +1,6 @@
 """Traffic-subsystem tests: workload determinism, EDF batcher properties
-(hypothesis), bank paging round-trips, gateway-vs-FleetSim bitwise parity
+(hypothesis), bank paging round-trips, the columnar session store against
+the per-session paging it replaced, gateway-vs-FleetSim bitwise parity
 through session paging, admission control under overload, and the load
 sweep."""
 
@@ -564,6 +565,220 @@ class TestRoundLoopRegressions:
         gw._busy_until[:] = 1e9          # every lane mid-service
         with pytest.raises(RuntimeError, match="page-in"):
             gw._page_in([0, 1, 2], sessions, round_k=0, now=0.0)
+
+
+# ------------------------------------------------------------------ #
+# columnar session store vs the per-session paging it replaced        #
+# ------------------------------------------------------------------ #
+class _DictPager:
+    """The per-session LRU paging the columnar store replaced, kept as
+    the reference: a dict store of ``[1]`` slices keyed by sid and a
+    sid -> lane dict, over the banks and lane arrays of its own
+    gateway (whose own ``_page_in`` it never calls)."""
+
+    def __init__(self, gw):
+        self.gw = gw
+        self.lane_of: dict = {}
+        self.store: dict = {}
+        self.pages_in = self.pages_out = 0
+
+    def evict(self, ev_lanes):
+        gw = self.gw
+        if not len(ev_lanes):
+            return
+        slow_s = gw.slow.export_lanes(ev_lanes)
+        idle_s = gw.idle.export_lanes(ev_lanes)
+        goal_s = gw.goal_bank.export_lanes(ev_lanes)
+        for k, ln in enumerate(ev_lanes):
+            old = int(gw._resident[ln])
+            self.store[old] = {
+                "slow": {n: v[k:k + 1] for n, v in slow_s.items()},
+                "idle": {n: v[k:k + 1] for n, v in idle_s.items()},
+                "goal": {n: v[k:k + 1] for n, v in goal_s.items()},
+            }
+            del self.lane_of[old]
+            gw._resident[ln] = -1
+            self.pages_out += 1
+
+    def page_in(self, sids, sessions, round_k, now):
+        from repro.core.batched import goal_codes
+
+        gw = self.gw
+        needed = set(sids)
+        lanes = np.empty(len(sids), dtype=np.int64)
+        missing = []
+        for pos, sid in enumerate(sids):
+            lane = self.lane_of.get(sid, -1)
+            lanes[pos] = lane
+            if lane < 0:
+                missing.append(pos)
+        if missing:
+            idle = (gw._busy_until <= now) & ~gw._dead
+            free = [int(x) for x in
+                    np.nonzero((gw._resident < 0) & idle)[0]]
+            n_evict = len(missing) - len(free)
+            ev_lanes = []
+            if n_evict > 0:
+                evictable = sorted(
+                    (int(gw._last_used[ln]), ln)
+                    for ln in range(gw.n_lanes)
+                    if idle[ln] and gw._resident[ln] >= 0
+                    and int(gw._resident[ln]) not in needed)
+                ev_lanes = [ln for _, ln in evictable[:n_evict]]
+            if ev_lanes:
+                self.evict(ev_lanes)
+                free += ev_lanes
+            assert len(free) >= len(missing)
+            paged_lanes, paged_sids, fresh_lanes, fresh_sids = \
+                [], [], [], []
+            for pos, ln in zip(missing, free):
+                sid = sids[pos]
+                lanes[pos] = ln
+                gw._resident[ln] = sid
+                self.lane_of[sid] = ln
+                if sid in self.store:
+                    paged_lanes.append(ln)
+                    paged_sids.append(sid)
+                else:
+                    fresh_lanes.append(ln)
+                    fresh_sids.append(sid)
+                gw._goal_kinds[ln] = goal_codes([sessions[sid].goal])[0]
+            if paged_lanes:
+                def cat(part):
+                    return {n: np.concatenate([self.store[s][part][n]
+                                               for s in paged_sids])
+                            for n in self.store[paged_sids[0]][part]}
+                gw.slow.import_lanes(paged_lanes, cat("slow"))
+                gw.idle.import_lanes(paged_lanes, cat("idle"))
+                gw.goal_bank.import_lanes(paged_lanes, cat("goal"))
+                for s in paged_sids:
+                    del self.store[s]
+                self.pages_in += len(paged_lanes)
+            if fresh_lanes:
+                gw.slow.reset_lanes(fresh_lanes)
+                gw.idle.reset_lanes(fresh_lanes)
+                gw.goal_bank.reset_lanes(
+                    fresh_lanes,
+                    goal=[sessions[s].constraints.accuracy_goal or 0.0
+                          for s in fresh_sids])
+        gw._last_used[lanes] = round_k
+        return lanes
+
+
+def _bank_state(gw) -> dict:
+    """Every lane's full bank state, on the host by bank and field."""
+    lanes = np.arange(gw.n_lanes)
+    return {part: {n: np.asarray(v) for n, v in
+                   bank.export_lanes(lanes).items()}
+            for part, bank in (("slow", gw.slow), ("idle", gw.idle),
+                               ("goal", gw.goal_bank))}
+
+
+class TestColumnarPaging:
+    @pytest.mark.parametrize("mesh_size", [None, 1])
+    def test_page_in_matches_per_session_reference(self, table,
+                                                   mesh_size):
+        """50 random rounds of 96 sessions (sparse, shuffled sids) over
+        16 lanes, with busy lanes, dead lanes (residents quarantined
+        through ``_evict_lanes``) and batches that hold resident
+        sessions: the columnar gateway picks the same lanes, pages the
+        same sessions and ends every round with the same bank state, bit
+        for bit, as the per-session dict paging it replaced."""
+        from repro.launch.mesh import make_lane_mesh
+
+        mesh = None if mesh_size is None else make_lane_mesh(mesh_size)
+        n_lanes, n_sess = 16, 96
+        dl = float(deadline_range(table, 5)[3])
+        tr = _short_trace(ENVS["default"], 3, 4)
+        rng = np.random.default_rng(11)
+        sessions = {}
+        for k in rng.permutation(n_sess):
+            sid = int(7 * k + 3)
+            if k % 3:
+                goal, cons = Goal.MINIMIZE_ENERGY, Constraints(
+                    deadline=dl, accuracy_goal=0.6 + 0.003 * k)
+            else:
+                goal = Goal.MAXIMIZE_ACCURACY
+                cons = Constraints.from_power_budget(dl, 170.0)
+            sessions[sid] = Session(sid, "t", goal, cons, np.zeros(1), tr)
+        all_sids = np.asarray(sorted(sessions))
+        gw = SessionGateway(table, n_lanes, tick=1.0, mesh=mesh)
+        ref = _DictPager(SessionGateway(table, n_lanes, tick=1.0,
+                                        mesh=mesh))
+        rg = ref.gw
+        n_fresh = 0
+        for k in range(50):
+            now = float(k)
+            if k % 7 == 3:
+                dead_now = rng.random(n_lanes) < 0.15
+                newly = dead_now & ~gw._dead
+                gw._evict_lanes(np.nonzero(newly & (gw._resident >= 0))[0])
+                ref.evict([int(x) for x in np.nonzero(newly)[0]
+                           if rg._resident[x] >= 0])
+                gw._dead, rg._dead = dead_now.copy(), dead_now.copy()
+            idle = (gw._busy_until <= now) & ~gw._dead
+            held = gw._resident[(gw._resident >= 0) & ~idle]
+            on_idle = gw._resident[(gw._resident >= 0) & idle]
+            others = np.setdiff1d(all_sids, gw._resident)
+            n = int(rng.integers(1, int(idle.sum()) + 1))
+            mine = rng.choice(on_idle, int(rng.integers(
+                0, min(n, on_idle.size) + 1)), replace=False)
+            rest = rng.choice(others, n - mine.size, replace=False)
+            sids = [int(s) for s in rng.permutation(
+                np.concatenate([mine, rest]))]
+            assert not set(sids) & set(held.tolist())
+            n_fresh += sum(s not in ref.store and s not in ref.lane_of
+                           for s in sids)
+            got = gw._page_in(sids, sessions, k, now)
+            want = ref.page_in(sids, sessions, k, now)
+            np.testing.assert_array_equal(got, want, err_msg=f"round {k}")
+            # The round's feedback, the same on both, so that every
+            # session carries state of its own through the store.
+            act = np.zeros(n_lanes, bool)
+            act[got] = True
+            obs = [rng.uniform(0.5, 2.0, n_lanes) for _ in range(5)]
+            miss = rng.random(n_lanes) < 0.2
+            for g in (gw, rg):
+                observe_fleet(g.slow, g.idle, obs[0], obs[1],
+                              deadline_missed=miss, idle_power=obs[2],
+                              active_power=obs[3], mask=act)
+                g.goal_bank.record(obs[4], mask=act)
+            busy = now + rng.uniform(0.0, 3.0, got.size)
+            gw._busy_until[got] = busy
+            rg._busy_until[got] = busy
+            for name in ("_resident", "_goal_kinds", "_last_used"):
+                np.testing.assert_array_equal(getattr(gw, name),
+                                              getattr(rg, name),
+                                              err_msg=f"{name} round {k}")
+            assert (gw.pages_in, gw.pages_out) == \
+                (ref.pages_in, ref.pages_out), f"round {k}"
+            mine_s, ref_s = _bank_state(gw), _bank_state(rg)
+            for part in ref_s:
+                for name, v in ref_s[part].items():
+                    np.testing.assert_array_equal(
+                        mine_s[part][name], v,
+                        err_msg=f"{part}.{name} round {k}")
+            store = gw._store
+            assert set(store) == set(ref.store), f"round {k}"
+            assert len(store) == len(ref.store)
+            for sid, entry in ref.store.items():
+                mine_e = store[sid]
+                for part in entry:
+                    assert mine_e[part].keys() == entry[part].keys()
+                    for name, v in entry[part].items():
+                        assert mine_e[part][name].shape == v.shape
+                        np.testing.assert_array_equal(
+                            mine_e[part][name], v,
+                            err_msg=f"sid {sid} {part}.{name}")
+        assert ref.pages_in > 50 and ref.pages_out > 100 and n_fresh > 40, \
+            "the rounds must page in, out and fresh"
+        assert all(e["slow"]["mu"].shape == (1,) and
+                   e["goal"]["buf"].shape == (1, 9)
+                   for e in gw._store.values())
+        resident = int(gw._resident[gw._resident >= 0][0])
+        assert resident not in gw._store and 10 ** 6 not in gw._store
+        with pytest.raises(KeyError):
+            gw._store[resident]
 
 
 # ------------------------------------------------------------------ #
