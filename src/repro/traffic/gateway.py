@@ -61,50 +61,6 @@ REJECTED_INFEASIBLE = 1     # EDF fail-fast: slack below any feasible run
 REJECTED_BACKPRESSURE = 2   # bounded queue was full at arrival
 
 
-def _resolve_obs(obs):
-    """An attached-and-enabled flight recorder, else None.
-
-    ``obs=None`` and ``obs=FlightRecorder(enabled=False)`` both resolve
-    to None here, so every instrumentation site reduces to one pointer
-    check — the asserted ~zero-cost disabled mode of the pure-observer
-    contract (docs/OBSERVABILITY.md)."""
-    return obs if (obs is not None and getattr(obs, "enabled", False)) \
-        else None
-
-
-def _obs_record_result(metrics, out: "GatewayResult", *, gateway: str,
-                       policy: str) -> None:
-    """Fold one finished run's :class:`GatewayResult` into the registry:
-    disposition counters, paging totals, and the headline SLO/efficiency
-    gauges — shared by both gateways so the metric catalog is uniform
-    across the host loop and the megatick regimes."""
-    lab = dict(gateway=gateway, policy=policy)
-    metrics.counter("requests_offered", **lab).inc(out.offered)
-    metrics.counter("requests_served", **lab).inc(int(out.served.sum()))
-    metrics.counter("requests_rejected_infeasible", **lab).inc(
-        int((out.status == REJECTED_INFEASIBLE).sum()))
-    metrics.counter("requests_rejected_backpressure", **lab).inc(
-        int((out.status == REJECTED_BACKPRESSURE).sum()))
-    metrics.counter("requests_good", **lab).inc(int(out.good.sum()))
-    metrics.counter("deadline_misses", **lab).inc(
-        int(out.missed[out.served].sum()))
-    metrics.counter("energy_served_j", **lab).inc(
-        float(out.energy[out.served].sum()))
-    metrics.counter("rounds_served", **lab).inc(out.n_rounds)
-    metrics.counter("pages_in", **lab).inc(out.pages_in)
-    metrics.counter("pages_out", **lab).inc(out.pages_out)
-    metrics.gauge("slo_miss_rate", **lab).set(out.slo_miss_rate)
-    metrics.gauge("served_miss_rate", **lab).set(out.served_miss_rate)
-    metrics.gauge("reject_rate", **lab).set(out.reject_rate)
-    metrics.gauge("goodput_rps", **lab).set(out.goodput)
-    eg = out.energy_per_good
-    metrics.gauge("energy_per_good_j", **lab).set(
-        eg if np.isfinite(eg) else 0.0)
-    metrics.gauge("n_compiles_estimate", gateway=gateway).set(
-        out.n_compiles[0])
-    metrics.gauge("n_compiles_select", gateway=gateway).set(
-        out.n_compiles[1])
-
 def _lru_pick(resident: np.ndarray, last_used: np.ndarray,
               avail: np.ndarray, needed: np.ndarray,
               n_missing: int) -> tuple[np.ndarray, np.ndarray]:
@@ -133,16 +89,17 @@ _CKPT_OUT_FIELDS = ("status", "start", "latency", "sojourn", "missed",
 
 @dataclasses.dataclass
 class _RunState:
-    """Everything one :meth:`SessionGateway.run` mutates outside the
-    gateway's lane pool and banks — the resumable unit a checkpoint
-    captures (DESIGN.md §10)."""
+    """Everything one gateway run mutates outside the lane pool and
+    banks: the sorted requests, the queue, the result and the round
+    clock — the resumable unit a checkpoint captures (DESIGN.md §10)."""
 
     requests: list
     sess: dict
     tick: float
     queue: DeadlineBatcher
     out: "GatewayResult"
-    sidx: np.ndarray            # dense session index per request row
+    q_depth: object = None      # queue-depth histogram (a recorder on)
+    sidx: np.ndarray | None = None  # dense session index per request row
     ri: int = 0                 # next unsubmitted request index
     round_k: int = 0            # round clock
     n_rounds: int = 0           # rounds that served a batch
@@ -251,7 +208,343 @@ class GatewayResult:
                            scheme="gateway")
 
 
-class SessionGateway:
+class _GatewayCore:
+    """The host half both gateways share: a run's checks, request
+    intake, round clock, admission and device-loss quarantine, over the
+    lane pool's metadata (``_resident``, ``_last_used``, ``_dead`` per
+    lane; ``_lane_of``, ``_stored`` per dense session index).
+    :class:`SessionGateway` runs it round by round,
+    :class:`~repro.traffic.megatick.MegatickGateway` up front as its
+    planner."""
+
+    _label = "host"     # the `gateway=` label of the telemetry
+
+    def __init__(self, table: ProfileTable, n_lanes: int, *,
+                 phi_true: float, overhead: float, tick: float | None,
+                 max_queue: int | None,
+                 min_feasible_latency: float | None,
+                 accuracy_window: int, backend: str, mesh, obs):
+        self.table = table
+        # Optional flight recorder (repro.obs.FlightRecorder).  Strictly
+        # a pure observer: every pick, bank state, and golden trace is
+        # bitwise identical with or without it (tests/test_obs.py).  A
+        # disabled one leaves `_ob` None like no recorder, so every
+        # instrumentation site is one pointer check (the ~zero-cost
+        # disabled mode of docs/OBSERVABILITY.md).
+        self.obs = obs
+        self._ob = obs if getattr(obs, "enabled", False) else None
+        self.n_lanes = int(n_lanes)
+        self.phi_true = float(phi_true)
+        self.tick = tick
+        self.max_queue = max_queue
+        self.min_feasible_latency = float(table.latency.min()) \
+            if min_feasible_latency is None else float(min_feasible_latency)
+        self.accuracy_window = int(accuracy_window)
+        self.mesh = mesh
+        self.engine = BatchedAlertEngine(table, None, overhead=overhead,
+                                         backend=backend, mesh=mesh)
+        self._st = table.staircase_tensors()
+        groups = table.anytime_groups()
+        self._is_anytime = np.zeros(len(table.candidates), bool)
+        self._is_anytime[sorted({i for g in groups.values()
+                                 for i in g})] = True
+        self._stored = np.zeros(0, dtype=bool)
+        self._lane_of = np.zeros(0, dtype=np.int64)
+        self._reset_pool(0)
+
+    # -------------------------------------------------------------- #
+    # lane pool                                                       #
+    # -------------------------------------------------------------- #
+    def _reset_pool(self, n_sessions: int) -> None:
+        """An empty lane pool over ``n_sessions`` dense session indices:
+        no residents, no session stored or on a lane, no lane dead,
+        paging counters at zero."""
+        if self._stored.shape[0] != n_sessions:
+            self._stored = np.zeros(n_sessions, dtype=bool)
+            self._lane_of = np.zeros(n_sessions, dtype=np.int64)
+        self._resident = np.full(self.n_lanes, -1, dtype=np.int64)
+        self._stored[:] = False
+        self._lane_of[:] = -1
+        self._last_used = np.zeros(self.n_lanes, dtype=np.int64)
+        self._dead = np.zeros(self.n_lanes, dtype=bool)
+        self.pages_in = self.pages_out = 0
+
+    def _dense(self, keys) -> np.ndarray:
+        """Dense session indices of the session keys ``_resident``
+        holds: the keys themselves, unless a subclass keys lanes by sid."""
+        return keys
+
+    def _export(self, lanes: np.ndarray, dense: np.ndarray) -> None:
+        """Move the state of the sessions on ``lanes`` (dense indices
+        ``dense``) to the session store: nothing to move unless a
+        subclass keeps per-lane state."""
+
+    def _evict_lanes(self, ev_lanes) -> None:
+        """Page the residents of ``ev_lanes`` out to the session store
+        (:meth:`_export`) and free the lanes.  Shared by LRU eviction and
+        device-loss quarantine — a dead lane's session state survives
+        the device and can be re-admitted on a surviving lane
+        (DESIGN.md §10)."""
+        ev = np.asarray(ev_lanes, dtype=np.int64)
+        if not ev.size:
+            return
+        olds = self._dense(self._resident[ev])
+        self._export(ev, olds)
+        self._stored[olds] = True
+        self._lane_of[olds] = -1
+        self._resident[ev] = -1
+        self.pages_out += int(ev.size)
+
+    def _place(self, keys: np.ndarray, dense: np.ndarray,
+               idle: np.ndarray, round_k: int):
+        """Put the distinct sessions ``keys`` (dense indices ``dense``)
+        on lanes, aligned with them: residents keep theirs, the others
+        take the free ``idle`` lanes in ascending order, then those of
+        the least-recently-used ``idle`` residents not among ``keys``,
+        evicted (:func:`_lru_pick`).  Returns the lanes, the positions
+        that were off-lane, and which of those come back from the store
+        (counted in ``pages_in``)."""
+        lanes = self._lane_of[dense]
+        miss = np.nonzero(lanes < 0)[0]
+        paged = np.zeros(0, dtype=bool)
+        if miss.size:
+            free, ev = _lru_pick(self._resident, self._last_used, idle,
+                                 keys, miss.size)
+            if ev.size:
+                self._evict_lanes(ev)
+                free = np.concatenate([free, ev])
+            if free.size < miss.size:
+                # Eviction could not produce enough idle lanes (every
+                # other resident is busy or needed this round).  A
+                # silent truncation here would leave a lane of -1 and
+                # corrupt the last lane downstream, so fail loudly.
+                raise RuntimeError(
+                    f"page-in underflow: {miss.size} non-resident "
+                    f"session(s) need lanes but only {free.size} "
+                    "lane(s) are free or evictable (the rest are busy"
+                    " or needed this round)")
+            take = free[:miss.size]
+            inc = dense[miss]
+            lanes[miss] = take
+            self._resident[take] = keys[miss]
+            self._lane_of[inc] = take
+            paged = self._stored[inc]
+            self._stored[inc] = False
+            self.pages_in += int(paged.sum())
+        self._last_used[lanes] = round_k
+        return lanes, miss, paged
+
+    def _quarantine(self, now: float, faults) -> None:
+        """The fault schedule's device loss at round instant ``now``:
+        newly dead lanes page their residents out (their Kalman/goal
+        state survives the device) and leave the pool until restored —
+        the §5 churn protocol, no re-traces."""
+        dead_now = faults.dead_at(now)
+        newly_dead = dead_now & ~self._dead
+        if newly_dead.any():
+            self._evict_lanes(
+                np.nonzero(newly_dead & (self._resident >= 0))[0])
+            ob = self._ob
+            if ob:
+                lanes = [int(x) for x in np.nonzero(newly_dead)[0]]
+                ob.metrics.counter("quarantine_events",
+                                   gateway=self._label).inc()
+                ob.metrics.counter("lanes_quarantined",
+                                   gateway=self._label).inc(len(lanes))
+                ob.spans.event("quarantine", cat="fault", lanes=lanes,
+                               now_s=float(now))
+        self._dead = dead_now
+
+    # -------------------------------------------------------------- #
+    # intake and clock                                                #
+    # -------------------------------------------------------------- #
+    def _check_run(self, policy: str, static_config, faults) -> None:
+        """Refuse a run's arguments before any state is touched."""
+        if policy not in ("alert", "static"):
+            raise ValueError(policy)
+        if policy == "static" and static_config is None:
+            raise ValueError("policy='static' needs static_config=(i, j)")
+        if faults is not None and faults.n_lanes != self.n_lanes:
+            raise ValueError(
+                f"FaultSchedule covers {faults.n_lanes} lanes but the "
+                f"gateway has {self.n_lanes}")
+
+    def _offer(self, sessions: Sequence[Session],
+               requests: list[TrafficRequest] | None) -> _RunState:
+        """One run's intake: the requests in arrival order, each paired
+        with its result row, the result shell (every request shed until
+        decided), the tick and an empty queue."""
+        sess = {s.sid: s for s in sessions}
+        if requests is None:
+            requests = generate_requests(sessions)
+        # The event loop needs arrival order; caller-supplied lists may
+        # be merged/unsorted, so sort defensively (stable — equal keys
+        # keep their input order) and index results by sorted row.
+        requests = sorted(
+            requests,
+            key=lambda r: (r.arrival,
+                           0 if r.req_id is None else r.req_id))
+        # Pair every request with its sorted result row directly
+        # (enumerate after the sort).  Keying rows on object identity
+        # would collapse two occurrences of the same object into one
+        # row, so true duplicates are rejected up front instead.
+        if len({id(r) for r in requests}) != len(requests):
+            raise ValueError(
+                "the same TrafficRequest object was offered more than "
+                "once; every offered request must be a distinct object")
+        for k, r in enumerate(requests):
+            r._row = k
+        n = len(requests)
+        out = GatewayResult(
+            sid=np.asarray([r.sid for r in requests], dtype=np.int64),
+            index=np.asarray([r.index for r in requests], dtype=np.int64),
+            arrival=np.asarray([r.arrival for r in requests]),
+            status=np.full(n, REJECTED_BACKPRESSURE, dtype=np.int64),
+            start=np.zeros(n), latency=np.zeros(n), sojourn=np.zeros(n),
+            missed=np.zeros(n, bool), accuracy=np.zeros(n),
+            energy=np.zeros(n), model_index=np.zeros(n, dtype=np.int64),
+            power_index=np.zeros(n, dtype=np.int64))
+        tick = self.tick if self.tick is not None else \
+            (max(r.rel_deadline for r in requests) if n else 1.0)
+        ob = self._ob
+        queue = DeadlineBatcher(batch_size=self.n_lanes,
+                                min_feasible_latency=
+                                self.min_feasible_latency,
+                                max_queue=self.max_queue,
+                                metrics=ob.metrics if ob else None)
+        q_depth = ob.metrics.histogram("queue_depth",
+                                       gateway=self._label) if ob else None
+        return _RunState(requests=requests, sess=sess, tick=float(tick),
+                         queue=queue, out=out, q_depth=q_depth)
+
+    @staticmethod
+    def _round_of(arrival: float, tick: float) -> int:
+        """Smallest round k with ``k * tick >= arrival`` (float-safe:
+        a request arriving exactly on a round boundary is served in that
+        round, which is what makes zero queueing delay *exactly* zero)."""
+        k = max(int(np.ceil(arrival / tick)), 0)
+        while k * tick < arrival:
+            k += 1
+        while k > 0 and (k - 1) * tick >= arrival:
+            k -= 1
+        return k
+
+    def _next_round(self, rs: _RunState) -> float:
+        """The instant of round ``rs.round_k``, skipped ahead to the next
+        arrival's round when the queue is empty."""
+        if not len(rs.queue):
+            rs.round_k = max(rs.round_k, self._round_of(
+                rs.requests[rs.ri].arrival, rs.tick))
+        return rs.round_k * rs.tick
+
+    # -------------------------------------------------------------- #
+    # admission                                                       #
+    # -------------------------------------------------------------- #
+    def _admit(self, rs: _RunState, now: float,
+               busy_until: np.ndarray | None = None):
+        """One round's admission: submit the arrivals due by ``now``
+        (backpressure at submit), then pop the EDF queue onto the live
+        lanes, at most one request per session, failing fast what can no
+        longer meet its deadline.  ``busy_until`` (per-lane completion
+        instants) also keeps busy lanes out of the round and defers a
+        session mid-service on one; ``None`` says no lane is busy (the
+        megatick's regime contract), decided once for the round.
+        Returns the round's batch and the requests popped and put back
+        (deferred)."""
+        requests, queue, out = rs.requests, rs.queue, rs.out
+        n = len(requests)
+        ri = rs.ri
+        while ri < n and requests[ri].arrival <= now:
+            req = requests[ri]
+            if not queue.submit(req):
+                out.status[req._row] = REJECTED_BACKPRESSURE
+            ri += 1
+        rs.ri = ri
+        if rs.q_depth is not None:
+            rs.q_depth.observe(len(queue))
+        # A session is sequential: whether queued behind itself or
+        # mid-service on a busy lane, its later requests wait.  The scan
+        # is bounded: a run of blocked same-session requests longer than
+        # the deferral budget waits for the next round instead of
+        # churning the whole backlog through the heap every round.
+        n_rej = len(queue.rejected)
+        idle = ~self._dead if busy_until is None else \
+            (busy_until <= now) & ~self._dead
+        avail = int(idle.sum())
+        batch: list[TrafficRequest] = []
+        seen: set[int] = set()
+        deferred: list[TrafficRequest] = []
+        defer_budget = 4 * self.n_lanes
+        while len(batch) < avail and len(deferred) <= defer_budget:
+            req = queue.pop_one(now)
+            if req is None:
+                break
+            if req.sid in seen:
+                deferred.append(req)
+                continue
+            if busy_until is not None:
+                lane = self._lane_of[rs.sidx[req._row]]
+                if lane >= 0 and busy_until[lane] > now:
+                    deferred.append(req)
+                    continue
+            seen.add(req.sid)
+            batch.append(req)
+        for req in deferred:
+            # Deferral is not a new arrival: requeue() bypasses
+            # max_queue backpressure (the request was already admitted)
+            # and restores the original heap seq so the EDF
+            # submission-order tie-break survives deferral.
+            queue.requeue(req)
+        for req in queue.rejected[n_rej:]:   # failed fast this round
+            out.status[req._row] = REJECTED_INFEASIBLE
+            out.start[req._row] = now
+        return batch, deferred
+
+    def _finish(self, out: GatewayResult, last_completion: float,
+                n_rounds: int, n_compiles: tuple,
+                policy: str) -> GatewayResult:
+        """Close a run's result (horizon, round, paging and compile
+        counts) and fold it into an attached recorder's registry:
+        disposition counters, paging totals and the headline
+        SLO/efficiency gauges, one metric catalog for both gateways."""
+        out.horizon = max(last_completion,
+                          float(out.arrival[-1]) if out.offered else 0.0)
+        out.n_rounds = n_rounds
+        out.pages_in, out.pages_out = self.pages_in, self.pages_out
+        out.n_compiles = n_compiles
+        if not self._ob:
+            return out
+        metrics = self._ob.metrics
+        lab = dict(gateway=self._label, policy=policy)
+        metrics.counter("requests_offered", **lab).inc(out.offered)
+        metrics.counter("requests_served", **lab).inc(int(out.served.sum()))
+        metrics.counter("requests_rejected_infeasible", **lab).inc(
+            int((out.status == REJECTED_INFEASIBLE).sum()))
+        metrics.counter("requests_rejected_backpressure", **lab).inc(
+            int((out.status == REJECTED_BACKPRESSURE).sum()))
+        metrics.counter("requests_good", **lab).inc(int(out.good.sum()))
+        metrics.counter("deadline_misses", **lab).inc(
+            int(out.missed[out.served].sum()))
+        metrics.counter("energy_served_j", **lab).inc(
+            float(out.energy[out.served].sum()))
+        metrics.counter("rounds_served", **lab).inc(out.n_rounds)
+        metrics.counter("pages_in", **lab).inc(out.pages_in)
+        metrics.counter("pages_out", **lab).inc(out.pages_out)
+        metrics.gauge("slo_miss_rate", **lab).set(out.slo_miss_rate)
+        metrics.gauge("served_miss_rate", **lab).set(out.served_miss_rate)
+        metrics.gauge("reject_rate", **lab).set(out.reject_rate)
+        metrics.gauge("goodput_rps", **lab).set(out.goodput)
+        eg = out.energy_per_good
+        metrics.gauge("energy_per_good_j", **lab).set(
+            eg if np.isfinite(eg) else 0.0)
+        metrics.gauge("n_compiles_estimate", gateway=self._label).set(
+            out.n_compiles[0])
+        metrics.gauge("n_compiles_select", gateway=self._label).set(
+            out.n_compiles[1])
+        return out
+
+
+class SessionGateway(_GatewayCore):
     """Open-loop traffic over one fixed-size batched scoring engine.
 
     The engine, filter banks, goal bank, and lane pool are built once at
@@ -273,41 +566,23 @@ class SessionGateway:
                  min_feasible_latency: float | None = None,
                  accuracy_window: int = 10, backend: str = "xla",
                  mesh=None, obs=None):
-        self.table = table
-        # Optional flight recorder (repro.obs.FlightRecorder).  Strictly
-        # a pure observer: every pick, bank state, and golden trace is
-        # bitwise identical with or without it (tests/test_obs.py).
-        self.obs = obs
-        self._ob = _resolve_obs(obs)
-        self.n_lanes = int(n_lanes)
-        self.phi_true = float(phi_true)
-        self.tick = tick
-        self.max_queue = max_queue
-        self.min_feasible_latency = float(table.latency.min()) \
-            if min_feasible_latency is None else float(min_feasible_latency)
-        self.accuracy_window = int(accuracy_window)
-        self.mesh = mesh
-        self.engine = BatchedAlertEngine(table, None, overhead=overhead,
-                                         backend=backend, mesh=mesh)
+        super().__init__(table, n_lanes, phi_true=phi_true,
+                         overhead=overhead, tick=tick, max_queue=max_queue,
+                         min_feasible_latency=min_feasible_latency,
+                         accuracy_window=accuracy_window, backend=backend,
+                         mesh=mesh, obs=obs)
         self.slow = SlowdownFilterBank(self.n_lanes, mesh=mesh)
         self.idle = IdlePowerFilterBank(self.n_lanes, mesh=mesh)
         # The goal window stays host-resident even under a mesh (bitwise
         # window sums, mirroring FleetSim.run_streams).
         self.goal_bank = WindowedGoalBank(
             np.zeros(self.n_lanes), self.n_lanes, accuracy_window)
-        self._st = table.staircase_tensors()
-        groups = table.anytime_groups()
-        self._is_anytime = np.zeros(len(table.candidates), bool)
-        self._is_anytime[sorted({i for g in groups.values()
-                                 for i in g})] = True
         # The session store: columns over a dense session index, built
         # per run by _index_sessions (empty until the first run).
         self._banks = {"slow": self.slow, "idle": self.idle,
                        "goal": self.goal_bank}
         self._sess = None
         self._sids = np.zeros(0, dtype=np.int64)
-        self._stored = np.zeros(0, dtype=bool)
-        self._lane_of = np.zeros(0, dtype=np.int64)
         self._cols: dict[str, dict[str, np.ndarray]] = {}
         self._reset_lane_pool()
 
@@ -318,14 +593,9 @@ class SessionGateway:
         """Fresh lane pool + empty session store (between runs).  The
         ``[S]`` shapes are untouched, so the engine's jit cache
         survives."""
-        self._resident = np.full(self.n_lanes, -1, dtype=np.int64)
-        self._stored[:] = False
-        self._lane_of[:] = -1
+        self._reset_pool(self._stored.shape[0])
         self._goal_kinds = np.zeros(self.n_lanes, dtype=np.int64)
-        self._last_used = np.zeros(self.n_lanes, dtype=np.int64)
         self._busy_until = np.zeros(self.n_lanes)
-        self._dead = np.zeros(self.n_lanes, dtype=bool)
-        self.pages_in = self.pages_out = 0
         all_lanes = np.arange(self.n_lanes)
         self.slow.reset_lanes(all_lanes)
         self.idle.reset_lanes(all_lanes)
@@ -359,33 +629,20 @@ class SessionGateway:
                 part: {name: np.zeros((n,) + v.shape[1:], v.dtype)
                        for name, v in bank.export_lanes(none).items()}
                 for part, bank in self._banks.items()}
-            self._stored = np.zeros(n, dtype=bool)
-            self._lane_of = np.full(n, -1, dtype=np.int64)
+            self._reset_pool(n)
         self._sess = sess
 
     def _dense(self, sids) -> np.ndarray:
         """Dense session indices of raw ``sids`` (all indexed)."""
         return np.searchsorted(self._sids, sids)
 
-    def _evict_lanes(self, ev_lanes) -> None:
-        """Page the residents of ``ev_lanes`` out to the session store
-        (one batched ``export_lanes`` per bank, scattered into the
-        columns) and free the lanes.  Shared by LRU eviction and
-        device-loss quarantine — a dead lane's session state survives
-        the device and can be re-admitted on a surviving lane
-        (DESIGN.md §10)."""
-        ev = np.asarray(ev_lanes, dtype=np.int64)
-        if not ev.size:
-            return
-        olds = self._dense(self._resident[ev])
+    def _export(self, lanes: np.ndarray, dense: np.ndarray) -> None:
+        """One batched ``export_lanes`` per bank, scattered into the
+        session store's columns at ``dense``."""
         for part, bank in self._banks.items():
             cols = self._cols[part]
-            for name, v in bank.export_lanes(ev).items():
-                cols[name][olds] = v
-        self._stored[olds] = True
-        self._lane_of[olds] = -1
-        self._resident[ev] = -1
-        self.pages_out += int(ev.size)
+            for name, v in bank.export_lanes(lanes).items():
+                cols[name][dense] = v
 
     def _page_in(self, sids: Sequence[int],
                  sessions: dict[int, Session], round_k: int,
@@ -396,7 +653,7 @@ class SessionGateway:
         Non-residents land in free idle lanes first, then evict the
         least-recently-used *idle* residents not needed this round (a
         busy lane's session is mid-service and cannot move;
-        :func:`_lru_pick`).  The evictees' filter + goal-window state goes
+        :meth:`_place`).  The evictees' filter + goal-window state goes
         to the session store (one ``export_lanes`` per bank) and the
         incomers' state is restored (one ``import_lanes`` per bank for
         paged sessions, one ``reset_lanes`` for first-time sessions) —
@@ -410,58 +667,32 @@ class SessionGateway:
                       round_k=round_k) as sp:
             sids = np.asarray(sids, dtype=np.int64)
             idx = self._dense(sids)
-            lanes = self._lane_of[idx]
-            miss = np.nonzero(lanes < 0)[0]
-            n_in = n_out = 0
-            if miss.size:
-                free, ev = _lru_pick(
-                    self._resident, self._last_used,
-                    (self._busy_until <= now) & ~self._dead, sids,
-                    miss.size)
-                if ev.size:
-                    self._evict_lanes(ev)
-                    n_out = int(ev.size)
-                    free = np.concatenate([free, ev])
-                if free.size < miss.size:
-                    # Eviction could not produce enough idle lanes (every
-                    # other resident is busy or needed this round).  A
-                    # silent truncation here would leave a lane of -1
-                    # and corrupt the last lane downstream, so fail
-                    # loudly instead.
-                    raise RuntimeError(
-                        f"page-in underflow: {miss.size} non-resident "
-                        f"session(s) need lanes but only {free.size} "
-                        "lane(s) are free or evictable (the rest are busy"
-                        " or needed this round)")
-                take = free[:miss.size]
-                inc = idx[miss]
-                lanes[miss] = take
-                self._resident[take] = sids[miss]
-                self._lane_of[inc] = take
-                self._goal_kinds[take] = self._sess_goal_kind[inc]
-                paged = self._stored[inc]
-                if paged.any():
-                    p_lanes, p_idx = take[paged], inc[paged]
-                    for part, bank in self._banks.items():
-                        bank.import_lanes(p_lanes, {
-                            name: col[p_idx]
-                            for name, col in self._cols[part].items()})
-                    self._stored[p_idx] = False
-                    n_in = int(p_lanes.size)
-                    self.pages_in += n_in
-                if n_in < miss.size:
-                    f_lanes, f_idx = take[~paged], inc[~paged]
-                    self.slow.reset_lanes(f_lanes)
-                    self.idle.reset_lanes(f_lanes)
-                    self.goal_bank.reset_lanes(
-                        f_lanes, goal=self._sess_accuracy_goal[f_idx])
-            sp.set(n_in=n_in, n_fresh=int(miss.size) - n_in, n_out=n_out)
+            n_out = self.pages_out
+            lanes, miss, paged = self._place(
+                sids, idx, (self._busy_until <= now) & ~self._dead,
+                round_k)
+            take, inc = lanes[miss], idx[miss]
+            self._goal_kinds[take] = self._sess_goal_kind[inc]
+            n_in = int(paged.sum())
+            if n_in:
+                p_lanes, p_idx = take[paged], inc[paged]
+                for part, bank in self._banks.items():
+                    bank.import_lanes(p_lanes, {
+                        name: col[p_idx]
+                        for name, col in self._cols[part].items()})
+            if n_in < miss.size:
+                f_lanes, f_idx = take[~paged], inc[~paged]
+                self.slow.reset_lanes(f_lanes)
+                self.idle.reset_lanes(f_lanes)
+                self.goal_bank.reset_lanes(
+                    f_lanes, goal=self._sess_accuracy_goal[f_idx])
+            sp.set(n_in=n_in, n_fresh=int(miss.size) - n_in,
+                   n_out=self.pages_out - n_out)
             if np.any(lanes < 0):
                 raise RuntimeError(
                     "page-in invariant violated: a requested session has "
                     "no lane after paging (lanes={})".format(
                         lanes.tolist()))
-            self._last_used[lanes] = round_k
         return lanes
 
     @property
@@ -472,21 +703,6 @@ class SessionGateway:
         return _SessionStoreView(self)
 
     # -------------------------------------------------------------- #
-    # clock                                                           #
-    # -------------------------------------------------------------- #
-    @staticmethod
-    def _round_of(arrival: float, tick: float) -> int:
-        """Smallest round k with ``k * tick >= arrival`` (float-safe:
-        a request arriving exactly on a round boundary is served in that
-        round, which is what makes zero queueing delay *exactly* zero)."""
-        k = max(int(np.ceil(arrival / tick)), 0)
-        while k * tick < arrival:
-            k += 1
-        while k > 0 and (k - 1) * tick >= arrival:
-            k -= 1
-        return k
-
-    # -------------------------------------------------------------- #
     # the event loop                                                  #
     # -------------------------------------------------------------- #
     def _init_run(self, sessions: Sequence[Session],
@@ -495,62 +711,18 @@ class SessionGateway:
         """Validate one run's inputs and build its fresh, resumable
         loop state (requests sorted + row-assigned, result shell, round
         clock, empty queue, reset lane pool)."""
-        if policy not in ("alert", "static"):
-            raise ValueError(policy)
-        if policy == "static" and static_config is None:
-            raise ValueError("policy='static' needs static_config=(i, j)")
-        if faults is not None and faults.n_lanes != self.n_lanes:
-            raise ValueError(
-                f"FaultSchedule covers {faults.n_lanes} lanes but the "
-                f"gateway has {self.n_lanes}")
-        sess = {s.sid: s for s in sessions}
-        if requests is None:
-            requests = generate_requests(sessions)
-        # The event loop needs arrival order; caller-supplied lists may
-        # be merged/unsorted, so sort defensively (stable — equal keys
-        # keep their input order) and index results by sorted row.
-        requests = sorted(
-            requests,
-            key=lambda r: (r.arrival,
-                           0 if r.req_id is None else r.req_id))
-        # Pair every request with its sorted result row directly
-        # (enumerate after the sort).  Keying rows on object identity
-        # would collapse two occurrences of the same object into one
-        # row, so true duplicates are rejected up front instead.
-        if len({id(r) for r in requests}) != len(requests):
-            raise ValueError(
-                "the same TrafficRequest object was offered more than "
-                "once; every offered request must be a distinct object")
-        for k, r in enumerate(requests):
-            r._row = k
-        n = len(requests)
-        out = GatewayResult(
-            sid=np.asarray([r.sid for r in requests], dtype=np.int64),
-            index=np.asarray([r.index for r in requests], dtype=np.int64),
-            arrival=np.asarray([r.arrival for r in requests]),
-            status=np.full(n, REJECTED_BACKPRESSURE, dtype=np.int64),
-            start=np.zeros(n), latency=np.zeros(n), sojourn=np.zeros(n),
-            missed=np.zeros(n, bool), accuracy=np.zeros(n),
-            energy=np.zeros(n), model_index=np.zeros(n, dtype=np.int64),
-            power_index=np.zeros(n, dtype=np.int64))
-        tick = self.tick if self.tick is not None else \
-            (max(r.rel_deadline for r in requests) if n else 1.0)
+        self._check_run(policy, static_config, faults)
+        rs = self._offer(sessions, requests)
         self._reset_lane_pool()
-        self._index_sessions(sess)
-        sidx = self._dense(out.sid)
+        self._index_sessions(rs.sess)
+        sids = rs.out.sid
+        rs.sidx = sidx = self._dense(sids)
         known = sidx < len(self._sids)
-        known[known] = self._sids[sidx[known]] == out.sid[known]
+        known[known] = self._sids[sidx[known]] == sids[known]
         if not known.all():
             raise ValueError("a request names a session that is not "
                              "among the offered sessions")
-        queue = DeadlineBatcher(batch_size=self.n_lanes,
-                                min_feasible_latency=
-                                self.min_feasible_latency,
-                                max_queue=self.max_queue,
-                                metrics=self._ob.metrics
-                                if self._ob else None)
-        return _RunState(requests=requests, sess=sess, tick=float(tick),
-                         queue=queue, out=out, sidx=sidx)
+        return rs
 
     def run(self, sessions: Sequence[Session],
             requests: list[TrafficRequest] | None = None, *,
@@ -630,48 +802,24 @@ class SessionGateway:
         """The round loop, resumable at any iteration boundary: every
         mutation lives in ``rs`` / the lane pool / the banks, all of
         which the checkpoint captures."""
-        requests, sess, tick, queue, out = \
-            rs.requests, rs.sess, rs.tick, rs.queue, rs.out
-        n = len(requests)
+        sess, out = rs.sess, rs.out
+        n = len(rs.requests)
         lanes_arange = np.arange(self.n_lanes)
         ob = self._ob
-        q_depth = ob.metrics.histogram("queue_depth", gateway="host") \
-            if ob else None
-        while rs.ri < n or len(queue):
+        while rs.ri < n or len(rs.queue):
             if kill_at_round is not None and rs.iters == kill_at_round:
                 raise InjectedFailure(
                     f"injected kill at gateway iteration {rs.iters}")
-            if not len(queue):
-                rs.round_k = max(
-                    rs.round_k,
-                    self._round_of(requests[rs.ri].arrival, tick))
-            now = rs.round_k * tick
+            now = self._next_round(rs)
             # --- fault schedule at the round instant: pure numpy f64,
-            # shared verbatim with the megatick planner so both paths
-            # see bit-identical perturbations ---
+            # read at the same instants by the megatick planner so both
+            # paths see bit-identical perturbations ---
             fmul = None
             if faults is not None:
-                dead_now = faults.dead_at(now)
-                newly_dead = dead_now & ~self._dead
-                if newly_dead.any():
-                    # Device loss quarantines its lanes: residents page
-                    # out to the host store (their Kalman/goal state
-                    # survives the device), capacity shrinks to the
-                    # survivors — the §5 churn protocol, no re-traces.
-                    self._evict_lanes(
-                        np.nonzero(newly_dead & (self._resident >= 0))[0])
-                    if ob:
-                        lanes = [int(x) for x in np.nonzero(newly_dead)[0]]
-                        ob.metrics.counter("quarantine_events",
-                                           gateway="host").inc()
-                        ob.metrics.counter("lanes_quarantined",
-                                           gateway="host").inc(len(lanes))
-                        ob.spans.event("quarantine", cat="fault",
-                                       lanes=lanes, now_s=float(now))
-                self._dead = dead_now
+                self._quarantine(now, faults)
                 fmul = faults.slow_at(now)
             with obs_span(ob, "admit", "gateway", round_k=rs.round_k):
-                batch, deferred = self._admit(rs, now, q_depth)
+                batch, deferred = self._admit(rs, now, self._busy_until)
             obs_count(ob, "pops", len(batch) + len(deferred),
                       gateway="host")
             obs_count(ob, "deferred", len(deferred), gateway="host")
@@ -692,63 +840,8 @@ class SessionGateway:
                 with obs_span(ob, "checkpoint_write", "checkpoint",
                               iters=rs.iters):
                     self._save_checkpoint(rs, checkpoint_dir)
-        out.horizon = max(rs.last_completion,
-                          float(out.arrival[-1]) if n else 0.0)
-        out.n_rounds = rs.n_rounds
-        out.pages_in, out.pages_out = self.pages_in, self.pages_out
-        out.n_compiles = self.engine.n_compiles()
-        if ob:
-            _obs_record_result(ob.metrics, out, gateway="host",
-                               policy=policy)
-        return out
-
-    def _admit(self, rs: "_RunState", now: float, q_depth):
-        """One round's admission: submit the arrivals due by ``now``
-        (backpressure at submit), then pop the EDF queue onto the lanes
-        free this round, at most one request per session, failing fast
-        what can no longer meet its deadline.  Returns the round's batch
-        and the requests popped and put back (deferred)."""
-        requests, queue, out = rs.requests, rs.queue, rs.out
-        n = len(requests)
-        while rs.ri < n and requests[rs.ri].arrival <= now:
-            req = requests[rs.ri]
-            if not queue.submit(req):
-                out.status[req._row] = REJECTED_BACKPRESSURE
-            rs.ri += 1
-        if q_depth is not None:
-            q_depth.observe(len(queue))
-        # A session is sequential: whether queued behind itself or
-        # mid-service on a busy lane, its later requests wait.  The scan
-        # is bounded: a run of blocked same-session requests longer than
-        # the deferral budget waits for the next round instead of
-        # churning the whole backlog through the heap every round.
-        n_rej = len(queue.rejected)
-        avail = int(((self._busy_until <= now) & ~self._dead).sum())
-        batch: list[TrafficRequest] = []
-        seen: set[int] = set()
-        deferred: list[TrafficRequest] = []
-        defer_budget = 4 * self.n_lanes
-        while len(batch) < avail and len(deferred) <= defer_budget:
-            req = queue.pop_one(now)
-            if req is None:
-                break
-            lane = self._lane_of[rs.sidx[req._row]]
-            if req.sid in seen or \
-                    (lane >= 0 and self._busy_until[lane] > now):
-                deferred.append(req)
-                continue
-            seen.add(req.sid)
-            batch.append(req)
-        for req in deferred:
-            # Deferral is not a new arrival: requeue() bypasses
-            # max_queue backpressure (the request was already admitted)
-            # and restores the original heap seq so the EDF
-            # submission-order tie-break survives deferral.
-            queue.requeue(req)
-        for req in queue.rejected[n_rej:]:   # failed fast this round
-            out.status[req._row] = REJECTED_INFEASIBLE
-            out.start[req._row] = now
-        return batch, deferred
+        return self._finish(out, rs.last_completion, rs.n_rounds,
+                            self.engine.n_compiles(), policy)
 
     # -------------------------------------------------------------- #
     # checkpoint / resume                                             #

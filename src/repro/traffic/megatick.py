@@ -1,10 +1,8 @@
 """Device-resident gateway megatick: the round clock as ONE jitted scan.
 
-:class:`~repro.traffic.gateway.SessionGateway` runs its round clock as a
-host Python loop — one engine dispatch, one delivery call, one feedback
-call, and one LRU paging pass *per round*.  That loop is the scalability
-wall (ROADMAP open item 1): at 10^5-10^6 sessions the host is in the
-inner loop of every round.  :class:`MegatickGateway` serves the same
+The host loop of :class:`~repro.traffic.gateway.SessionGateway` makes
+one engine dispatch, one delivery call, one feedback call, and one
+paging pass *per round*.  :class:`MegatickGateway` serves the same
 workload with the whole inner round clock — effective-deadline math
 (``T_goal - queueing delay``), the masked select, the shared delivery
 kernel, and the Eq. 6/8 + goal-window feedback — inside ONE jitted
@@ -12,22 +10,18 @@ kernel, and the Eq. 6/8 + goal-window feedback — inside ONE jitted
 with every state buffer donated: a full load sweep never gathers state
 and never re-traces.
 
-**Regime contract.**  The host loop's only genuinely data-dependent
-control flow is admission: which requests are submitted, failed fast,
-deferred, and paged.  At ``tick >= max(rel_deadline)`` — the gateway's
-default tick — every admission decision is *latency-independent*: a
-round's run time is capped at its effective deadline
+**Regime contract.**  At ``tick >= max(rel_deadline)`` — the gateway's
+default tick — a round's run time is capped at its effective deadline
 (``run_t = min(lat, dvec) <= dvec <= rel_deadline <= tick``), so every
-lane's ``busy_until`` lands at or before the next round boundary and
-every lane is idle at every boundary.  Under that contract the megatick
-splits the loop in two exact halves:
+lane is idle at every round boundary and no admission decision depends
+on a latency.  The loop then splits in two exact halves:
 
-* a **host planner** that replays the host loop's clock, arrival
-  ingestion, EDF fail-fast admission, backpressure, same-session
-  deferral, and LRU paging *bookkeeping* up front (reusing the same
-  :class:`~repro.serving.batcher.DeadlineBatcher` and the same paging
-  order, so ``pages_in``/``pages_out`` and every disposition match the
-  host loop exactly), emitting a dense ``[R, L]`` round schedule;
+* a **host planner** that runs the gateway's own host half
+  (:class:`~repro.traffic.gateway._GatewayCore`: request intake, round
+  clock, device-loss quarantine, EDF admission with backpressure,
+  fail-fast and same-session deferral, LRU lane placement) with no lane
+  busy, and adds only the fill of a dense ``[R, L]`` round schedule and
+  a page-in that moves metadata, never state;
 * a **device scan** over that schedule, holding all per-session filter
   and goal-window state ``[S]``-resident (sessions are gathered to lanes
   by index and scattered back each round) — which makes session paging a
@@ -35,10 +29,9 @@ splits the loop in two exact halves:
   round-trips are bitwise lossless and every per-lane operation is
   lane-independent, so lane placement cannot alter any outcome.
 
-A tick below the largest relative deadline genuinely couples admission
-to in-scan latencies (a busy lane defers its session's next request);
-that regime stays on the host loop, and :meth:`run` raises on it rather
-than silently diverge.
+A finer tick couples admission to in-scan latencies (a busy lane defers
+its session's next request); that regime stays on the host loop, and
+:meth:`run` raises on it rather than silently diverge.
 
 Every traced piece is the host loop's op-for-op twin —
 :meth:`~repro.core.batched.BatchedAlertEngine.select_step_impl` (sigma
@@ -63,8 +56,8 @@ from typing import Sequence
 import jax
 import numpy as np
 
-from repro.core.batched import (BatchedAlertEngine, _goal_record_step,
-                                goal_codes, goal_current_step_hostsum)
+from repro.core.batched import (_goal_record_step, goal_codes,
+                                goal_current_step_hostsum)
 from repro.core.kalman import (IdlePowerFilterBank, SlowdownFilterBank,
                                fused_fleet_step)
 from repro.core.precision import x64_scope
@@ -72,15 +65,9 @@ from repro.core.profiles import ProfileTable
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.ring import round_aggregates
 from repro.obs.trace import count as obs_count, span as obs_span
-from repro.serving.batcher import DeadlineBatcher
 from repro.serving.sim import deliver_step
-from repro.traffic.gateway import (REJECTED_BACKPRESSURE,
-                                   REJECTED_INFEASIBLE, SERVED,
-                                   GatewayResult, SessionGateway,
-                                   _lru_pick, _obs_record_result,
-                                   _resolve_obs)
-from repro.traffic.workloads import Session, TrafficRequest, \
-    generate_requests
+from repro.traffic.gateway import SERVED, GatewayResult, _GatewayCore
+from repro.traffic.workloads import Session, TrafficRequest
 
 
 @dataclasses.dataclass
@@ -104,7 +91,7 @@ class _Plan:
     now: np.ndarray         # [R] f64 round instants k * tick
 
 
-class MegatickGateway:
+class MegatickGateway(_GatewayCore):
     """Open-loop traffic with the round clock flattened on device.
 
     Drop-in for :class:`~repro.traffic.gateway.SessionGateway` in the
@@ -119,6 +106,8 @@ class MegatickGateway:
     ``n_compiles`` stays flat across a whole load sweep).
     """
 
+    _label = "megatick"
+
     def __init__(self, table: ProfileTable, n_lanes: int, *,
                  phi_true: float = 0.25, overhead: float = 0.0,
                  tick: float | None = None,
@@ -126,13 +115,13 @@ class MegatickGateway:
                  min_feasible_latency: float | None = None,
                  accuracy_window: int = 10, backend: str = "xla",
                  mesh=None, chunk: int = 128, obs=None):
-        self.table = table
-        # Optional flight recorder (repro.obs.FlightRecorder): attaching
-        # one adds the telemetry-ring outputs to the scan (a separate
-        # jit cache entry) and host spans/metrics — all pure observers,
-        # bitwise-neutral per tests/test_obs.py.
-        self.obs = obs
-        self._ob = _resolve_obs(obs)
+        # An attached flight recorder also adds the telemetry-ring
+        # outputs to the scan (a separate jit cache entry).
+        super().__init__(table, n_lanes, phi_true=phi_true,
+                         overhead=overhead, tick=tick, max_queue=max_queue,
+                         min_feasible_latency=min_feasible_latency,
+                         accuracy_window=accuracy_window, backend=backend,
+                         mesh=mesh, obs=obs)
         # Phase timers live in a registry even with no recorder attached
         # so plan/scan wall time ACCUMULATES across repeated run() calls
         # (total_s/count); last_plan_s/last_scan_s stay as read-through
@@ -140,26 +129,11 @@ class MegatickGateway:
         reg = self._ob.metrics if self._ob else MetricsRegistry()
         self._plan_timer = reg.timer("megatick_plan", gateway="megatick")
         self._scan_timer = reg.timer("megatick_scan", gateway="megatick")
-        self.n_lanes = int(n_lanes)
-        self.phi_true = float(phi_true)
-        self.tick = tick
-        self.max_queue = max_queue
-        self.min_feasible_latency = float(table.latency.min()) \
-            if min_feasible_latency is None else float(min_feasible_latency)
-        self.accuracy_window = int(accuracy_window)
         self.chunk = int(chunk)
-        self.mesh = mesh
         if mesh is not None and self.n_lanes % mesh.size:
             raise ValueError(
                 f"lane-sharded megatick needs n_lanes divisible by the "
                 f"mesh size ({mesh.size}); got {self.n_lanes}")
-        self.engine = BatchedAlertEngine(table, None, overhead=overhead,
-                                         backend=backend, mesh=mesh)
-        self._st = table.staircase_tensors()
-        groups = table.anytime_groups()
-        self._is_anytime = np.zeros(len(table.candidates), bool)
-        self._is_anytime[sorted({i for g in groups.values()
-                                 for i in g})] = True
         self._chunk_jits: dict = {}
 
     # -------------------------------------------------------------- #
@@ -194,135 +168,56 @@ class MegatickGateway:
     # -------------------------------------------------------------- #
     # host planner                                                    #
     # -------------------------------------------------------------- #
-    def _reset_lru(self, n_sessions: int) -> None:
-        """Fresh LRU paging bookkeeping (between runs).
-
-        Everything is indexed by DENSE session index (``sid_index``
-        order), not raw sid — a bijection, so lane assignment, eviction
-        order, and page counts are unchanged — which lets the whole
-        twin run on flat arrays instead of per-sid dicts."""
-        self._resident = np.full(self.n_lanes, -1, dtype=np.int64)
-        self._lane_arr = np.full(max(n_sessions, 1), -1, dtype=np.int64)
-        self._stored_arr = np.zeros(max(n_sessions, 1), dtype=bool)
-        self._last_used = np.zeros(self.n_lanes, dtype=np.int64)
-        self._dead = np.zeros(self.n_lanes, dtype=bool)
-        self.pages_in = self.pages_out = 0
-
     def _page_in_meta(self, sids: np.ndarray,
                       round_k: int) -> np.ndarray:
-        """:meth:`SessionGateway._page_in`'s lane assignment and paging
-        accounting, without moving any state.
+        """:meth:`SessionGateway._page_in`'s lane placement and paging
+        accounting (:meth:`~repro.traffic.gateway._GatewayCore._place`),
+        without moving any state.
 
         The ``[S]``-resident scan buffers make the page *transfers* a
         semantic no-op (export/import round-trips are bitwise lossless
         and every per-lane op is lane-independent), but WHICH sessions
         page — and therefore ``pages_in``/``pages_out`` — is still the
-        host loop's observable, so the LRU bookkeeping is the host
-        loop's own pick (:func:`~repro.traffic.gateway._lru_pick`),
-        assigned to missing batch positions in order.  Under the regime
-        contract every lane is idle at every round boundary, so the
-        host loop's idle mask is all-true here by construction.
-
-        ``sids`` are dense session indices (see :meth:`_reset_lru`).
+        host loop's observable.  Under the regime contract every lane is
+        idle at every round boundary, so every live lane may take a
+        session.  ``sids`` are dense session indices (``sid_index`` of
+        :meth:`_plan`: sessions in the order offered).
         """
-        lanes = self._lane_arr[sids]
-        miss = np.nonzero(lanes < 0)[0]
-        if miss.size:
-            free, ev = _lru_pick(self._resident, self._last_used,
-                                 ~self._dead, sids, miss.size)
-            if ev.size:
-                olds = self._resident[ev]
-                self._stored_arr[olds] = True
-                self._lane_arr[olds] = -1
-                self._resident[ev] = -1
-                self.pages_out += int(ev.size)
-                free = np.concatenate([free, ev])
-            if free.size < miss.size:
-                # Unreachable in-regime (a batch never exceeds the lane
-                # count and every non-needed resident is evictable), but
-                # fail loudly rather than truncate — same invariant as
-                # the host loop's page-in guard.
-                raise RuntimeError(
-                    f"page-in underflow: {miss.size} session(s) need "
-                    f"lanes but only {free.size} are available")
-            take = free[:miss.size]
-            msids = sids[miss]
-            lanes[miss] = take
-            self._resident[take] = msids
-            self._lane_arr[msids] = take
-            self.pages_in += int(self._stored_arr[msids].sum())
-            self._stored_arr[msids] = False
-        self._last_used[lanes] = round_k
-        return lanes
+        return self._place(sids, sids, ~self._dead, round_k)[0]
 
     def _plan(self, sessions: Sequence[Session],
               requests: list[TrafficRequest] | None,
               sid_index: dict[int, int], faults=None) -> _Plan:
-        """Replay the host loop's clock and admission up front.
+        """Run the host loop's clock and admission up front: the
+        gateway's own intake, round clock, device-loss quarantine and
+        admission (:class:`~repro.traffic.gateway._GatewayCore`) with no
+        lane busy (the regime contract), the metadata-only page-in, and
+        the fill of the dense round schedule the scan consumes.
 
-        Runs the EXACT control flow of the fixed
-        :meth:`SessionGateway.run` — stable arrival sort, duplicate
-        rejection, round skip-ahead, arrival submission with
-        backpressure, EDF pop with fail-fast and same-session deferral
-        (via :meth:`DeadlineBatcher.requeue`), LRU paging bookkeeping —
-        under the regime contract (every lane idle at every boundary),
-        and emits the dense round schedule the scan consumes.
-
-        ``faults`` replays the host loop's fault protocol at the same
-        round instants: death transitions quarantine lanes (residents
-        marked stored, capacity shrinks), and each scheduled round
-        records the schedule's numpy-f64 slow-down row — multiplied
-        onto the ``[R, L]`` scale grid in the host's exact
-        ``(xi*lam) * f`` order, so the scan sees bit-identical inputs.
+        ``faults`` is read at the host loop's round instants: death
+        transitions quarantine lanes (residents marked stored, capacity
+        shrinks), and each scheduled round records the schedule's
+        numpy-f64 slow-down row — multiplied onto the ``[R, L]`` scale
+        grid in the host's exact ``(xi*lam) * f`` order, so the scan
+        sees bit-identical inputs.
         """
-        sess = {s.sid: s for s in sessions}
-        if requests is None:
-            requests = generate_requests(sessions)
-        requests = sorted(
-            requests,
-            key=lambda r: (r.arrival,
-                           0 if r.req_id is None else r.req_id))
-        if len({id(r) for r in requests}) != len(requests):
-            raise ValueError(
-                "the same TrafficRequest object was offered more than "
-                "once; every offered request must be a distinct object")
-        for k, r in enumerate(requests):
-            r._row = k
+        rs = self._offer(sessions, requests)
+        out, requests, sess = rs.out, rs.requests, rs.sess
         n = len(requests)
-        out = GatewayResult(
-            sid=np.asarray([r.sid for r in requests], dtype=np.int64),
-            index=np.asarray([r.index for r in requests], dtype=np.int64),
-            arrival=np.asarray([r.arrival for r in requests]),
-            status=np.full(n, REJECTED_BACKPRESSURE, dtype=np.int64),
-            start=np.zeros(n), latency=np.zeros(n), sojourn=np.zeros(n),
-            missed=np.zeros(n, bool), accuracy=np.zeros(n),
-            energy=np.zeros(n), model_index=np.zeros(n, dtype=np.int64),
-            power_index=np.zeros(n, dtype=np.int64))
         if n == 0:
             return _Plan(out, 0, *(np.zeros((0, self.n_lanes)),) * 9,
                          np.zeros(0))
-        tick = self.tick if self.tick is not None else \
-            max(r.rel_deadline for r in requests)
         max_rel = max(r.rel_deadline for r in requests)
-        if tick < max_rel:
+        if rs.tick < max_rel:
             raise ValueError(
                 f"megatick needs tick >= max relative deadline "
-                f"({tick} < {max_rel}): a finer tick couples admission "
+                f"({rs.tick} < {max_rel}): a finer tick couples admission "
                 f"to in-round latencies (busy lanes at round "
                 f"boundaries) — use SessionGateway for that regime")
-        self._reset_lru(len(sessions))
+        self._reset_pool(len(sessions))
         ob = self._ob
-        queue = DeadlineBatcher(batch_size=self.n_lanes,
-                                min_feasible_latency=
-                                self.min_feasible_latency,
-                                max_queue=self.max_queue,
-                                metrics=ob.metrics if ob else None)
-        q_depth = ob.metrics.histogram("queue_depth",
-                                       gateway="megatick") if ob else None
-        code_of: dict = {}      # goal_codes is pure per goal: memoize
-        for s in sessions:
-            if s.goal not in code_of:
-                code_of[s.goal] = int(goal_codes([s.goal])[0])
+        code_of = {g: int(goal_codes([g])[0])
+                   for g in {s.goal for s in sessions}}
         gk_of = {s.sid: code_of[s.goal] for s in sessions}
         # Flat per-field accumulators (one entry per served request),
         # scattered into the [R, L] schedule in one vectorized pass —
@@ -340,72 +235,13 @@ class MegatickGateway:
         f_gk: list[int] = []
         fault_mul: list[np.ndarray] = []    # [L] per scheduled round
         fault_dead: list[np.ndarray] = []   # [L] per scheduled round
-        ri = 0
-        round_k = 0
-        while ri < n or len(queue):
-            if not len(queue):
-                round_k = max(round_k, SessionGateway._round_of(
-                    requests[ri].arrival, tick))
-            now = round_k * tick
+        while rs.ri < n or len(rs.queue):
+            now = self._next_round(rs)
+            round_k = rs.round_k
             if faults is not None:
-                # The host loop's death-transition protocol at the same
-                # instant: newly dead lanes page their residents to the
-                # (virtual) store and leave the pool until restored.
-                dead_now = faults.dead_at(now)
-                newly_dead = dead_now & ~self._dead
-                if newly_dead.any():
-                    ev = np.nonzero(newly_dead
-                                    & (self._resident >= 0))[0]
-                    if ev.size:
-                        olds = self._resident[ev]
-                        self._stored_arr[olds] = True
-                        self._lane_arr[olds] = -1
-                        self._resident[ev] = -1
-                        self.pages_out += int(ev.size)
-                    if ob:
-                        lanes = [int(x) for x in np.nonzero(newly_dead)[0]]
-                        ob.metrics.counter("quarantine_events",
-                                           gateway="megatick").inc()
-                        ob.metrics.counter(
-                            "lanes_quarantined",
-                            gateway="megatick").inc(len(lanes))
-                        ob.spans.event("quarantine", cat="fault",
-                                       lanes=lanes, now_s=float(now))
-                self._dead = dead_now
+                self._quarantine(now, faults)
             with obs_span(ob, "plan_admit", "megatick", round_k=round_k):
-                while ri < n and requests[ri].arrival <= now:
-                    req = requests[ri]
-                    if not queue.submit(req):
-                        out.status[req._row] = REJECTED_BACKPRESSURE
-                    ri += 1
-                if q_depth is not None:
-                    q_depth.observe(len(queue))
-                n_rej = len(queue.rejected)
-                # avail == surviving lanes and no busy-lane deferral: the
-                # regime contract makes every lane idle at every round
-                # boundary (run_t <= dvec <= rel_deadline <= tick), so
-                # the host's `(busy_until <= now) & ~dead` count reduces
-                # to the live-lane count.
-                avail = self.n_lanes - int(self._dead.sum())
-                batch: list[TrafficRequest] = []
-                seen: set[int] = set()
-                deferred: list[TrafficRequest] = []
-                defer_budget = 4 * self.n_lanes
-                while len(batch) < avail and \
-                        len(deferred) <= defer_budget:
-                    req = queue.pop_one(now)
-                    if req is None:
-                        break
-                    if req.sid in seen:
-                        deferred.append(req)
-                        continue
-                    seen.add(req.sid)
-                    batch.append(req)
-                for req in deferred:
-                    queue.requeue(req)
-                for req in queue.rejected[n_rej:]:
-                    out.status[req._row] = REJECTED_INFEASIBLE
-                    out.start[req._row] = now
+                batch, _ = self._admit(rs, now)
             if batch:
                 obs_count(ob, "rounds", gateway="megatick")
                 with obs_span(ob, "plan_page", "megatick",
@@ -431,7 +267,7 @@ class MegatickGateway:
                     f_sc.append(s.trace.xi[req.index]
                                 * s.trace.lam[req.index])
                     f_gk.append(gk_of[req.sid])
-            round_k += 1
+            rs.round_k += 1
         n_active = len(now_l)
         n_pad = -n_active % self.chunk
         r_tot = n_active + n_pad
@@ -666,15 +502,7 @@ class MegatickGateway:
         carries the lane-death mask, so the result stays
         bitwise-identical to ``SessionGateway.run(..., faults=...)``
         (``tests/test_faults.py`` pins the whole fault matrix)."""
-        if policy not in ("alert", "static"):
-            raise ValueError(policy)
-        if policy == "static" and static_config is None:
-            raise ValueError("policy='static' needs static_config=(i, j)")
-        if faults is not None and faults.n_lanes != self.n_lanes:
-            raise ValueError(
-                f"FaultSchedule covers {faults.n_lanes} lanes but the "
-                f"gateway has {self.n_lanes}")
-
+        self._check_run(policy, static_config, faults)
         ob = self._ob
         t0 = time.perf_counter()
         with obs_span(ob, "plan", "megatick"):
@@ -730,16 +558,8 @@ class MegatickGateway:
         last_completion = float(np.max(out.start[served]
                                        + out.latency[served])) \
             if served.any() else 0.0
-        out.horizon = max(last_completion,
-                          float(out.arrival[-1]) if out.offered else 0.0)
-        out.n_rounds = plan.n_active
-        out.pages_in = getattr(self, "pages_in", 0)
-        out.pages_out = getattr(self, "pages_out", 0)
-        out.n_compiles = self.n_compiles()
-        if ob:
-            _obs_record_result(ob.metrics, out, gateway="megatick",
-                               policy=policy)
-        return out
+        return self._finish(out, last_completion, plan.n_active,
+                            self.n_compiles(), policy)
 
     def _scatter(self, plan: _Plan, lo: int, hi: int, ys,
                  out: GatewayResult) -> None:

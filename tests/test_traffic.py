@@ -18,7 +18,9 @@ from repro.traffic import (DiurnalProcess, FlashCrowdProcess, MMPPProcess,
                            PoissonProcess, Session, SessionGateway,
                            TenantSpec, build_sessions, generate_requests,
                            sweep_loads)
-from repro.traffic.gateway import REJECTED_INFEASIBLE
+from repro.traffic.gateway import (REJECTED_BACKPRESSURE,
+                                   REJECTED_INFEASIBLE, SERVED)
+from repro.traffic.workloads import TrafficRequest
 from tests._hypothesis_compat import given, settings, st
 
 
@@ -539,6 +541,81 @@ class TestRoundLoopRegressions:
         r2 = Request(deadline=5.0)
         b.submit(r2)
         assert r2._seq == 1              # not 2: refusal consumed nothing
+
+    @pytest.mark.parametrize("busy", ["host", "megatick"])
+    def test_round_admission(self, table, busy):
+        """The one round admission both gateways call, with the host's
+        per-lane completion instants (``busy="host"``) and without them
+        (``"megatick"``: no lane busy by the regime contract).  On three
+        lanes, a queue of five and a fail-fast floor of 0.5 s at
+        ``now = 1``: the sixth arrival is shed by backpressure, the
+        hopeless head fails fast with ``start = now``, a session's
+        second request waits behind its first, and the session resident
+        on a busy lane waits only where busy lanes are given, which also
+        takes that lane out of the round.  Deferred requests keep their
+        EDF seq; a run of one session's requests stops the round after
+        ``4 * n_lanes + 1`` deferrals."""
+        dl = float(deadline_range(table, 5)[3])
+        tr = _short_trace(ENVS["default"], 3, 4)
+
+        def sessions(sids):
+            return [Session(sid, "t", Goal.MINIMIZE_ENERGY,
+                            Constraints(deadline=dl, accuracy_goal=0.7),
+                            np.arange(4) * dl, tr) for sid in sids]
+
+        def request(k, sid, arrival, rel):
+            return TrafficRequest(deadline=arrival + rel, arrival=arrival,
+                                  req_id=k, sid=sid, rel_deadline=rel)
+
+        def admit(gw, sess, reqs):
+            rs = gw._init_run(sess, reqs, policy="alert",
+                              static_config=None, faults=None)
+            # Session 11 resident on lane 1, mid-service until t = 5.
+            gw._lane_of[gw._dense(11)] = 1
+            gw._resident[1] = 11
+            until = np.asarray([0.0, 5.0, 0.0]) if busy == "host" \
+                else None
+            return rs, gw._admit(rs, 1.0, until)
+
+        gw = SessionGateway(table, 3, tick=1.0, max_queue=5,
+                            min_feasible_latency=0.5)
+        reqs = [request(0, 10, 0.5, 0.9),     # slack 0.4: fails fast
+                request(1, 10, 0.5, 1.5),     # served
+                request(2, 10, 0.5, 1.5),     # same session: deferred
+                request(3, 11, 0.5, 2.5),     # on the busy lane
+                request(4, 12, 0.5, 3.5),     # served
+                request(5, 13, 0.5, 4.5),     # queue full: shed
+                request(6, 13, 2.0, 1.0)]     # not yet arrived
+        rs, (batch, deferred) = admit(gw, sessions([10, 11, 12, 13]),
+                                      reqs)
+        if busy == "host":
+            assert batch == [reqs[1], reqs[4]]
+            assert deferred == [reqs[2], reqs[3]]
+        else:
+            assert batch == [reqs[1], reqs[3], reqs[4]]
+            assert deferred == [reqs[2]]
+        assert rs.ri == 6 and rs.queue.overflowed == [reqs[5]]
+        assert rs.queue.rejected == [reqs[0]]
+        out = rs.out
+        assert out.status[0] == REJECTED_INFEASIBLE and out.start[0] == 1.0
+        assert out.status[5] == REJECTED_BACKPRESSURE
+        assert not (out.status[[1, 3, 4]] == SERVED).any()
+        assert (out.start[1:] == 0.0).all()
+        # Submitted after them, a request due with the deferred one
+        # pops after it.
+        late = TrafficRequest(deadline=2.0, arrival=1.0, sid=14)
+        rs.queue.submit(late)
+        popped = [rs.queue.pop_one(1.0) for _ in range(len(rs.queue))]
+        assert popped == [reqs[2], late] + deferred[1:]
+
+        gw = SessionGateway(table, 3, tick=1.0)
+        run = [request(k, 20, 0.0, 3.0) for k in range(16)]
+        other = request(16, 21, 0.0, 4.0)
+        rs, (batch, deferred) = admit(gw, sessions([11, 20, 21]),
+                                      run + [other])
+        assert batch == run[:1] and deferred == run[1:14]
+        popped = [rs.queue.pop_one(1.0) for _ in range(len(rs.queue))]
+        assert popped == run[1:] + [other]
 
     def test_duplicate_request_object_rejected(self, table):
         dl = float(deadline_range(table, 5)[3])
